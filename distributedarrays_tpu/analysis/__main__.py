@@ -8,7 +8,7 @@
     python -m distributedarrays_tpu.analysis locks [paths...]
 
 ``lint`` exits 0 when every finding is suppressed (or none exist), 1
-otherwise — the CI / tpu_watch gate.  Default paths are the package's own
+otherwise — the CI gate.  Default paths are the package's own
 lint surface: ``distributedarrays_tpu examples bench.py``.  Output
 formats: ``--format=text`` (default), ``json`` (one object per finding),
 ``github`` (workflow-command annotations rendered inline on PR diffs).

@@ -2,7 +2,7 @@
 
 The engine is deliberately stdlib-only (``ast`` + ``re``): linting a tree
 must not require a working JAX install, must start fast enough to run
-before every TPU bench leg (tools/tpu_watch.sh), and must be importable
+before every chip run, and must be importable
 from CI without pulling the framework's device runtime.
 
 Suppression syntax (checked per physical line of the finding):

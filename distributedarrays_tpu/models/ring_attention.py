@@ -223,8 +223,8 @@ def _rdma_attn_call(axis, p, b, h, dh, dtype_str, causal, scale, qblk,
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((b, h, dh), jnp.float32),
                         pltpu.VMEM((2, 2, b, h, dh), dtype),

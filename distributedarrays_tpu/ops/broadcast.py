@@ -115,29 +115,36 @@ def _replicate(r, mesh_sh, warn_key=None, warn_msg=None):
                                       jax.sharding.PartitionSpec()))
 
 
+def _device_order(sh) -> tuple:
+    """The device assignment a jitted program would take from ``sh``: the
+    mesh's flat device order (a transposed layout holds the SAME devices
+    in another order, and jit refuses to mix the two)."""
+    mesh = getattr(sh, "mesh", None)
+    if mesh is not None:
+        return tuple(d.id for d in mesh.devices.flat)
+    return tuple(sorted(d.id for d in sh.device_set))
+
+
 def _align_devices(raw, sharding):
-    """Move committed args whose device set differs from the target sharding's
-    onto it — one jit program needs one device assignment.  This is the moral
-    equivalent of the reference re-distributing misaligned broadcast args
-    (``bcdistribute`` → ``makelocal`` remote path, broadcast.jl:124-152), done
-    as an XLA resharding instead of per-chunk RPC."""
-    if sharding is None:
+    """Move committed args whose device assignment (set or order) differs
+    from the target sharding's onto it — one jit program needs one device
+    assignment.  This is the moral equivalent of the reference
+    re-distributing misaligned broadcast args (``bcdistribute`` →
+    ``makelocal`` remote path, broadcast.jl:124-152), done as an XLA
+    resharding instead of per-chunk RPC."""
+    mesh_sh = sharding
+    if mesh_sh is None:
         # canonicalize onto the first committed arg's devices
-        target = None
-        for r in raw:
-            if isinstance(r, jax.Array) and getattr(r, "sharding", None) is not None:
-                target = r.sharding.device_set
-                mesh_sh = r.sharding
-                break
-        if target is None:
+        mesh_sh = next(
+            (r.sharding for r in raw if isinstance(r, jax.Array)
+             and getattr(r, "sharding", None) is not None), None)
+        if mesh_sh is None:
             return raw
-    else:
-        target = sharding.device_set
-        mesh_sh = sharding
     spec = tuple(getattr(mesh_sh, "spec", ()) or ())
+    order = _device_order(mesh_sh)
     out = []
     for r in raw:
-        if isinstance(r, jax.Array) and r.sharding.device_set != target:
+        if isinstance(r, jax.Array) and _device_order(r.sharding) != order:
             misfit = _spec_misfit(r, spec, mesh_sh)
             if misfit is not None:
                 # rank/divisibility misfit pre-checked — never attempt a

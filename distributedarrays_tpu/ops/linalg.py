@@ -306,6 +306,13 @@ def _ring_ag_gemm(A: DArray, B: DArray, out_dtype):
         rdma = None
     isz = np.dtype(A.dtype).itemsize
     osz = np.dtype(out_dtype).itemsize
+    if rdma == "compiled" and not (
+            A.dtype == B.dtype and _pc.gemm_ring_eligible(
+                "ag_rhs", (k // p, n), (m // p, k), p, isz, osz)):
+        # decided HERE from the shapes, and said on the span: demanding
+        # the compiled fused kernel for operands its scoped-VMEM gate
+        # refuses would only be counted as a degradation further down
+        rdma, dispatch_src = None, "vmem_gate"
     with _tm.span("matmul.ring_ag", ranks=p,
                   dispatch="rdma" if rdma else "xla",
                   dispatch_key=dispatch_key, dispatch_source=dispatch_src,

@@ -34,10 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_gemm import _on_tpu
 from .. import telemetry as _tm
@@ -136,8 +133,6 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 @functools.lru_cache(maxsize=64)
 def _build(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
            hfold: int = 1):
-    if pltpu is None:
-        raise RuntimeError("pallas TPU namespace unavailable")
     k_steps = s // bk
     kern = functools.partial(_kernel, scale=scale, causal=causal,
                              bq=bq, bk=bk, k_steps=k_steps, hfold=hfold)
@@ -283,8 +278,6 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 @functools.lru_cache(maxsize=64)
 def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
                out_dtype_str=None):
-    if pltpu is None:
-        raise RuntimeError("pallas TPU namespace unavailable")
     out_dtype = jnp.dtype(out_dtype_str or dtype_str)
     k_steps, q_steps = s // bk, s // bq
 
@@ -407,8 +400,6 @@ def _carry_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, m_in_ref,
 @functools.lru_cache(maxsize=64)
 def _build_carry(h, b, d, bq, bk, dtype_str, scale, causal, interpret,
                  hfold: int = 1):
-    if pltpu is None:
-        raise RuntimeError("pallas TPU namespace unavailable")
     k_steps = b // bk
     kern = functools.partial(_carry_kernel, scale=scale, causal=causal,
                              bq=bq, bk=bk, k_steps=k_steps, hfold=hfold)

@@ -47,25 +47,13 @@ __all__ = [
 
 def shard_map_compat(f: Callable, mesh: Mesh, in_specs, out_specs,
                      check: bool | None = None):
-    """``shard_map`` across jax versions: the stable ``jax.shard_map``
-    (``check_vma=``) when present, else the 0.4.x experimental API
-    (``jax.experimental.shard_map.shard_map``, ``check_rep=``).  Every
-    shard_map construction in the package goes through here so a jax
-    upgrade/downgrade is a one-site change.  ``check=None`` keeps the
-    library's own default (the replication/VMA check stays ON for call
-    sites that never opted out of it)."""
-    kw = {}
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        if check is not None:
-            kw["check_vma"] = check
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as _esm
-    if check is not None:
-        kw["check_rep"] = check
-    return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kw)
+    """``jax.shard_map`` with the package's ``check`` spelling.  Every
+    shard_map construction in the package goes through here.
+    ``check=None`` keeps the library's own default (the replication/VMA
+    check stays ON for call sites that never opted out of it)."""
+    kw = {} if check is None else {"check_vma": check}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def _rec(kind: str, x, axis: str, **fields) -> None:
@@ -111,14 +99,8 @@ def axis_rank(axis: str):
 
 
 def axis_size(axis: str):
-    """Static size of a mesh axis from inside a traced program.  Version
-    compat: ``lax.axis_size`` when present (new jax), else the 0.4.x
-    ``jax.core.axis_frame`` (which returns the size directly)."""
-    sz = getattr(lax, "axis_size", None)
-    if sz is not None:
-        return sz(axis)
-    import jax.core as _jc
-    return _jc.axis_frame(axis)
+    """Static size of a mesh axis from inside a traced program."""
+    return lax.axis_size(axis)
 
 
 def pshift(x, axis: str, shift: int = 1, wrap: bool = True):

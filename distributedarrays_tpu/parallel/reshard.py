@@ -877,10 +877,13 @@ def _collective_jit(mesh, strategy, ndim, src_dim, dst_dim, p,
         blk = x.shape[dst_dim] // p
         return lax.dynamic_slice_in_dim(x, r * blk, blk, axis=dst_dim)
 
-    # pallas_call has no shard_map replication rule: the RDMA variant
-    # must opt out of the check (the XLA variant keeps it)
+    # pallas_call has no shard_map replication rule, and the VMA check
+    # types lax.all_gather's output as varying although every rank holds
+    # the same gathered value: both must opt out of the check for their
+    # out-spec to be accepted (the other XLA variants keep it)
+    opt_out = bool(rdma) or strategy == "all_gather"
     return jax.jit(shard_map_compat(kernel, mesh, in_spec, out_spec,
-                                    check=False if rdma else None))
+                                    check=False if opt_out else None))
 
 
 def _run_collective(x, dst_sharding, plan: ReshardPlan, rdma=None):

@@ -19,7 +19,7 @@ from pathlib import Path
 
 import numpy as np
 
-__all__ = ["available", "assemble", "scatter_chunks", "worth_using"]
+__all__ = ["available", "assemble", "scatter_chunks", "worth_using", "tier"]
 
 _REPO = Path(__file__).resolve().parents[2]
 _SRC = _REPO / "native" / "chunkcopy.cpp"
@@ -29,10 +29,11 @@ _SO = _BUILD / "libchunkcopy.so"
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_built_here = False
 
 
 def _load():
-    global _lib, _tried
+    global _lib, _tried, _built_here
     with _lock:
         if _tried:
             return _lib
@@ -51,6 +52,7 @@ def _load():
                      "-o", str(tmp), str(_SRC)],
                     check=True, capture_output=True, timeout=120)
                 os.replace(tmp, _SO)
+                _built_here = True
             try:
                 lib = ctypes.CDLL(str(_SO))
             except OSError:
@@ -77,6 +79,16 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def tier() -> str:
+    """Which copy tier this process runs: ``"native-built"`` (compiled
+    from ``native/chunkcopy.cpp`` by this process), ``"native-prebuilt"``
+    (a ``build/libchunkcopy.so`` that was already on disk) or
+    ``"numpy"`` (no toolchain; the pure-numpy fallback)."""
+    if _load() is None:
+        return "numpy"
+    return "native-built" if _built_here else "native-prebuilt"
 
 
 def worth_using(total_bytes: int, n_chunks: int) -> bool:

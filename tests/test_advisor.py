@@ -319,7 +319,7 @@ def test_apply_measure_or_revert_contract(clean_autotune,
     def probe(action, config=None):
         calls["n"] += 1
         if calls["n"] > 4:            # warmup+3 before OK; after dies
-            raise RuntimeError("tunnel dropped")
+            raise RuntimeError("device lost")
         return 0.01
 
     results = advisor.apply([_chunk_action(current=[1], proposed=[2])],

@@ -111,48 +111,6 @@ def test_failure_banks_no_comm_bytes_column(bench):
     assert "sort_comm_bytes_est" not in d
 
 
-def test_provenance_collapse_carries_probe_attempts(bench):
-    # same-environment headers merge; probe_attempts survives as the max
-    provs = [
-        {"device_kind": "v5e", "method": "direct", "utc": "t1",
-         "probe_attempts": 2},
-        {"device_kind": "v5e", "method": "direct", "utc": "t2",
-         "probe_attempts": 5},
-        {"device_kind": "v5e", "method": "direct",
-         "utcs": ["t0"], "probe_attempts_max": 7},   # already collapsed
-        {"device_kind": "v4", "method": "direct", "utc": "t3",
-         "probe_attempts": 1},
-        {"device_kind": "v4", "method": "direct", "utc": "t4"},  # no attempts
-    ]
-    out = bench._collapse_provenances(provs)
-    assert len(out) == 2
-    v5e = next(c for c in out if c["device_kind"] == "v5e")
-    assert v5e["utcs"] == ["t1", "t2", "t0"]
-    assert v5e["probe_attempts_max"] == 7
-    v4 = next(c for c in out if c["device_kind"] == "v4")
-    assert v4["utcs"] == ["t3", "t4"]
-    assert v4["probe_attempts_max"] == 1
-
-
-def test_details_lock_serializes_invocations(bench, monkeypatch, tmp_path):
-    # second acquirer must wait; with a zero wait budget it gives up with
-    # None instead of proceeding into the read-modify-write race.  flock
-    # is per open-file-description, so two opens conflict even in-process.
-    # Sandboxed lock path: the test must never contend on (or briefly
-    # hold) the repo's production BENCH_DETAILS.lock.
-    monkeypatch.setattr(bench, "_LOCK_PATH", tmp_path / "details.lock")
-    monkeypatch.setenv("DAT_BENCH_LOCK_WAIT_S", "5")
-    lock1 = bench._acquire_details_lock()
-    assert lock1 is not None
-    monkeypatch.setenv("DAT_BENCH_LOCK_WAIT_S", "0")
-    assert bench._acquire_details_lock() is None
-    lock1.close()   # releases the flock
-    monkeypatch.setenv("DAT_BENCH_LOCK_WAIT_S", "5")
-    lock2 = bench._acquire_details_lock()
-    assert lock2 is not None
-    lock2.close()
-
-
 def test_banked_in_handles_dynamic_gemm16k_labels(bench):
     # the one dynamic label family is grid-tagged; its sentinel is
     # derived, not listed (multi-chip runs tag e.g. gemm_16k_2x2)

@@ -1,0 +1,249 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``v5e:2x2``).  These cases keep the answers of
+the PR 21 bring-up as tests: the kernels of ``chip_smoke.py``'s main path
+at their real widths, the ring kernels on a four-chip mesh, and the whole
+transformer ``train_step`` — each must lower through Mosaic
+(``tpu_custom_call`` in the compiled text), so a later PR that breaks a
+kernel's tiling, VMEM budget or partitioning is refused here at no chip
+time.  A compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never while a
+module is imported: only one process at a time may load the TPU's
+library, and every xdist worker imports every test file).  Code that asks
+``_on_tpu()`` still sees the CPU here, so the tests steer it with
+``monkeypatch``.
+"""
+
+import json
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from distributedarrays_tpu import parallel
+from distributedarrays_tpu.models import transformer as T
+from distributedarrays_tpu.ops import pallas_attention as PA
+from distributedarrays_tpu.ops import pallas_collectives as PC
+from distributedarrays_tpu.ops import pallas_gemm as PG
+from distributedarrays_tpu.ops import pallas_stencil as PS
+
+REPO = Path(__file__).resolve().parent.parent
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def ring_mesh(topo):
+    return Mesh(np.asarray(topo.devices, dtype=object).reshape(4), ("d0",))
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer every module's platform test to the chip's branch."""
+    for mod in (PG, PA, PC, PS):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _assert_kernel(fn, *shapes):
+    assert "tpu_custom_call" in _compiled_text(fn, *shapes)
+
+
+# ---------------------------------------------------------------------------
+# one-chip kernels at chip_smoke.py's widths
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_pallas_matmul_4096(one_chip, dtype):
+    a = jax.ShapeDtypeStruct((4096, 4096), dtype, sharding=one_chip)
+    _assert_kernel(lambda a, b: PG.pallas_matmul(a, b, interpret=False),
+                   a, a)
+
+
+def test_pallas_matmul_int8_4096(one_chip):
+    q = jax.ShapeDtypeStruct((4096, 4096), jnp.int8, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
+    _assert_kernel(lambda qa, qb, sa, sb: PG.pallas_matmul_int8(
+        qa, qb, sa, sb, interpret=False), q, q, s, s)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("heads,d", [(8, 64), (4, 128)], ids=["d64", "d128"])
+def test_flash_forward_8192_default_blocks(one_chip, heads, d, causal):
+    q = jax.ShapeDtypeStruct((8192, heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    _assert_kernel(lambda q, k, v: PA.flash_attention(
+        q, k, v, causal=causal, interpret=False), q, q, q)
+
+
+def _seed_flash_entries():
+    seed = json.loads((REPO / "AUTOTUNE_SEED.json").read_text())
+    return sorted(seed["flash_attention"].items())
+
+
+@pytest.mark.parametrize("idx", range(3))
+def test_flash_forward_with_seeded_blocks(one_chip, idx):
+    # device_key_for sees the CPU here and never matches the seed, so the
+    # blocks AUTOTUNE_SEED.json would select on the chip are passed in
+    entries = _seed_flash_entries()
+    assert len(entries) == 3, "AUTOTUNE_SEED.json flash entries changed"
+    key, (bq, bk) = entries[idx]
+    s, h, d, dtype, causal = key.split("|")[:5]
+    q = jax.ShapeDtypeStruct((int(s), int(h), int(d)), jnp.dtype(dtype),
+                             sharding=one_chip)
+    _assert_kernel(lambda q, k, v: PA.flash_attention(
+        q, k, v, causal=causal == "True", block_q=bq, block_k=bk,
+        interpret=False), q, q, q)
+
+
+@pytest.mark.parametrize("heads,d", [(16, 64), (8, 128)], ids=["d64", "d128"])
+def test_flash_backward_2048(one_chip, heads, d):
+    q = jax.ShapeDtypeStruct((2048, heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(PA.flash_attention(q, k, v, causal=True,
+                                          interpret=False)
+                       .astype(jnp.float32))
+
+    txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    # forward, dq and dk/dv: three kernels
+    assert txt.count("tpu_custom_call") >= 3
+
+
+def test_stencil5_block_8192(one_chip):
+    x = jax.ShapeDtypeStruct((8192, 8192), jnp.float32, sharding=one_chip)
+    h = jax.ShapeDtypeStruct((1, 8192), jnp.float32, sharding=one_chip)
+    _assert_kernel(lambda x, lo, hi: PS.stencil5_block(
+        x, lo, hi, interpret=False), x, h, h)
+
+
+def test_stencil5_multistep_8192_k8(one_chip):
+    x = jax.ShapeDtypeStruct((8192, 8192), jnp.float32, sharding=one_chip)
+    h = jax.ShapeDtypeStruct((8, 8192), jnp.float32, sharding=one_chip)
+    _assert_kernel(lambda x, lo, hi: PS.stencil5_multistep(
+        x, lo, hi, 8, True, True, interpret=False), x, h, h)
+
+
+# ---------------------------------------------------------------------------
+# ring kernels on the four-chip mesh
+# ---------------------------------------------------------------------------
+
+
+def _ring_text(mesh, f, in_specs, out_spec, *shapes):
+    fn = parallel.run_spmd(f, mesh, in_specs=in_specs, out_specs=out_spec)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=NamedSharding(mesh, sp))
+            for (s, dt), sp in zip(shapes, in_specs)]
+    return fn.lower(*args).compile().as_text()
+
+
+def test_ring_all_gather_4chips(ring_mesh, on_tpu):
+    txt = _ring_text(
+        ring_mesh,
+        lambda x: PC.ring_all_gather(x, "d0", dim=0, interpret=False),
+        (P("d0", None),), P("d0", None), ((4 * 4096, 4096), jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
+def test_ring_all_to_all_4chips(ring_mesh, on_tpu):
+    txt = _ring_text(
+        ring_mesh,
+        lambda x: PC.ring_all_to_all(x, "d0", split_dim=1, concat_dim=0,
+                                     interpret=False),
+        (P("d0", None),), P("d0", None), ((4 * 4096, 4096), jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
+def test_ring_reduce_scatter_4chips(ring_mesh, on_tpu):
+    # the derived chunk depth is 1 at this size and the p-1 receive slots
+    # then exceed scoped VMEM (the kernel would give way, and say so);
+    # chunks=16 is the depth chip_smoke.py's four-chip phase runs
+    txt = _ring_text(
+        ring_mesh,
+        lambda x: PC.ring_reduce_scatter(x, "d0", dim=0, chunks=16,
+                                         interpret=False),
+        (P("d0", None),), P("d0", None), ((4 * 4096, 4096), jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
+def test_ring_allgather_matmul_4chips(ring_mesh, on_tpu):
+    # 1792^2 bf16 is the largest square (in steps of 128) the scoped-VMEM
+    # gate admits: x (4*448, 1792) row-sharded, w (1792, 1792) resident;
+    # 2048^2 and up are refused by gemm_ring_eligible
+    txt = _ring_text(
+        ring_mesh,
+        lambda x, w: PC.ring_allgather_matmul(x, w, "d0", interpret=False),
+        (P("d0", None), P()), P("d0", None),
+        ((1792, 1792), jnp.bfloat16), ((1792, 1792), jnp.bfloat16))
+    assert "tpu_custom_call" in txt
+    assert not PC.gemm_ring_eligible("ag", (512, 2048), (2048, 2048), 4, 2,
+                                     2)
+
+
+def test_refused_compiled_ring_is_counted(ring_mesh, on_tpu):
+    # interpret=False asks for the compiled kernel; where eligibility
+    # refuses it (at 4096^2 f32 the derived chunk depth is 1 and the p-1
+    # receive slots exceed scoped VMEM) the kernel gives way to the lax
+    # collective — counted under fallback.hits and warned, not silent
+    from distributedarrays_tpu import telemetry as tm
+    from distributedarrays_tpu.utils import debug as dbg
+    key = ("pallas_collectives:ring_reduce_scatter:"
+           "receive slots exceed scoped VMEM")
+    dbg._warned.discard(key)
+    before = tm.counter_value("fallback.hits", key=key)
+    with pytest.warns(RuntimeWarning, match="compiled RDMA kernel demanded"):
+        txt = _ring_text(
+            ring_mesh,
+            lambda x: PC.ring_reduce_scatter(x, "d0", dim=0,
+                                             interpret=False),
+            (P("d0", None),), P("d0", None),
+            ((4 * 4096, 4096), jnp.float32))
+    assert "tpu_custom_call" not in txt
+    assert tm.counter_value("fallback.hits", key=key) == before + 1
+
+
+# ---------------------------------------------------------------------------
+# the whole train step of chip_smoke.py's train phase (the one long case)
+# ---------------------------------------------------------------------------
+
+
+def test_transformer_train_step_full_width(one_chip, on_tpu):
+    cfg = T.Config(vocab=8192, dim=1024, heads=16, layers=8, ffn_mult=4,
+                   max_seq=2048, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(on, shapes)
+    tokens = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = T.train_step.lower(params, tokens, lr, cfg).compile()
+    # 8 layers x (flash forward + dq + dk/dv)
+    assert compiled.as_text().count("tpu_custom_call") >= 24
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 14 * 2**30

@@ -455,3 +455,22 @@ def test_dmatmul_int8_host_array_lhs(rng):
     ref2 = A2 @ B
     assert np.abs(np.asarray(C2) - ref2).max() / np.abs(ref2).max() < 3e-2
     dat.d_closeall()
+
+
+@pytest.mark.parametrize("n", [64, 72])
+def test_readme_opening_lines_transposed_operand(n):
+    # README's opening four lines on the whole mesh: r.T holds the SAME
+    # eight devices in another order, which one jitted program refuses
+    # unless the operand is re-laid first (found by PR 21's rehearsal)
+    d = dat.drand((n, n))
+    r = dat.dmap(jnp.sin, d) + d * 2.0
+    assert sorted(int(p) for p in r.T.pids.flat) == \
+        sorted(int(p) for p in d.pids.flat)
+    s = float(dat.dsum(r))
+    C = d @ r.T
+    dh, rh = np.asarray(d, np.float64), np.asarray(r, np.float64)
+    np.testing.assert_allclose(s, rh.sum(), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(C), dh @ rh.T, rtol=1e-4,
+                               atol=1e-4)
+    # elementwise with the transposed operand takes the same path
+    np.testing.assert_allclose(np.asarray(d + r.T), dh + rh.T, rtol=1e-5)

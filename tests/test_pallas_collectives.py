@@ -208,12 +208,6 @@ def test_kill_switch_forces_xla(monkeypatch):
     assert PC.rdma_mode() is None
 
 
-def test_missing_pltpu_falls_back(monkeypatch):
-    monkeypatch.setattr(PC, "pltpu", None)
-    assert PC.rdma_mode(interpret=True) is None
-    assert PC.rdma_mode() is None
-
-
 def test_explicit_request_counts_fallback_hits(monkeypatch, rng):
     from distributedarrays_tpu.utils import debug as dbg
     monkeypatch.setenv("DA_TPU_RDMA", "1")

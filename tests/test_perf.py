@@ -538,31 +538,6 @@ def test_regress_cli_replay_and_strict(tmp_path):
     assert _regress_cli(lonely, tmp_path, "--strict").returncode == 2
 
 
-def test_bench_replay_row_is_machine_flagged():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_test", str(REPO / "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    row = bench._replay_row(
-        152021.34, 114.2,
-        {"utc": "2026-07-31T06:50:08Z", "device_kind": "TPU v5 lite"},
-        "accelerator unreachable after 5 attempts")
-    assert row["replayed"] is True
-    assert row["probe_error"].startswith("accelerator unreachable")
-    assert row["replayed_from_utc"] == "2026-07-31T06:50:08Z"
-    assert regress.is_replay(row)
-    # and load_rows refuses to treat it as a fresh measurement
-    import tempfile
-    with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                     delete=False) as f:
-        json.dump({"parsed": row}, f)
-    try:
-        assert regress.load_rows(f.name) == {}
-    finally:
-        os.unlink(f.name)
-
-
 def test_annotate_and_trace_ctx_disabled_are_silent(tmp_path):
     code = (
         "import distributedarrays_tpu.telemetry as tm\n"

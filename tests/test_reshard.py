@@ -8,6 +8,7 @@ repeated reshards of one layout pair must hit the plan cache.
 """
 
 import itertools
+import warnings
 
 import numpy as np
 import pytest
@@ -148,7 +149,8 @@ def test_samedist_oracle_vector_and_single_row_blocks(rng):
         dat.d_closeall()
 
 
-def test_planner_replicated_and_gather_strategies(rng):
+def test_planner_replicated_and_gather_strategies(telemetry_capture, rng):
+    tm = telemetry_capture
     shape = (32, 16)
     A = rng.standard_normal(shape).astype(np.float32)
     sharded = _shardings_for(shape, (8, 1))
@@ -156,8 +158,22 @@ def test_planner_replicated_and_gather_strategies(rng):
     x = jax.device_put(A, sharded)
     plan = R.plan_reshard(x, rep)
     assert plan.strategy == "all_gather"
-    z = R.reshard(x, rep)
+    # assert the dispatch that RAN, not values only: the compiled gather
+    # must not give way to device_put (it did, silently, while jax 0.9's
+    # replication check refused its out-spec)
+    fb0 = tm.counter_value("reshard.collective_fallbacks", reason="runtime")
+    b0 = tm.comm_bytes("reshard")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        z = R.reshard(x, rep)
     np.testing.assert_array_equal(np.asarray(z), A)
+    assert tm.counter_value("reshard.collective_fallbacks",
+                            reason="runtime") == fb0
+    assert tm.comm_bytes("reshard") - b0 == plan.moved_bytes
+    assert any(e.get("strategy") == "all_gather"
+               and e.get("dispatch") == "xla"
+               for e in tm.events("comm") if e.get("name") == "reshard"), \
+        tm.events("comm")
     # replicated -> sharded is comm-free local slicing
     plan2 = R.plan_reshard(z, sharded)
     assert plan2.strategy == "local_slice" and plan2.moved_bytes == 0

@@ -412,7 +412,6 @@ def test_bench_partial_rows_not_treated_as_banked():
         assert not bench._banked_in(details, label), label
         details.pop(f"{label}_partial")
         assert bench._banked_in(details, label), label
-        assert label in bench._ROW_PROBE_BUDGET_S
 
 
 # ---------------------------------------------------------------------------

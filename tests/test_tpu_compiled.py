@@ -7,7 +7,10 @@ through Mosaic and must match its dense oracle.  Single-chip by design —
 it exercises kernel lowering (block shapes, VMEM budgets, SMEM scalars),
 not cross-chip collectives (the CPU-mesh suite covers those).
 
-Skipped silently off-hardware so `pytest tests/` stays green everywhere.
+Skipped off-hardware so `pytest tests/` stays green everywhere.  The
+decision is made inside a module-scoped fixture, never while the module is
+imported: every xdist worker imports every test file, and workers that
+collect different tests run none.
 """
 
 import os
@@ -19,14 +22,18 @@ import jax
 from distributedarrays_tpu.parallel.collectives import shard_map_compat
 import jax.numpy as jnp
 
-if os.environ.get("DAT_TEST_TPU") != "1":  # pragma: no cover
-    pytest.skip("hardware leg: set DAT_TEST_TPU=1 on a TPU host",
-                allow_module_level=True)
 
-from distributedarrays_tpu.ops.pallas_gemm import _on_tpu
+@pytest.fixture(scope="module")
+def tpu():
+    if os.environ.get("DAT_TEST_TPU") != "1":
+        pytest.skip("hardware leg: set DAT_TEST_TPU=1 on a TPU host")
+    from distributedarrays_tpu.ops.pallas_gemm import _on_tpu
+    if not _on_tpu():
+        pytest.skip("no TPU visible")
+    return jax.devices()
 
-if not _on_tpu():  # pragma: no cover
-    pytest.skip("no TPU visible", allow_module_level=True)
+
+pytestmark = pytest.mark.usefixtures("tpu")
 
 
 def test_flash_attention_compiled_fwd_bwd():
@@ -220,7 +227,8 @@ def test_pallas_stencil_temporal_compiled():
         want = x[:-2] + x[2:] + left + right - 4 * want
     z = jnp.zeros((k, A.shape[1]), jnp.float32)
     got = np.asarray(stencil5_multistep(jnp.asarray(A), z, z, k, True, True))
-    assert np.abs(got - want).max() < 1e-2   # k chained f32 steps
+    # k chained f32 Laplacian steps grow the values ~8x a step: relative
+    assert np.abs(got - want).max() / np.abs(want).max() < 1e-5
 
 
 def test_flash_attention_head_fold_compiled():
@@ -292,7 +300,9 @@ def test_matmul_dispatch_pallas_promoted_compiled():
         got = np.asarray(da @ db)
         want = A @ A
         rel = np.abs(got - want).max() / np.abs(want).max()
-        assert rel < 1e-3, rel
+        # f32 operands take the MXU's default precision in the kernel
+        # (bf16 passes), as GSPMD's own default f32 dot does
+        assert rel < 1e-2, rel
     finally:
         autotune.clear()
         dat.d_closeall()
@@ -315,9 +325,9 @@ def test_dmatmul_int8_compiled():
         dat.d_closeall()
 
 
-@pytest.mark.skipif(len(jax.devices()) < 2,
-                    reason="RDMA ring collectives need >= 2 chips")
-def test_rdma_ring_collectives_compiled():
+def test_rdma_ring_collectives_compiled(tpu):
+    if len(tpu) < 2:
+        pytest.skip("RDMA ring collectives need >= 2 chips")
     # COMPILED-mode oracle for the PR 8 RDMA rings on a real multi-chip
     # slice: the interpret-mode suite proves the schedule, this proves
     # the Mosaic lowering (semaphore allocation, LOGICAL device ids,
