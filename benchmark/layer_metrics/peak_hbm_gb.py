@@ -1,0 +1,8 @@
+"""Device: ``memory_stats()["peak_bytes_in_use"]`` after the window, before
+the reference runs, fullest chip."""
+
+
+def read(run):
+    if not run.memory_peak_bytes:
+        return None
+    return run.memory_peak_bytes / 1e9
