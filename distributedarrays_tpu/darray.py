@@ -1542,7 +1542,11 @@ def distribute(A, procs=None, dist=None, like: DArray | None = None) -> DArray:
             np.shape(A), [int(p) for p in like.pids.flat], list(like.pids.shape))
     else:
         dims, pids, idxs, cuts, sharding = _resolve_layout(np.shape(A), procs, dist)
-    return DArray(_fresh(_place_chunked(A, pids, cuts, sharding), A), pids, idxs, cuts)
+    placed = _place_chunked(A, pids, cuts, sharding)
+    # host phase 4 of a leg (1 to 3 are in parallel/reshard.py): the
+    # result DArray, its registration and its ledger entry
+    with _tm.span("distribute.wrap", _journal=False):
+        return DArray(_fresh(placed, A), pids, idxs, cuts)
 
 
 # ---------------------------------------------------------------------------

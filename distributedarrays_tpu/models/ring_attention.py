@@ -234,6 +234,7 @@ def _rdma_attn_call(axis, p, b, h, dh, dtype_str, causal, scale, qblk,
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA] + _pc._credit_scratch(),
+        name="attn_ring_hop",
         interpret=interpret,
     )
 

@@ -155,21 +155,30 @@ def forward(params, tokens, cfg: Config):
     B, S = tokens.shape
     if S > cfg.max_seq:
         raise ValueError(f"sequence length {S} exceeds max_seq {cfg.max_seq}")
-    x = params["embed"][tokens] + params["pos"][:S][None]
+    # the scopes name the phases in a profiler trace (docs/telemetry.md);
+    # they are metadata only, the arithmetic is what it was
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens] + params["pos"][:S][None]
     for blk in params["blocks"]:
-        x = x + _attention(_rmsnorm(x, blk["ln1"]), blk, cfg.heads)
-        h = _rmsnorm(x, blk["ln2"])
-        x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
-    return (_rmsnorm(x, params["ln_f"]) @ params["head"]).astype(jnp.float32)
+        with jax.named_scope("block"):
+            with jax.named_scope("attn"):
+                x = x + _attention(_rmsnorm(x, blk["ln1"]), blk, cfg.heads)
+            with jax.named_scope("mlp"):
+                h = _rmsnorm(x, blk["ln2"])
+                x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
+    with jax.named_scope("head_loss"):
+        return (_rmsnorm(x, params["ln_f"])
+                @ params["head"]).astype(jnp.float32)
 
 
 def loss_fn(params, tokens, cfg: Config):
     """Next-token cross-entropy."""
     logits = forward(params, tokens[:, :-1], cfg)
-    targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
-    return -jnp.mean(ll)
+    with jax.named_scope("head_loss"):
+        targets = tokens[:, 1:]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
+        return -jnp.mean(ll)
 
 
 def _decode_attn(h, blk, heads, kc, vc, i, t, max_seq):
@@ -279,11 +288,12 @@ def _optax_f32_step(tx, grad_fn):
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(params, opt_state, tokens):
         loss, g = grad_fn(params, tokens)
-        p32 = _as_f32(params)
-        updates, opt_state = tx.update(_as_f32(g), opt_state, p32)
-        new32 = optax.apply_updates(p32, updates)
-        new = jax.tree_util.tree_map(
-            lambda n, p: n.astype(p.dtype), new32, params)
+        with jax.named_scope("optimizer"):
+            p32 = _as_f32(params)
+            updates, opt_state = tx.update(_as_f32(g), opt_state, p32)
+            new32 = optax.apply_updates(p32, updates)
+            new = jax.tree_util.tree_map(
+                lambda n, p: n.astype(p.dtype), new32, params)
         return new, opt_state, loss
 
     def init(params):

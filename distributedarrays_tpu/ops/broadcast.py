@@ -260,31 +260,34 @@ def djit(fn: Callable) -> Callable:
     partitioned over the mesh by GSPMD.
     """
     jfn = jax.jit(fn)
+    fn_name = getattr(fn, "__name__", None) or type(fn).__name__
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        d_args = [a for a in args if isinstance(a, DArray)]
-        raw = [(a.garray if isinstance(a, DArray) else
-                a.materialize() if isinstance(a, SubDArray) else a)
-               for a in args]
-        try:
-            res = jfn(*raw, **kwargs)
-        except Exception as e:
-            # flight recorder: a crashed compiled program leaves a
-            # postmortem bundle (ring + open spans + HBM ledger)
-            if _tm.enabled():
-                _tm.flight.record_crash(e, where="djit")
-            raise
+        # the root span of one call: unwrap, dispatch and re-wrap
+        with _tm.span("djit", fn=fn_name):
+            d_args = [a for a in args if isinstance(a, DArray)]
+            raw = [(a.garray if isinstance(a, DArray) else
+                    a.materialize() if isinstance(a, SubDArray) else a)
+                   for a in args]
+            try:
+                res = jfn(*raw, **kwargs)
+            except Exception as e:
+                # flight recorder: a crashed compiled program leaves a
+                # postmortem bundle (ring + open spans + HBM ledger)
+                if _tm.enabled():
+                    _tm.flight.record_crash(e, where="djit")
+                raise
 
-        def wrap(r):
-            if isinstance(r, jax.Array) and r.ndim > 0:
-                for a in d_args:
-                    if a.dims == tuple(r.shape):
-                        return a.with_data(r)
-                return _wrap_global(r)
-            return r
-        return jax.tree_util.tree_map(
-            wrap, res, is_leaf=lambda x: isinstance(x, jax.Array))
+            def wrap(r):
+                if isinstance(r, jax.Array) and r.ndim > 0:
+                    for a in d_args:
+                        if a.dims == tuple(r.shape):
+                            return a.with_data(r)
+                    return _wrap_global(r)
+                return r
+            return jax.tree_util.tree_map(
+                wrap, res, is_leaf=lambda x: isinstance(x, jax.Array))
     return wrapper
 
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import math
 from typing import Callable
 
 import numpy as np
@@ -139,7 +140,7 @@ def dmapreduce(f: Callable, op_name_or_fn, d, dims=None):
             # HBM-bound, which is what a reduction is)
             from ..telemetry import perf as _perf
             try:
-                n_elems = int(np.prod(d.dims))
+                n_elems = math.prod(int(n) for n in d.dims)
                 isz = np.dtype(d.dtype).itemsize
             except (AttributeError, TypeError):
                 n_elems, isz = _tm.nbytes_of(d), 1
@@ -252,9 +253,17 @@ def _binary_reduce_host(x, mapper, op, axes, ndim):
     return np.asarray(cols).reshape(v.shape[1:])
 
 
+def _reduce_entry(d, mapper, reducer, dims=None, **kw):
+    """``_reduce_impl`` for a public entry that is not ``dmapreduce``:
+    under the same journaled ``mapreduce`` root span, so every reduction
+    leaves one root span a call whichever entry it came through."""
+    with _tm.span("mapreduce"):
+        return _reduce_impl(d, mapper, reducer, dims=dims, **kw)
+
+
 def _named(name):
     def f(d, dims=None, **kw):
-        return _reduce_impl(d, None, _REDUCERS[name], dims=dims, **kw)
+        return _reduce_entry(d, None, _REDUCERS[name], dims=dims, **kw)
     f.__name__ = "d" + name
     return f
 
@@ -270,20 +279,20 @@ dany = _named("any")
 
 def dvar(d, dims=None, ddof=1):
     """Corrected (ddof=1) variance, matching Julia's Statistics.var default."""
-    return _reduce_impl(d, None, jnp.var, dims=dims, ddof=ddof)
+    return _reduce_entry(d, None, jnp.var, dims=dims, ddof=ddof)
 
 
 def dstd(d, dims=None, ddof=1):
     """Sample std, matching Julia's Statistics.std default (corrected);
     reference ext/StatisticsExt.jl:6 builds mean from sum — here it is one
     fused reduction."""
-    return _reduce_impl(d, None, jnp.std, dims=dims, ddof=ddof)
+    return _reduce_entry(d, None, jnp.std, dims=dims, ddof=ddof)
 
 
 def dcount(pred, d, dims=None):
     """count(pred, d) (reference mapreduce.jl:117-126)."""
-    return _reduce_impl(d, lambda a: pred(a).astype(jnp.int32), jnp.sum,
-                        dims=dims)
+    return _reduce_entry(d, lambda a: pred(a).astype(jnp.int32), jnp.sum,
+                         dims=dims)
 
 
 @functools.lru_cache(maxsize=64)

@@ -158,6 +158,7 @@ def _build(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
             pltpu.VMEM((hfold, bq, 1), jnp.float32),
             pltpu.VMEM((hfold, bq, d), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )
     return jax.jit(call)
@@ -298,6 +299,7 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
         out_specs=pl.BlockSpec((1, bq, d), lambda hh, qi, ki: (hh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((h, s, d), out_dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )
 
@@ -325,6 +327,7 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
         ),
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )
     return jax.jit(dq_call), jax.jit(dkv_call)
@@ -435,6 +438,7 @@ def _build_carry(h, b, d, bq, bk, dtype_str, scale, causal, interpret,
             pltpu.VMEM((hfold, bq, 1), jnp.float32),
             pltpu.VMEM((hfold, bq, d), jnp.float32),
         ],
+        name="flash_carry",
         interpret=interpret,
     )
     return call

@@ -433,6 +433,7 @@ def _ag_call(axis: str, p: int, shape: tuple, dtype_str: str, dim: int,
         scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA],
+        name="ring_all_gather",
         interpret=interpret,
     )
 
@@ -516,6 +517,7 @@ def _a2a_call(axis: str, p: int, shape: tuple, dtype_str: str,
         scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.DMA],
+        name="ring_all_to_all",
         interpret=interpret,
     )
 
@@ -633,6 +635,7 @@ def _rs_call(axis: str, p: int, shape: tuple, dtype_str: str, dim: int,
                         pltpu.SemaphoreType.DMA((p - 1,)),
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.DMA((2,))] + _credit_scratch(),
+        name="ring_reduce_scatter",
         interpret=interpret,
     )
 
@@ -756,6 +759,7 @@ def _ag_mm_call(axis: str, p: int, xs: tuple, ws: tuple, dtype_str: str,
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA] + _credit_scratch(),
+        name="matmul_ring_ag",
         interpret=interpret,
     )
 
@@ -830,6 +834,7 @@ def _ag_mm_rhs_call(axis: str, p: int, as_: tuple, bs: tuple,
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA] + _credit_scratch(),
+        name="matmul_ring_ag_rhs",
         interpret=interpret,
     )
 
@@ -909,6 +914,7 @@ def _mm_rs_call(axis: str, p: int, xs: tuple, ws: tuple, dtype_str: str,
                         pltpu.VMEM((2, m_loc, n), dtype),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,))] + _credit_scratch(),
+        name="matmul_ring_rs",
         interpret=interpret,
     )
 

@@ -252,6 +252,7 @@ def _build(m, n, k, bm, bn, bk, dtype_str, epilogue, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="pallas_matmul",
         interpret=interpret,
     )
     return jax.jit(call)
@@ -361,6 +362,7 @@ def _build_int8(m, n, k, bm, bn, bk, out_dtype_str, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype_str)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name="pallas_matmul_int8",
         interpret=interpret,
     )
     return jax.jit(call)

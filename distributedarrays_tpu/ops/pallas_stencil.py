@@ -131,6 +131,7 @@ def _build(m, n, bm, dtype_str, interpret, w):
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(dtype_str)),
+        name="stencil_3x3",
         interpret=interpret,
     )
     return call
@@ -242,6 +243,7 @@ def _build_multi(m, n, bm, k, dtype_str, interpret, w):
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(dtype_str)),
+        name="stencil_3x3_multistep",
         interpret=interpret,
     )
 

@@ -857,6 +857,7 @@ def _collective_jit(mesh, strategy, ndim, src_dim, dst_dim, p,
     out_spec = _spec_for(dst_dim, ndim, axis) if strategy != "all_gather" \
         else P(*([None] * ndim))
 
+    @jax.named_scope(f"reshard.{strategy}")
     def kernel(x):
         if rdma and strategy in ("all_to_all", "all_gather"):
             from ..ops import pallas_collectives as _pc
@@ -887,15 +888,17 @@ def _collective_jit(mesh, strategy, ndim, src_dim, dst_dim, p,
 
 
 def _run_collective(x, dst_sharding, plan: ReshardPlan, rdma=None):
-    mesh = L.mesh_for(list(plan.ranks), (plan.nparts,))
-    fn = _collective_jit(mesh, plan.strategy, len(plan.shape),
-                         plan.src_dim, plan.dst_dim, plan.nparts,
-                         plan.chunk_axis, plan.nchunks, rdma)
-    y = fn(x)
-    if y.sharding != dst_sharding:
-        # equivalent placement under the caller's sharding object —
-        # zero-copy relabel
-        y = jax.device_put(y, dst_sharding)
+    with _tm.span("reshard.program", _journal=False):
+        mesh = L.mesh_for(list(plan.ranks), (plan.nparts,))
+        fn = _collective_jit(mesh, plan.strategy, len(plan.shape),
+                             plan.src_dim, plan.dst_dim, plan.nparts,
+                             plan.chunk_axis, plan.nchunks, rdma)
+    with _tm.span("reshard.dispatch", _journal=False):
+        y = fn(x)
+        if y.sharding != dst_sharding:
+            # equivalent placement under the caller's sharding object —
+            # zero-copy relabel
+            y = jax.device_put(y, dst_sharding)
     return y
 
 
@@ -932,31 +935,34 @@ def _chain_jit(mesh, ndim, src_comp, dst_comp, steps, rdma=None):
     names = mesh.axis_names
     mesh_axes = tuple(names) if len(names) > 1 else None
 
+    @jax.named_scope("reshard.chain")
     def kernel(x):
         from ..ops import pallas_collectives as _pc
-        for kind, m, q, i, j, ca, nc in (s[:7] for s in steps):
+        for n, (kind, m, q, i, j, ca, nc) in enumerate(
+                s[:7] for s in steps):
             name = f"d{m}"
-            if kind == "a2a":
-                if rdma:
-                    x = _pc.ring_all_to_all(
-                        x, name, split_dim=j, concat_dim=i,
-                        interpret=rdma == "interpret",
-                        mesh_axes=mesh_axes)
-                else:
-                    x = _a2a_chunked(x, name, j, i, q,
-                                     ca if ca >= 0 else None, nc)
-            elif kind == "gather":
-                if rdma:
-                    x = _pc.ring_all_gather(
-                        x, name, dim=i, interpret=rdma == "interpret",
-                        mesh_axes=mesh_axes)
-                else:
-                    x = _gather_chunked(x, name, i,
-                                        ca if ca >= 0 else None, nc)
-            else:                            # slice: local, no comm
-                r = lax.axis_index(name)
-                blk = x.shape[j] // q
-                x = lax.dynamic_slice_in_dim(x, r * blk, blk, axis=j)
+            with jax.named_scope(f"step{n}.{kind}"):
+                if kind == "a2a":
+                    if rdma:
+                        x = _pc.ring_all_to_all(
+                            x, name, split_dim=j, concat_dim=i,
+                            interpret=rdma == "interpret",
+                            mesh_axes=mesh_axes)
+                    else:
+                        x = _a2a_chunked(x, name, j, i, q,
+                                         ca if ca >= 0 else None, nc)
+                elif kind == "gather":
+                    if rdma:
+                        x = _pc.ring_all_gather(
+                            x, name, dim=i, interpret=rdma == "interpret",
+                            mesh_axes=mesh_axes)
+                    else:
+                        x = _gather_chunked(x, name, i,
+                                            ca if ca >= 0 else None, nc)
+                else:                        # slice: local, no comm
+                    r = lax.axis_index(name)
+                    blk = x.shape[j] // q
+                    x = lax.dynamic_slice_in_dim(x, r * blk, blk, axis=j)
         return x
 
     # composite specs + optional pallas_call inside: opt out of the
@@ -986,22 +992,24 @@ def _slice_back_jit(dst_sharding, shape):
 
 
 def _run_chain(x, dst_sharding, plan: ReshardPlan, rdma=None):
-    mesh = L.mesh_for(list(plan.ranks), plan.mesh_shape)
-    ndim = len(plan.shape)
-    if plan.pad_shape:
-        x = _pad_jit(mesh, plan.src_comp, plan.shape, plan.pad_shape)(x)
-    fn = _chain_jit(mesh, ndim, plan.src_comp, plan.dst_comp, plan.steps,
-                    rdma)
-    y = fn(x)
-    if plan.pad_shape:
-        return _slice_back_jit(dst_sharding, plan.shape)(y)
-    if plan.strategy == "gather_put":
-        # restrict the now-replicated buffer to the survivor subset —
-        # comm-free: every destination device already holds the bytes
-        return _device_put_path(y, dst_sharding)
-    if y.sharding != dst_sharding:
-        y = jax.device_put(y, dst_sharding)
-    return y
+    with _tm.span("reshard.program", _journal=False):
+        mesh = L.mesh_for(list(plan.ranks), plan.mesh_shape)
+        fn = _chain_jit(mesh, len(plan.shape), plan.src_comp,
+                        plan.dst_comp, plan.steps, rdma)
+    with _tm.span("reshard.dispatch", _journal=False):
+        if plan.pad_shape:
+            x = _pad_jit(mesh, plan.src_comp, plan.shape,
+                         plan.pad_shape)(x)
+        y = fn(x)
+        if plan.pad_shape:
+            return _slice_back_jit(dst_sharding, plan.shape)(y)
+        if plan.strategy == "gather_put":
+            # restrict the now-replicated buffer to the survivor subset —
+            # comm-free: every destination device already holds the bytes
+            return _device_put_path(y, dst_sharding)
+        if y.sharding != dst_sharding:
+            y = jax.device_put(y, dst_sharding)
+        return y
 
 
 @functools.lru_cache(maxsize=None)
@@ -1063,59 +1071,64 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
     boundary), not the whole array."""
     if getattr(x, "sharding", None) == dst_sharding:
         return x
-    if plan is None:
-        plan = plan_reshard(x, dst_sharding)
-    if plan.strategy == "noop":
-        return x
-    if plan.collective:
-        try:
-            ext = jax.dtypes.issubdtype(getattr(x, "dtype", None),
-                                        jax.dtypes.extended)
-        except Exception:
-            ext = False
-        if ext:
-            # extended dtypes (PRNG key arrays) have no collective
-            # lowering — planned from shardings alone, gated on dtype here
-            plan = dataclasses.replace(plan, strategy="device_put",
-                                       reason="extended dtype")
-    # RDMA dispatch decided eagerly so the compiled program is keyed on
-    # it (flipping DA_TPU_RDMA re-jits) and the span says which path ran
-    rdma = None
-    rdma_chunks = 0
-    chunks_src = ""
-    autotune_key = ""
-    dispatch_key = ""
-    dispatch_src = ""
-    if plan.steps and any(s[0] != "slice" for s in plan.steps):
-        # chain steps ride the ring kernels when the platform arms them
-        # (mesh-coordinate addressing on multi-axis meshes); slices-only
-        # chains are local and need no dispatch decision
-        from ..ops import pallas_collectives as _pc
-        rdma = _pc.rdma_mode()
-    elif plan.collective and plan.strategy in ("all_to_all", "all_gather"):
-        from ..ops import pallas_collectives as _pc
-        rdma = _pc.rdma_mode()
-        dtype_str = str(getattr(x, "dtype", "float32"))
-        # per-shape-class dispatch preference (advisor-written
-        # "rdma_dispatch" entry); an explicit DA_TPU_RDMA env wins inside
-        # resolve_dispatch, and a preference can only demote to XLA — it
-        # never conjures RDMA on a platform rdma_mode rejected
-        dispatch_key = _pc.dispatch_key_for(
-            "reshard", plan.strategy, *plan.shape, dtype_str, plan.nparts)
-        pref, dispatch_src = _pc.resolve_dispatch(dispatch_key)
-        if pref == "xla":
-            rdma = None
-        if rdma and plan.strategy == "all_to_all":
-            lshape = tuple(s // plan.nparts if d == plan.src_dim else s
-                           for d, s in enumerate(plan.shape))
-            # the kernel concats along the plan's src dim; clamping here
-            # keeps span/bench provenance equal to the depth it runs
-            rdma_chunks, chunks_src = _pc.a2a_chunks_for(
-                lshape, dtype_str, plan.nparts, plan.src_dim)
-            # the exact "rdma_chunks" registry key this depth resolved
-            # under — the advisor addresses its writes by this label
-            autotune_key = _pc.a2a_chunks_key(lshape, dtype_str,
-                                              plan.nparts)
+    # host phase 1 of a leg: the plan (cached per layout pair) and the
+    # dispatch it resolves to; phases 2 and 3 are in _run_collective /
+    # _run_chain, phase 4 is the caller wrapping the result
+    with _tm.span("reshard.plan", _journal=False):
+        if plan is None:
+            plan = plan_reshard(x, dst_sharding)
+        if plan.strategy == "noop":
+            return x
+        if plan.collective:
+            try:
+                ext = jax.dtypes.issubdtype(getattr(x, "dtype", None),
+                                            jax.dtypes.extended)
+            except Exception:
+                ext = False
+            if ext:
+                # extended dtypes (PRNG key arrays) have no collective
+                # lowering — planned from shardings alone, gated on dtype
+                # here
+                plan = dataclasses.replace(plan, strategy="device_put",
+                                           reason="extended dtype")
+        # RDMA dispatch decided eagerly so the compiled program is keyed on
+        # it (flipping DA_TPU_RDMA re-jits) and the span says which path ran
+        rdma = None
+        rdma_chunks = 0
+        chunks_src = ""
+        autotune_key = ""
+        dispatch_key = ""
+        dispatch_src = ""
+        if plan.steps and any(s[0] != "slice" for s in plan.steps):
+            # chain steps ride the ring kernels when the platform arms them
+            # (mesh-coordinate addressing on multi-axis meshes); slices-only
+            # chains are local and need no dispatch decision
+            from ..ops import pallas_collectives as _pc
+            rdma = _pc.rdma_mode()
+        elif plan.collective and plan.strategy in ("all_to_all", "all_gather"):
+            from ..ops import pallas_collectives as _pc
+            rdma = _pc.rdma_mode()
+            dtype_str = str(getattr(x, "dtype", "float32"))
+            # per-shape-class dispatch preference (advisor-written
+            # "rdma_dispatch" entry); an explicit DA_TPU_RDMA env wins inside
+            # resolve_dispatch, and a preference can only demote to XLA — it
+            # never conjures RDMA on a platform rdma_mode rejected
+            dispatch_key = _pc.dispatch_key_for(
+                "reshard", plan.strategy, *plan.shape, dtype_str, plan.nparts)
+            pref, dispatch_src = _pc.resolve_dispatch(dispatch_key)
+            if pref == "xla":
+                rdma = None
+            if rdma and plan.strategy == "all_to_all":
+                lshape = tuple(s // plan.nparts if d == plan.src_dim else s
+                               for d, s in enumerate(plan.shape))
+                # the kernel concats along the plan's src dim; clamping here
+                # keeps span/bench provenance equal to the depth it runs
+                rdma_chunks, chunks_src = _pc.a2a_chunks_for(
+                    lshape, dtype_str, plan.nparts, plan.src_dim)
+                # the exact "rdma_chunks" registry key this depth resolved
+                # under — the advisor addresses its writes by this label
+                autotune_key = _pc.a2a_chunks_key(lshape, dtype_str,
+                                                  plan.nparts)
     with _tm.span("reshard", op=op, strategy=plan.strategy,
                   dispatch="rdma" if rdma else "xla",
                   rdma_chunks=rdma_chunks, rdma_chunks_source=chunks_src,

@@ -102,6 +102,19 @@ if not _HOST:
     except Exception:  # pragma: no cover
         _HOST = "unknown"
 
+# the process id stamped beside it: taken once, and again in a forked
+# child (parallel/spmd_process.py forks rank children that journal)
+_PID = os.getpid()
+
+
+def _refresh_pid() -> None:
+    global _PID
+    _PID = os.getpid()
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_refresh_pid)
+
 # the innermost open tracing span (telemetry/tracing.py) on this
 # thread/context — read here so events and comm records are stamped with
 # the span they happened under.  A ContextVar, not thread-local: tasks
@@ -347,7 +360,7 @@ def event(category: str, name: str | None = None, *,
                "cat": category,
                "tid": threading.get_ident(),
                "host": _HOST,
-               "pid": os.getpid()}
+               "pid": _PID}
         if name is not None:
             rec["name"] = name
         if sp is not None and "span_id" not in fields:
@@ -358,10 +371,14 @@ def event(category: str, name: str | None = None, *,
         if _incident_id is not None and "incident" not in fields:
             rec["incident"] = _incident_id
         for k, v in fields.items():
-            rec[k] = _jsonable(v)
+            # most fields are plain scalars: spare them the call
+            rec[k] = v if type(v) in _SCALARS else _jsonable(v)
         _events_total += 1
         _events.append(rec)
         _write_journal_locked(rec)
+
+
+_SCALARS = frozenset((str, int, float, bool, type(None)))
 
 
 def _jsonable(v):
@@ -391,7 +408,7 @@ def begin_incident(kind: str = "failure") -> str | None:
         if _incident_id is not None:
             return _incident_id
         _incident_seq += 1
-        _incident_id = f"inc-{_HOST}-{os.getpid()}-{_incident_seq}"
+        _incident_id = f"inc-{_HOST}-{_PID}-{_incident_seq}"
         inc = _incident_id
     event("incident", "begin", kind=kind)
     return inc
@@ -452,7 +469,7 @@ def _write_journal_locked(rec: dict) -> None:
                        "t": round(time.monotonic() - _T0, 6),
                        "wall": round(time.time(), 3),
                        "cat": "journal", "name": "capped",
-                       "host": _HOST, "pid": os.getpid(),
+                       "host": _HOST, "pid": _PID,
                        "bytes_written": rotated,
                        "max_bytes": _journal_max}
                 _events_total += 1
@@ -466,7 +483,7 @@ def _write_journal_locked(rec: dict) -> None:
                       "t": round(time.monotonic() - _T0, 6),
                       "wall": round(time.time(), 3),
                       "cat": "journal", "name": "rotated",
-                      "host": _HOST, "pid": os.getpid(),
+                      "host": _HOST, "pid": _PID,
                       "rotated_to": _journal_path + ".1",
                       "rotation": _journal_rotations,
                       "bytes_rotated": rotated,

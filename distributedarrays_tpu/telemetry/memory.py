@@ -40,7 +40,6 @@ import itertools
 import os
 import sys
 import time
-import traceback
 import weakref
 
 from . import core
@@ -60,6 +59,11 @@ def _stack_enabled() -> bool:
     return v is None or v.strip().lower() not in core._FALSY
 
 
+# read once at import, like core._ENABLED: every DArray construction
+# passes here
+_STACK_ENABLED: bool = _stack_enabled()
+
+
 class _Entry:
     """One tracked device buffer.  ``owners`` is the set of DArray ids
     co-owning it (>1 after a ``_BufShare`` join); bytes are freed when
@@ -69,13 +73,13 @@ class _Entry:
                  "stack", "buf_ref", "buf_id", "t")
 
     def to_dict(self) -> dict:
-        # the stack is stored as raw FrameSummary objects (no line-text
-        # lookup, no string formatting on the allocation path) and only
-        # rendered here, when someone actually inspects the entry
+        # the stack is stored as raw (file, line, function) triples (no
+        # line-text lookup, no string formatting on the allocation path)
+        # and only rendered here, when someone actually inspects the entry
         stack = None
         if self.stack:
-            stack = [f"{os.path.basename(fr.filename)}:{fr.lineno}:"
-                     f"{fr.name}" for fr in reversed(self.stack)]
+            stack = [f"{os.path.basename(filename)}:{lineno}:{name}"
+                     for filename, lineno, name in reversed(self.stack)]
         return {"owners": [list(o) if isinstance(o, tuple) else o
                            for o in sorted(self.owners)],
                 "nbytes": self.nbytes,
@@ -126,15 +130,16 @@ def _capture_site():
     sp = core._CURRENT_SPAN.get()
     span = sp.name if sp is not None else None
     stack = None
-    if _stack_enabled():
-        try:
-            # lookup_lines=False: no linecache file reads on the hot
-            # path; frames are formatted lazily in _Entry.to_dict
-            stack = list(traceback.StackSummary.extract(
-                traceback.walk_stack(sys._getframe(2)),
-                limit=_STACK_DEPTH, lookup_lines=False))
-        except Exception:
-            stack = None
+    if _STACK_ENABLED:
+        # a plain walk up the frames: every DArray construction passes
+        # here, and traceback.StackSummary costs ten times as much for
+        # the same three facts a frame; rendered lazily in _Entry.to_dict
+        stack = []
+        frame = sys._getframe(2)
+        while frame is not None and len(stack) < _STACK_DEPTH:
+            code = frame.f_code
+            stack.append((code.co_filename, frame.f_lineno, code.co_name))
+            frame = frame.f_back
     return span, stack
 
 

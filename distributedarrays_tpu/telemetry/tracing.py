@@ -10,12 +10,20 @@ reshard, GEMM stage, or checkpoint phase that caused them.
 
 - :class:`span` — context manager: ``with span("matmul", grid="2x2"):``.
 - :func:`traced` — decorator form: ``@traced(name="reshard")``.
+- Every span is also a ``jax.profiler.TraceAnnotation`` named
+  ``"dat." + name``: while a profiler session runs, the span shows on
+  its host thread's line of the trace, on the profiler's clock, beside
+  the device's idle gaps.  With no session it is a flag check and no
+  annotation is made.  ``jax.profiler`` is looked up at the first span,
+  and only in a process that has loaded JAX, so this module still
+  imports without it.
 - Start times share the journal's monotonic origin (``core._T0``), so
   span intervals and journal events live on one timeline (and one
   Perfetto track per thread, see ``telemetry/export.py``).
 - Disabled telemetry (``DA_TPU_TELEMETRY=0``): entering a span is the
   same single boolean check as a counter — no ids, no contextvar write,
-  no journal, nothing allocated beyond the context-manager object.
+  no journal, no annotation, nothing allocated beyond the
+  context-manager object.
 
 Spans are *host-side* intervals.  Inside traced code (jit/shard_map
 bodies) a span measures trace time, like PR 1's ``traced=True`` comm
@@ -35,6 +43,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import sys
 import threading
 import time
 from collections import deque
@@ -47,6 +56,7 @@ __all__ = ["Span", "span", "traced", "current_span", "current_span_id",
 
 _SPAN_BUFFER_MAX = 8192
 _ids = itertools.count(1)        # CPython-atomic; no lock needed
+# finished journaled Spans; spans() renders them as dicts when read
 _finished: deque = deque(maxlen=_SPAN_BUFFER_MAX)
 _finished_total = 0
 # name -> {count, total_s, self_s, bytes, child_bytes}
@@ -54,6 +64,26 @@ _stats: dict[str, dict] = {}
 # span_id -> Span, for every span currently OPEN on any thread — the
 # flight recorder's "what was in progress when we crashed" snapshot
 _open: dict[int, "Span"] = {}
+
+ANNOTATION_PREFIX = "dat."
+_TraceAnnotation = None          # jax.profiler.TraceAnnotation, at first use
+
+
+def _open_annotation(name: str):
+    """Enter the profiler annotation of a span, or ``None`` while no
+    profiler session runs (one flag check, nothing made) and in a process
+    that has not loaded JAX (nothing there can run a session)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    if not _TraceAnnotation.is_enabled():
+        return None
+    ann = _TraceAnnotation(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
@@ -84,13 +114,23 @@ class Span:
         self.bytes = 0
         self.child_s = 0.0
         self.child_bytes = 0
-        t = threading.current_thread()
-        self.tid = t.ident or 0
-        self.tname = t.name
+        self.tid = threading.get_ident()
+        self.tname = None         # looked up by to_dict / the journal
 
     @property
     def self_s(self) -> float:
         return (self.dur or 0.0) - self.child_s
+
+    def thread_name(self) -> str:
+        """The name of the thread that opened the span ("" once that
+        thread is gone and nothing asked before)."""
+        if self.tname is None:
+            if self.tid == threading.get_ident():
+                self.tname = threading.current_thread().name
+            else:
+                self.tname = next((t.name for t in threading.enumerate()
+                                   if t.ident == self.tid), "")
+        return self.tname
 
     def to_dict(self) -> dict:
         d = {"name": self.name, "span_id": self.span_id,
@@ -98,7 +138,7 @@ class Span:
              "start": round(self.start, 6),
              "dur": round(self.dur, 6) if self.dur is not None else None,
              "bytes": self.bytes, "child_bytes": self.child_bytes,
-             "tid": self.tid, "tname": self.tname}
+             "tid": self.tid, "tname": self.thread_name()}
         if self.labels:
             d["labels"] = dict(self.labels)
         if self.trace:
@@ -119,7 +159,7 @@ class span:
     thousands of times per run (e.g. the SPMD mailbox drain), which
     would otherwise evict every other span from the buffer."""
 
-    __slots__ = ("_name", "_labels", "_journal", "_sp", "_tok")
+    __slots__ = ("_name", "_labels", "_journal", "_sp", "_tok", "_ann")
 
     def __init__(self, name: str, _journal: bool = True, **labels):
         self._name = name
@@ -130,6 +170,9 @@ class span:
     def __enter__(self):
         if not core._ENABLED:        # the single-boolean disabled path
             return None
+        # opened first and closed last: the annotation covers the span's
+        # own bookkeeping too, which the chip waits for like the rest
+        self._ann = _open_annotation(self._name)
         parent = core._CURRENT_SPAN.get()
         sp = Span(self._name, self._labels, parent, self._journal)
         self._tok = core._CURRENT_SPAN.set(sp)
@@ -145,6 +188,8 @@ class span:
         self._sp = None
         core._CURRENT_SPAN.reset(self._tok)
         _finish(sp, self._journal, error=exc_type is not None)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -160,7 +205,8 @@ def _finish(sp: Span, journal: bool, error: bool = False) -> None:
             parent.child_s += sp.dur
             parent.child_bytes += sp.bytes + sp.child_bytes
         if journal:
-            _finished.append(sp.to_dict())
+            sp.thread_name()         # looked up while its thread is here
+            _finished.append(sp)     # rendered by spans(), when read
         _finished_total += 1
         st = _stats.get(sp.name)
         if st is None:
@@ -307,8 +353,9 @@ def record_external_span(name: str, start: float, dur: float, *,
         sp.tid = tid
     if tname:
         sp.tname = tname
+    sp.thread_name()
     with core._LOCK:
-        _finished.append(sp.to_dict())
+        _finished.append(sp)
         _finished_total += 1
         st = _stats.get(sp.name)
         if st is None:
@@ -344,9 +391,7 @@ def spans(name: str | None = None) -> list[dict]:
     the complete per-name totals."""
     with core._LOCK:
         out = list(_finished)
-    if name is None:
-        return out
-    return [s for s in out if s["name"] == name]
+    return [s.to_dict() for s in out if name is None or s.name == name]
 
 
 def open_spans() -> list[dict]:

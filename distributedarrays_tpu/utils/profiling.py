@@ -7,20 +7,22 @@ framework's terms:
 
 - ``trace(dir)`` — context manager capturing a JAX/XLA profile (viewable
   in Perfetto / TensorBoard) around any block of DArray operations.
-- ``annotate(name)`` — named trace spans for host-side phases.
+- ``annotate(name)`` — a named telemetry span for a host-side phase.
 - ``op_timer()`` — lightweight wall-clock accounting of eager ops with
   marginal-cost support.
 
 Framework-level accounting (byte counts, reshard/fallback/retrace
 counters, the event journal, hierarchical spans) lives in
 ``distributedarrays_tpu.telemetry`` — this module is the deep-dive tier
-on top, and both hooks are REBASED on telemetry spans: ``annotate(name)``
-opens one telemetry span AND one ``jax.profiler.TraceAnnotation``, so a
-single annotation shows the phase on the XLA/Perfetto profile timeline
-and in the framework journal (with comm-byte attribution); ``OpTimer``
-times through the same span machinery (keeping its local totals and the
-``optimer.<name>`` histograms).  Profiler captures are journaled so a
-telemetry report names the trace directories that cover it.
+on top, and both hooks ARE telemetry spans: ``annotate(name)`` opens one
+span and nothing else, and since every telemetry span is itself a
+``jax.profiler.TraceAnnotation`` named ``dat.<name>``
+(``telemetry/tracing.py``), the phase shows on the XLA/Perfetto profile
+timeline and in the framework journal (with comm-byte attribution)
+through that one mechanism; ``OpTimer`` times through the same span
+machinery (keeping its local totals and the ``optimer.<name>``
+histograms).  Profiler captures are journaled so a telemetry report
+names the trace directories that cover it.
 """
 
 from __future__ import annotations
@@ -54,13 +56,11 @@ def trace(log_dir: str):
 
 @contextlib.contextmanager
 def annotate(name: str):
-    """Named span on BOTH timelines: the XLA profiler trace
-    (``jax.profiler.TraceAnnotation``) and the framework journal (a
-    telemetry span — comm/events inside are attributed to it).  One
-    annotation, both views."""
+    """A telemetry span named ``name``: comm/events inside are
+    attributed to it in the framework journal, and a profiler trace
+    shows it as ``dat.<name>`` like every other span."""
     with _tm.span(name, src="annotate"):
-        with jax.profiler.TraceAnnotation(name):
-            yield
+        yield
 
 
 class OpTimer:
