@@ -1512,7 +1512,7 @@ def main() -> int:
     # The general per-axis collective chain (PR 19) against the
     # device_put baseline it demotes: an 8192² two-axis repartition
     # ((p,1) -> (p/2,2), a single axis-wise all-to-all moving half the
-    # array) and a mesh-axis transpose (gather+a2a+slice).  Banks the
+    # array) and a mesh-axis transpose (one block exchange).  Banks the
     # chain strategy and the plan's intra/cross-domain byte split so the
     # row attributes the win to the hierarchical tier.
     def cfg_reshard_multiaxis():

@@ -795,25 +795,27 @@ def phase_multichip(ctx):
     # -- reshard through the planner, bit-equal to device_put -------------
     n = sz["n_reshard"]
     xh = np.asarray(rng.standard_normal((n, n)), np.float32)
-    for dst in ((1, p), (g, g)):
-        src = dat.distribute(xh, procs=ranks, dist=(p, 1))
+    # one all_to_all, a chain of one a2a, a chain of one block exchange
+    for frm, dst in (((p, 1), (1, p)), ((p, 1), (g, g)), ((1, p), (g, g))):
+        src = dat.distribute(xh, procs=ranks, dist=frm)
         _on_distinct_devices(ctx, "reshard source", src, p)
         like = dat.dzeros((n, n), procs=ranks, dist=dst)
         plan = R.plan_reshard(garr(src), like.sharding)
-        ctx.require(f"reshard ({p},1)->{dst}: planned as a collective",
+        ctx.require(f"reshard {frm}->{dst}: planned as a collective",
                     plan.collective, strategy=plan.strategy)
         out, cold, warm = _timed(
             lambda: R.reshard(garr(src), like.sharding))
         put = jax.device_put(garr(src), like.sharding)
         got_sh, put_sh = _shards(out), _shards(put)
-        ctx.require(f"reshard ({p},1)->{dst}: placed as asked",
+        ctx.require(f"reshard {frm}->{dst}: placed as asked",
                     out.sharding.is_equivalent_to(like.sharding, 2))
         ctx.require(
-            f"reshard ({p},1)->{dst} {n}^2: bit-equal to device_put",
+            f"reshard {frm}->{dst} {n}^2: bit-equal to device_put",
             got_sh.keys() == put_sh.keys()
             and all(np.array_equal(v, put_sh[d]) for d, v in got_sh.items())
             and np.array_equal(np.asarray(out), xh),
             strategy=plan.strategy,
+            steps=[s[0] for s in plan.steps],
             dispatch=tm.spans("reshard")[-1]["labels"].get("dispatch"),
             cold_s=cold, seconds=warm)
         del out, put, got_sh, put_sh

@@ -40,7 +40,12 @@ program is what makes it scale.  This module is that planner:
    ``all_to_all`` for an axis moving between array dims, an
    ``all_gather`` for an axis leaving, a local dynamic-slice for an axis
    appearing — composed as one compiled shard_map *chain* (strategy
-   ``chain``).  Start-aligned ceil-uneven layouts ride the same chain
+   ``chain``).  Where that schedule would gather an axis only to slice
+   it back later (the axis to move is not the minor one of its dim),
+   the detour becomes one direct block *exchange*: every rank sends each
+   piece ``source block ∩ destination block`` straight to its new owner,
+   in rounds of one ``ppermute`` each.  Start-aligned ceil-uneven
+   layouts ride the same chain
    between a comm-free pad and slice-back; device-set-shrinking moves
    whose destination is replicated enough gather collectively on the
    source mesh first (``gather_put``).  The chain planner is
@@ -122,9 +127,12 @@ class ReshardPlan:
     Chain plans also carry: ``mesh_shape`` (refined mesh axis sizes,
     major→minor over the canonical rank order ``ranks``), ``src_comp`` /
     ``dst_comp`` (per array dim, the mesh-axis indices sharding it,
-    major→minor), ``steps`` (the scheduled per-axis ops, each
+    major→minor), ``steps`` (the scheduled ops, each
     ``(kind, axis, q, src_dim, dst_dim, chunk_axis, nchunks,
-    moved_bytes)``), ``pad_shape`` (ceil-uneven layouts: the even analog
+    moved_bytes)`` with kind ``a2a``, ``gather`` or ``slice`` along one
+    mesh axis; an ``exchange`` step has axis -1, ``q`` rounds, and in
+    place of the two dims the composites before and after it),
+    ``pad_shape`` (ceil-uneven layouts: the even analog
     the chain runs on, between a comm-free pad and slice-back),
     ``staging_bytes`` (the worst step's staging piece) and the
     topology split ``intra_bytes``/``cross_bytes``."""
@@ -382,14 +390,14 @@ def _digitize(ndim, s_grid, s_own, d_grid, d_own):
     return tuple(canon), sizes, strides, comps[0], comps[1]
 
 
-def _digit_cross_domain(canon, q, t):
-    """True when some sub-group along this digit spans failure domains —
-    an exchange along it rides the DCN, not fast intra-domain links."""
+def _domain_of():
+    """``rank -> failure domain`` under the current topology, or None when
+    there is none (every byte then counts as intra-domain)."""
     try:
         from ..resilience import domains as _dom
         topo = _dom.topology()
     except Exception:
-        return False
+        return None
 
     def dom(r):
         try:
@@ -397,6 +405,15 @@ def _digit_cross_domain(canon, q, t):
         except KeyError:
             return ("uncovered", r)
 
+    return dom
+
+
+def _digit_cross_domain(canon, q, t):
+    """True when some sub-group along this digit spans failure domains —
+    an exchange along it rides the DCN, not fast intra-domain links."""
+    dom = _domain_of()
+    if dom is None:
+        return False
     nr = len(canon)
     for base in range(nr):
         if (base // t) % q:
@@ -480,6 +497,133 @@ def _schedule_chain(sizes, src_comp, dst_comp, cross):
     return None
 
 
+def _unravel(flat, radices):
+    """Row-major digits of ``flat`` over ``radices``.  With
+    :func:`_ravel`, the index arithmetic of the block exchange: on numpy
+    arrays over all ranks at plan time, on traced scalars inside the
+    compiled program."""
+    out = []
+    for q in reversed(radices):
+        out.append(flat % q)
+        flat = flat // q
+    return out[::-1]
+
+
+def _ravel(digits, radices):
+    """Row-major flat index of ``digits`` over ``radices``."""
+    flat = 0
+    for v, q in zip(digits, radices):
+        flat = flat * q + v
+    return flat
+
+
+def _exchange_ratios(sizes, a_comp, b_comp, shape=None):
+    """``(r, s)`` of a direct block exchange between two composite
+    layouts of one refined mesh: per dim, ``r`` pieces of a source block
+    go to ``r`` different destination blocks (the dim gains digits) and
+    ``s`` pieces from ``s`` source blocks fill a destination block (it
+    loses digits).  None when the exchange does not apply: the two
+    states place different digits (net replication is a ``gather``'s or
+    a ``slice``'s job), or a dim's block counts (or, where the array's
+    ``shape`` is given, its extent) do not divide, so the pieces
+    ``source block ∩ destination block`` have no one shape."""
+    if sorted(m for c in a_comp for m in c) != \
+            sorted(m for c in b_comp for m in c):
+        return None
+    r, s = [], []
+    for d, (ca, cb) in enumerate(zip(a_comp, b_comp)):
+        pa = math.prod(sizes[m] for m in ca)
+        pb = math.prod(sizes[m] for m in cb)
+        if (pa % pb and pb % pa) or \
+                (shape is not None and shape[d] % max(pa, pb)):
+            return None
+        r.append(max(pb // pa, 1))
+        s.append(max(pa // pb, 1))
+    return tuple(r), tuple(s)
+
+
+def _exchange_slots(sizes, a_comp, b_comp, r, s, coords):
+    """What a rank at ``coords`` needs to take part in every round:
+    its source block index per dim, the flat slot ``j`` that every piece
+    it sends fills in its destination block, and the flat index ``k``
+    that every piece it receives has in its source block.  Round ``t``
+    carries the pieces with ``(k + j) % rounds == t``: the pieces a rank
+    sends differ in ``k`` and those it receives differ in ``j``, so in a
+    round each rank sends at most one piece and receives at most one."""
+    def block(comp):        # per dim, the block index its digits spell
+        return [_ravel([coords[m] for m in c], [sizes[m] for m in c])
+                for c in comp]
+
+    a, b = block(a_comp), block(b_comp)
+    j = _ravel([ad % sd for ad, sd in zip(a, s)], s)
+    k = _ravel([bd % rd for bd, rd in zip(b, r)], r)
+    return a, j, k
+
+
+@functools.lru_cache(maxsize=256)
+def _exchange_dests(sizes, a_comp, b_comp, r, s):
+    """Per round, the receiving rank (linear index over the refined
+    mesh) of the piece each rank sends — None unless every round is a
+    permutation of the ranks."""
+    nr = math.prod(sizes)
+    coords = list(np.indices(sizes).reshape(len(sizes), nr))
+    a, j, _k = _exchange_slots(sizes, a_comp, b_comp, r, s, coords)
+    rounds = math.prod(r)
+    dests = []
+    for t in range(rounds):
+        k = _unravel((t - j) % rounds, r)
+        to = list(coords)                    # digits neither side places
+        for d, cb in enumerate(b_comp):      # stay: the exchange runs in
+            blk = (a[d] * r[d] + k[d]) // s[d]     # each replica group
+            for m, v in zip(cb, _unravel(blk, [sizes[m] for m in cb])):
+                to[m] = v
+        dest = _ravel(to, sizes)
+        if len(set(dest.tolist())) != nr:
+            return None
+        dests.append(tuple(int(v) for v in dest))
+    return tuple(dests)
+
+
+def _fuse_detours(ops, sizes, src_comp, work):
+    """Replace every detour of the schedule — a digit gathered and later
+    sliced back, with whatever runs in between — by ONE ``exchange`` op
+    from the state before the gather to the state after the slice.  The
+    detour moves whole blocks to drop most of them; the exchange moves
+    only the pieces that change owner.  Ops outside a detour, and a
+    detour the exchange cannot express, stay as scheduled."""
+    states = [tuple(tuple(c) for c in src_comp)]
+    for kind, m, i, j in ops:
+        st = [list(c) for c in states[-1]]
+        if kind in ("a2a", "gather"):
+            st[i].pop()
+        if kind in ("a2a", "slice"):
+            st[j].append(m)
+        states.append(tuple(tuple(c) for c in st))
+    out, g = [], 0
+    while g < len(ops):
+        end = g
+        while ops[g][0] == "gather":
+            # the detour ends at the last slice of a digit gathered in it
+            gathered = {op[1] for op in ops[g:end + 1] if op[0] == "gather"}
+            last = max((e for e in range(end + 1, len(ops))
+                        if ops[e][0] == "slice" and ops[e][1] in gathered),
+                       default=end)
+            if last == end:
+                break
+            end = last
+        if end > g:
+            a, b = states[g], states[end + 1]
+            ratios = _exchange_ratios(sizes, a, b, work)
+            if ratios is not None and \
+                    _exchange_dests(sizes, a, b, *ratios) is not None:
+                out.append(("exchange", None, a, b))
+                g = end + 1
+                continue
+        out.append(ops[g])
+        g += 1
+    return out
+
+
 def _pick_step_chunking(local, itemsize, concat_dim, split_dim, q,
                         chunk_target):
     """(chunk_axis, nchunks) for one chain step — :func:`_pick_chunking`
@@ -497,7 +641,8 @@ def _pick_step_chunking(local, itemsize, concat_dim, split_dim, q,
             cands.append((units, d))
     if not cands:
         return -1, 1
-    units, axis = max(cands)
+    # of equally long axes the major one: its chunks are contiguous
+    units, axis = max(cands, key=lambda c: (c[0], -c[1]))
     return axis, _smallest_divisor_at_least(units, min(want, units))
 
 
@@ -511,9 +656,31 @@ def _chain_steps(shape, itemsize, sizes, strides, src_comp, ops, canon,
              for d in range(len(shape))]
     steps = []
     moved = staging = intra = crossb = 0
+    dom = _domain_of()
     for kind, m, i, j in ops:
-        q = sizes[m]
         lelems = math.prod(local) if local else 1
+        if kind == "exchange":
+            # i, j are the composites before and after; the piece is what
+            # one rank sends one peer, and the only transient
+            r, s = _exchange_ratios(sizes, i, j)
+            dests = _exchange_dests(sizes, i, j, r, s)
+            piece = [n // rd for n, rd in zip(local, r)]
+            ca, nc = _pick_step_chunking(piece, itemsize, None, None, 1,
+                                         chunk_target)
+            pbytes = math.prod(piece) * itemsize
+            sent = [(c, to) for dest in dests for c, to in enumerate(dest)
+                    if to != c]
+            far = sum(1 for c, to in sent
+                      if dom is not None and dom(canon[c]) != dom(canon[to]))
+            mstep = len(sent) * pbytes
+            moved += mstep
+            crossb += far * pbytes
+            intra += (len(sent) - far) * pbytes
+            staging = max(staging, -(-pbytes // nc))
+            local = [p * sd for p, sd in zip(piece, s)]
+            steps.append((kind, -1, len(dests), i, j, ca, nc, mstep))
+            continue
+        q = sizes[m]
         ca, nc, mstep, stg = -1, 1, 0, 0
         if kind == "a2a":
             ca, nc = _pick_step_chunking(local, itemsize, i, j, q,
@@ -565,6 +732,7 @@ def _try_chain(shape, itemsize, s_grid, s_own, d_grid, d_own, total,
     ops = _schedule_chain(sizes, src_comp, dst_comp, cross)
     if not ops:
         return None
+    ops = _fuse_detours(ops, sizes, src_comp, work)
     steps, moved, staging, intra, crossb = _chain_steps(
         work, itemsize, sizes, strides, src_comp, ops, canon, cross,
         chunk_target)
@@ -917,6 +1085,54 @@ def _comp_spec(comp, ndim):
     return P(*entries)
 
 
+def _exchange_blocks(x, names, sizes, a_comp, b_comp, chunk_axis, nchunks):
+    """The direct block exchange of a chain, inside its shard_map body:
+    per round every rank cuts the piece that leaves it out of its source
+    block, one ``ppermute`` over the whole refined mesh carries the
+    pieces, and each lands at its final offset in the output block.  A
+    piece whose owner does not change is copied locally.  Chunked along
+    ``chunk_axis`` so one transient stays under the chunk target."""
+    r, s = _exchange_ratios(sizes, a_comp, b_comp)
+    dests = _exchange_dests(sizes, a_comp, b_comp, r, s)
+    rounds = len(dests)
+    coords = [lax.axis_index(n) for n in names]
+    _a, j, k = _exchange_slots(sizes, a_comp, b_comp, r, s, coords)
+    me = lax.axis_index(names)
+    piece = [n // rd for n, rd in zip(x.shape, r)]
+    chunk = list(piece)
+    if nchunks > 1:
+        chunk[chunk_axis] //= nchunks
+    # every element of the output block is written by exactly one piece
+    out = lax.empty(tuple(p * sd for p, sd in zip(piece, s)), x.dtype)
+    sends = []
+    for t, dest in enumerate(dests):
+        # the piece this rank sends in round t, and the slot the piece
+        # it receives fills (see _exchange_slots)
+        at_src = [kd * p for kd, p in
+                  zip(_unravel((t - j) % rounds, r), piece)]
+        at_dst = [jd * p for jd, p in
+                  zip(_unravel((t - k) % rounds, s), piece)]
+        pairs = [(c, to) for c, to in enumerate(dest) if to != c]
+        keeps = jnp.asarray([to == c for c, to in enumerate(dest)])[me] \
+            if len(pairs) < len(dest) else None
+        sends.append((at_src, at_dst, pairs, keeps))
+    # chunk by chunk through all rounds, so that the rounds' transfers,
+    # which ride different links, are in flight together
+    for c in range(nchunks):
+        step = [c * chunk[d] if d == chunk_axis else 0
+                for d in range(x.ndim)]
+        for at_src, at_dst, pairs, keeps in sends:
+            got = part = lax.dynamic_slice(
+                x, [o + e for o, e in zip(at_src, step)], chunk)
+            if pairs:
+                got = lax.ppermute(part, names, pairs)
+                if keeps is not None:
+                    got = jnp.where(keeps, part, got)
+            out = lax.dynamic_update_slice(
+                out, got, [o + e for o, e in zip(at_dst, step)])
+    return out
+
+
 @functools.lru_cache(maxsize=512)
 def _chain_jit(mesh, ndim, src_comp, dst_comp, steps, rdma=None):
     """ONE compiled shard_map program running a planned per-axis
@@ -959,6 +1175,11 @@ def _chain_jit(mesh, ndim, src_comp, dst_comp, steps, rdma=None):
                     else:
                         x = _gather_chunked(x, name, i,
                                             ca if ca >= 0 else None, nc)
+                elif kind == "exchange":
+                    # i, j: the composites before and after; ppermute
+                    # whether or not the ring kernels are armed
+                    x = _exchange_blocks(x, tuple(names), mesh.axis_sizes,
+                                         i, j, ca, nc)
                 else:                        # slice: local, no comm
                     r = lax.axis_index(name)
                     blk = x.shape[j] // q
@@ -1001,6 +1222,8 @@ def _run_chain(x, dst_sharding, plan: ReshardPlan, rdma=None):
             x = _pad_jit(mesh, plan.src_comp, plan.shape,
                          plan.pad_shape)(x)
         y = fn(x)
+        for step in plan.steps:
+            _tm.count("reshard.chain_steps", kind=step[0])
         if plan.pad_shape:
             return _slice_back_jit(dst_sharding, plan.shape)(y)
         if plan.strategy == "gather_put":
@@ -1099,10 +1322,11 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
         autotune_key = ""
         dispatch_key = ""
         dispatch_src = ""
-        if plan.steps and any(s[0] != "slice" for s in plan.steps):
-            # chain steps ride the ring kernels when the platform arms them
-            # (mesh-coordinate addressing on multi-axis meshes); slices-only
-            # chains are local and need no dispatch decision
+        if any(s[0] in ("a2a", "gather") for s in plan.steps):
+            # a2a and gather steps ride the ring kernels when the platform
+            # arms them (mesh-coordinate addressing on multi-axis meshes);
+            # an exchange is ppermutes either way and a slice is local, so
+            # a chain of those alone ran "xla" whatever is armed
             from ..ops import pallas_collectives as _pc
             rdma = _pc.rdma_mode()
         elif plan.collective and plan.strategy in ("all_to_all", "all_gather"):

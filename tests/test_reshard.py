@@ -528,20 +528,87 @@ def test_dal007_suppressible():
 # ---------------------------------------------------------------------------
 
 
-def test_chain_matches_oracle_multiaxis_pairs(rng):
+def _needed_bytes(shape, itemsize, src, dst):
+    """What the two layouts need moved, from the block algebra alone."""
+    s_cuts, s_own = R.layout_of_sharding(src, shape)
+    d_cuts, d_own = R.layout_of_sharding(dst, shape)
+    return R._moved_elems(shape, s_cuts, s_own, d_cuts, d_own) * itemsize
+
+
+def _assert_shards_equal_device_put(y, A, dst):
+    want = {s.device.id: np.asarray(s.data)
+            for s in jax.device_put(A, dst).addressable_shards}
+    got = {s.device.id: np.asarray(s.data) for s in y.addressable_shards}
+    assert got.keys() == want.keys()
+    for dev, block in got.items():
+        np.testing.assert_array_equal(block, want[dev])
+
+
+def _last_reshard_labels(tm):
+    return tm.spans("reshard")[-1]["labels"]
+
+
+_GRID_SETS = [[(4, 1), (1, 4), (2, 2)], [(8, 1), (1, 8), (4, 2), (2, 4)]]
+_GRID_PAIRS = [pair for grids in _GRID_SETS
+               for pair in itertools.permutations(grids, 2)]
+
+
+@pytest.mark.parametrize("gs,gd", _GRID_PAIRS,
+                         ids=[f"{a}->{b}".replace(" ", "")
+                              for a, b in _GRID_PAIRS])
+def test_block_layout_pair_moves_what_it_needs(telemetry_capture, rng,
+                                               gs, gd):
+    # every repartition between upstream's block layouts on 4 and on 8
+    # ranks: the plan moves exactly what the two layouts need (no digit
+    # is gathered only to be sliced away), every shard is bit-equal to
+    # device_put's, and the span says which dispatch ran
+    tm = telemetry_capture
+    shape = (48, 64)
+    A = rng.standard_normal(shape).astype(np.float32)
+    src, dst = _shardings_for(shape, gs), _shardings_for(shape, gd)
+    x = jax.device_put(A, src)
+    plan = R.plan_reshard(x, dst)
+    assert plan.collective, (plan.strategy, plan.reason)
+    assert plan.moved_bytes == _needed_bytes(shape, 4, src, dst)
+    gathered = {s[1] for s in plan.steps if s[0] == "gather"}
+    sliced = {s[1] for s in plan.steps if s[0] == "slice"}
+    assert not gathered & sliced, plan.steps
+    fb0 = tm.counter_value("reshard.collective_fallbacks", reason="runtime")
+    b0 = tm.comm_bytes("reshard")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        y = R.reshard(x, dst)
+    assert y.sharding.is_equivalent_to(dst, y.ndim)
+    _assert_shards_equal_device_put(y, A, dst)
+    assert tm.counter_value("reshard.collective_fallbacks",
+                            reason="runtime") == fb0
+    assert tm.comm_bytes("reshard") - b0 == plan.moved_bytes
+    labels = _last_reshard_labels(tm)
+    assert labels["strategy"] == plan.strategy
+    assert labels["dispatch"] == "xla"      # no ring kernel armed on a CPU
+
+
+def test_chain_matches_oracle_multiaxis_pairs(telemetry_capture, rng):
     # same-device-set multi-axis repartitions lower to the collective
-    # chain (NOT device_put) and stay bit-identical to the oracle
+    # chain (NOT device_put) and stay bit-identical to the oracle; the
+    # counter says which kinds of step ran
+    tm = telemetry_capture
     shape = (48, 48)
     A = rng.standard_normal(shape).astype(np.float32)
-    for gs, gd in [((8, 1), (4, 2)), ((4, 2), (8, 1)), ((4, 2), (2, 4)),
-                   ((2, 4), (4, 2)), ((1, 8), (4, 2)), ((2, 2), (4, 1))]:
+    want = {((8, 1), (4, 2)): ["a2a"], ((4, 2), (8, 1)): ["a2a"],
+            ((4, 2), (2, 4)): ["exchange"], ((2, 4), (4, 2)): ["exchange"],
+            ((1, 8), (4, 2)): ["exchange"], ((2, 2), (4, 1)): ["a2a"]}
+    for (gs, gd), kinds in want.items():
         src, dst = _shardings_for(shape, gs), _shardings_for(shape, gd)
         x = jax.device_put(A, src)
         plan = R.plan_reshard(x, dst)
         assert plan.strategy == "chain", (gs, gd, plan.strategy,
                                           plan.reason)
-        assert all(s[0] in ("a2a", "gather", "slice") for s in plan.steps)
+        assert [s[0] for s in plan.steps] == kinds, (gs, gd)
+        ran0 = tm.counter_value("reshard.chain_steps", kind=kinds[0])
         y = R.reshard(x, dst)
+        assert tm.counter_value("reshard.chain_steps",
+                                kind=kinds[0]) == ran0 + 1
         assert y.sharding.is_equivalent_to(dst, y.ndim), (gs, gd)
         np.testing.assert_array_equal(
             np.asarray(y), np.asarray(jax.device_put(A, dst)))
@@ -563,8 +630,11 @@ def test_chain_two_axis_repartition_halves_moved_bytes(rng):
         np.asarray(jax.device_put(A, dst)))
 
 
-def test_chain_mesh_axis_transpose(rng):
-    # P(d0,d1) -> P(d1,d0) on one (4,2) mesh: gather + a2a + slice
+def test_chain_mesh_axis_transpose(telemetry_capture, rng):
+    # P(d0,d1) -> P(d1,d0) on one (4,2) mesh: neither digit is the minor
+    # one of a dim it could leave, so the whole move is one exchange of
+    # eighths of a block (it was gather + a2a + slice, 2.5x the array)
+    tm = telemetry_capture
     shape = (48, 48)
     A = rng.standard_normal(shape).astype(np.float32)
     mesh = L.mesh_for(list(range(8)), (4, 2))
@@ -573,11 +643,56 @@ def test_chain_mesh_axis_transpose(rng):
     x = jax.device_put(A, src)
     plan = R.plan_reshard(x, dst)
     assert plan.strategy == "chain", plan.reason
-    assert "a2a" in [s[0] for s in plan.steps]
-    y = R.reshard(x, dst)
+    assert [s[0] for s in plan.steps] == ["exchange"]
+    assert plan.moved_bytes == _needed_bytes(shape, 4, src, dst)
+    assert plan.moved_bytes * 4 == plan.total_bytes * 3
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        y = R.reshard(x, dst)
     assert y.sharding.is_equivalent_to(dst, y.ndim)
-    np.testing.assert_array_equal(
-        np.asarray(y), np.asarray(jax.device_put(A, dst)))
+    _assert_shards_equal_device_put(y, A, dst)
+    assert _last_reshard_labels(tm)["dispatch"] == "xla"
+
+
+@pytest.mark.parametrize("mode", ["interpret", "0"])
+def test_exchange_is_ppermutes_whatever_is_armed(telemetry_capture, rng,
+                                                 monkeypatch, mode):
+    # armed ring kernels (interpret) or none: an exchange-only chain runs
+    # the same ppermutes, dispatches no ring kernel and says "xla"; a
+    # chain with an a2a step says what its a2a rode
+    tm = telemetry_capture
+    monkeypatch.setenv("DA_TPU_RDMA", mode)
+    shape = (32, 64)
+    A = rng.standard_normal(shape).astype(np.float32)
+
+    def ring_dispatches():
+        return sum(tm.counter_value("pallas_collectives.dispatch", path=p)
+                   for p in ("rdma", "interpret", "compiled", "lax",
+                             "fallback"))
+
+    src, dst = _shardings_for(shape, (1, 4)), _shardings_for(shape, (2, 2))
+    x = jax.device_put(A, src)
+    plan = R.plan_reshard(x, dst)
+    assert [s[0] for s in plan.steps] == ["exchange"]
+    d0 = ring_dispatches()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        y = R.reshard(x, dst)
+    _assert_shards_equal_device_put(y, A, dst)
+    assert ring_dispatches() == d0
+    assert _last_reshard_labels(tm)["dispatch"] == "xla"
+    text = R._chain_jit(L.mesh_for(list(plan.ranks), plan.mesh_shape), 2,
+                        plan.src_comp, plan.dst_comp, plan.steps,
+                        None).lower(x).as_text()
+    assert "collective_permute" in text
+    assert "all_gather" not in text and "all_to_all" not in text
+    # the leg after it in the benchmark's cycle is one a2a
+    back = _shardings_for(shape, (4, 1))
+    assert [s[0] for s in R.plan_reshard(y, back).steps] == ["a2a"]
+    z = R.reshard(y, back)
+    _assert_shards_equal_device_put(z, A, back)
+    assert _last_reshard_labels(tm)["dispatch"] == \
+        ("rdma" if mode == "interpret" else "xla")
 
 
 def test_chain_matches_oracle_3d_mesh(rng):
@@ -650,10 +765,45 @@ def test_gather_put_on_replicated_subset(rng):
     x = jax.device_put(A, src)
     plan = R.plan_reshard(x, dst)
     assert plan.strategy == "gather_put", plan.reason
+    # a gather that nothing slices back is no detour: it stays a gather
+    assert {s[0] for s in plan.steps} == {"gather"}
     y = R.reshard(x, dst)
     assert {d.id for d in y.sharding.device_set} == set(range(6))
     np.testing.assert_array_equal(
         np.asarray(y), np.asarray(jax.device_put(A, dst)))
+
+
+def test_replicated_destination_still_gathers(rng):
+    # a multi-axis grid onto a fully replicated layout: every digit
+    # leaves for good, so the plan is gathers and the whole array
+    # arrives on every rank but the one block it had
+    shape = (48, 48)
+    A = rng.standard_normal(shape).astype(np.float32)
+    src = _shardings_for(shape, (4, 2))
+    dst = NamedSharding(src.mesh, P(None, None))
+    x = jax.device_put(A, src)
+    plan = R.plan_reshard(x, dst)
+    assert plan.strategy == "chain", plan.reason
+    assert {s[0] for s in plan.steps} == {"gather"}
+    assert plan.moved_bytes == _needed_bytes(shape, 4, src, dst)
+    np.testing.assert_array_equal(np.asarray(R.reshard(x, dst)), A)
+
+
+def test_exchange_plan_at_8gib_stages_one_chunk():
+    # the shape ISSUE 23 asked of the benchmark's cycle (32768 x 65536
+    # f32 over 2x2): leg 2 is one exchange of 0.75 of the array whose
+    # transient is a chunk of a piece, not a doubled block
+    shape = (32768, 65536)
+    src, dst = _shardings_for(shape, (1, 4)), _shardings_for(shape, (2, 2))
+    plan = R.plan_reshard(shape, dst, src_sharding=src, itemsize=4)
+    assert [s[0] for s in plan.steps] == ["exchange"]
+    assert plan.moved_bytes * 4 == plan.total_bytes * 3
+    assert 0 < plan.staging_bytes <= R._chunk_target_bytes()
+    kind, axis, rounds, before, after, chunk_axis, nchunks, moved = \
+        plan.steps[0]
+    assert (axis, rounds) == (-1, 2)
+    assert (before, after) == (plan.src_comp, plan.dst_comp)
+    assert nchunks == plan.nchunks > 1 and moved == plan.moved_bytes
 
 
 def test_chain_plan_stamps_domain_byte_split(rng, monkeypatch):
@@ -674,15 +824,16 @@ def test_chain_plan_stamps_domain_byte_split(rng, monkeypatch):
         # sub-groups {0,1},{2,3},... never span the 4|4 domain boundary
         assert plan.cross_bytes == 0
         assert plan.intra_bytes == plan.moved_bytes > 0
-        # transpose on the (4,2) mesh must touch the major axis -> the
-        # gather/a2a sub-groups span both domains
+        # transpose on the (4,2) mesh is one exchange, split piece by
+        # piece: some pieces change domain, some stay inside one
         mesh = L.mesh_for(list(range(8)), (4, 2))
         tsrc = NamedSharding(mesh, P("d0", "d1"))
         tdst = NamedSharding(mesh, P("d1", "d0"))
         xt = jax.device_put(A, tsrc)
         tplan = R.plan_reshard(xt, tdst)
         assert tplan.strategy == "chain"
-        assert tplan.cross_bytes > 0
+        assert [s[0] for s in tplan.steps] == ["exchange"]
+        assert 0 < tplan.cross_bytes < tplan.moved_bytes
         assert tplan.intra_bytes + tplan.cross_bytes == tplan.moved_bytes
         np.testing.assert_array_equal(
             np.asarray(R.reshard(xt, tdst)),
@@ -761,6 +912,20 @@ def test_pad_chain_plans_for_agreeing_ceil_cuts():
     assert p.strategy == "chain"
     assert p.pad_shape == (16, 8)
     assert [s[0] for s in p.steps] == ["a2a"]
+
+
+def test_pad_chain_exchanges_on_the_even_analog():
+    # (1,4)->(2,2) with 7 rows: the destination's ceil cuts pad to 8, and
+    # the exchange runs on the (8,8) analog between pad and slice-back
+    p = R._build_plan(
+        (7, 8), 4,
+        _FakeSharding([[0, 7], [0, 2, 4, 6, 8]], list(range(4))),
+        _FakeSharding([_ceil_cuts(7, 2), [0, 4, 8]], list(range(4))),
+        R._chunk_target_bytes())
+    assert p.strategy == "chain"
+    assert p.pad_shape == (8, 8)
+    assert [s[0] for s in p.steps] == ["exchange"]
+    assert p.moved_bytes == 8 * 8 * 4 * 3 // 4
 
 
 def test_pad_chain_rejects_disagreeing_or_arbitrary_cuts():

@@ -125,6 +125,7 @@ def test_public_entry_leaves_exactly_one_root_span(entry):
 def test_reshard_host_phases_are_counted_once_a_leg():
     d = dat.drand((64, 64), procs=range(8), dist=(4, 2))
     before = tm.span_stats()
+    ran = tm.counter_value("reshard.chain_steps", kind="exchange")
     dat.distribute(d, procs=range(8), dist=(2, 4))
     after = tm.span_stats()
     for phase in ("reshard.plan", "reshard.program", "reshard.dispatch",
@@ -133,6 +134,34 @@ def test_reshard_host_phases_are_counted_once_a_leg():
                 - before.get(phase, {"count": 0})["count"]) == 1, phase
     # aggregate-only: counted, never buffered
     assert not tm.spans("reshard.dispatch")
+    # (4,2)->(2,4) is one exchange step, counted once when it ran
+    assert tm.counter_value("reshard.chain_steps",
+                            kind="exchange") == ran + 1
+
+
+@pytest.mark.parametrize("grids,scope", [
+    (((1, 4), (2, 2)), "reshard.chain/step0.exchange"),
+    (((4, 1), (2, 2)), "reshard.chain/step0.a2a"),
+    (((4, 1), (1, 4)), "reshard.all_to_all"),
+])
+def test_reshard_programs_carry_their_scopes(grids, scope):
+    # the scopes docs/telemetry.md lists reach the lowered program's
+    # op names (the compiled HLO keeps them as op_name)
+    from distributedarrays_tpu import layout as L
+    from distributedarrays_tpu.parallel import reshard as R
+    shape = (32, 64)
+    src, dst = (L.sharding_for(list(range(4)), g, shape) for g in grids)
+    x = jax.device_put(np.zeros(shape, np.float32), src)
+    plan = R.plan_reshard(x, dst)
+    if plan.steps:
+        fn = R._chain_jit(L.mesh_for(list(plan.ranks), plan.mesh_shape), 2,
+                          plan.src_comp, plan.dst_comp, plan.steps, None)
+    else:
+        fn = R._collective_jit(L.mesh_for(list(plan.ranks), (plan.nparts,)),
+                               plan.strategy, 2, plan.src_dim, plan.dst_dim,
+                               plan.nparts, plan.chunk_axis, plan.nchunks,
+                               None)
+    assert scope in fn.lower(x).as_text(debug_info=True)
 
 
 # ---------------------------------------------------------------------------

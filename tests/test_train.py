@@ -155,9 +155,12 @@ def test_straggler_probe_confirms_dead_rank_and_recovers(tmp_path):
     # shrinks + recomputes deterministically
     s0 = tm.counter_value("train.stragglers")
     r0 = tm.counter_value("recovery.retries", verdict="device_loss")
+    # the budget is 3x the slowest earlier step, and the first steps
+    # compile: the hang has to outlast that on a slow or loaded host
+    # (at 0.6 s the budget was missed whenever a step took 0.2 s)
     faults.configure(plan=[
         {"site": "train.step", "match": {"step": 6}, "action": "hang",
-         "hang_s": 0.6, "at": 1, "count": 1, "device": 2}], seed=7)
+         "hang_s": 2.0, "at": 1, "count": 1, "device": 2}], seed=7)
     det = StragglerDetector(factor=3.0, min_budget_s=0.3, warmup=3)
     with _trainer(tmp_path, straggler=det) as t:
         res = t.fit(8)
