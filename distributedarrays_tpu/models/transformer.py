@@ -143,8 +143,10 @@ def _attention(x, blk, heads):
         return jnp.transpose(t.reshape(B, Spad, heads, D),
                              (1, 0, 2, 3)).reshape(Spad, B * heads, D)
 
-    o = flash_attention(fold(q), fold(k), fold(v), causal=True,
-                        block_q=bs, block_k=bs)
+    # blocks, fold and the form of the backward are flash_attention's own
+    # choice from (Spad, D): the padding above only keeps Spad a multiple
+    # of what it will pick
+    o = flash_attention(fold(q), fold(k), fold(v), causal=True)
     o = jnp.transpose(o.reshape(Spad, B, heads, D),
                       (1, 0, 2, 3)).reshape(B, Spad, E)[:, :S]
     return o @ blk["proj"]

@@ -104,3 +104,216 @@ def test_flash_autotune_three_tuple_entry(qkv):
     got = np.asarray(flash_attention(q, k, v))   # degrades, still correct
     assert np.abs(got - want).max() < 1e-5
     autotune.clear()
+
+
+# ---------------------------------------------------------------------------
+# every body the kernels have (unmasked tile, diagonal tile, clamped dead
+# step, fused and two-pass backward), against the dense differentiation rule
+# ---------------------------------------------------------------------------
+
+
+def _attention_pad(S):
+    # models/transformer._attention's padding rule
+    bs = next(b for b in (512, 256, 128, 64, 32)
+              if b == 32 or (-(-S // b) * b - S) * 8 <= S)
+    return -(-S // bs) * bs
+
+
+def _exact_cases():
+    # default choice of blocks, fold alternating so that every shape meets
+    # both dtypes and both folds meet both dtypes
+    shapes = [(d, s, causal) for d in (64, 128)
+              for s in (128, 1000, 1024, 1536) for causal in (True, False)]
+    for j, (d, s, causal) in enumerate(shapes):
+        for t, dtype in enumerate(("float32", "bfloat16")):
+            fold = 1 + (j + t) % 2
+            yield pytest.param(
+                s, d, causal, dtype, fold, None, None, True,
+                id=f"s{s}-d{d}-{'causal' if causal else 'full'}-{dtype}"
+                   f"-fold{fold}")
+    # named blocks at the benchmark cell's S and D: the two-pass backward,
+    # dead steps behind the clamp (several blocks a head), and blocks of
+    # two sizes (the diagonal crosses a step anywhere: traced sweep bounds)
+    for dtype, causal, fold, bq, bk, fused in [
+            ("bfloat16", True, 2, None, None, False),
+            ("float32", True, 1, 512, 512, False),
+            ("float32", True, 1, 256, 512, False),
+            ("float32", True, 1, 512, 256, True),
+            ("bfloat16", False, 1, 512, 512, False),
+            ("bfloat16", True, 2, 256, 256, True)]:
+        yield pytest.param(
+            1024, 64, causal, dtype, fold, bq, bk, fused,
+            id=f"s1024-d64-{'causal' if causal else 'full'}-{dtype}"
+               f"-fold{fold}-b{bq}x{bk}-{'fused' if fused else 'twopass'}")
+
+
+@pytest.mark.parametrize("S,D,causal,dtype,fold,bq,bk,fused",
+                         list(_exact_cases()))
+def test_flash_exact_output_and_grads(rng, monkeypatch, S, D, causal, dtype,
+                                      fold, bq, bk, fused):
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu.ops import pallas_attention as PA
+    if not fused:
+        monkeypatch.setattr(PA, "_FUSED_DQ_BYTES", 0)
+        PA._build_bwd.cache_clear()
+    # a causal S that is no multiple of a block is padded as _attention
+    # pads it (keys at positions >= S are hidden from every real row)
+    Spad = _attention_pad(S) if causal else S
+    q, k, v, w = (jnp.asarray(rng.standard_normal((S, fold, D)), dtype)
+                  for _ in range(4))
+    scale = 1.0 / np.sqrt(D)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def flash(q, k, v):
+        pad = lambda x: jnp.pad(x, ((0, Spad - S), (0, 0), (0, 0)))
+        return PA.flash_attention(pad(q), pad(k), pad(v), causal=causal,
+                                  block_q=bq, block_k=bk,
+                                  head_fold=fold)[:S]
+
+    def dense(q, k, v):
+        return PA._dense_attention_shd(q, k, v, causal, scale)
+
+    tol_o, tol_g = (2e-5, 1e-4) if dtype == "float32" else (2e-2, 3e-2)
+    assert float(jnp.abs(f32(flash(q, k, v))
+                         - f32(dense(q, k, v))).max()) < tol_o
+    loss = lambda f: (lambda q, k, v: jnp.sum(f32(f(q, k, v)) * f32(w)))
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    if not fused:
+        PA._build_bwd.cache_clear()
+    for name, a, b in zip("qkv", got, want):
+        gap = float(jnp.abs(f32(a) - f32(b)).max() / jnp.abs(f32(b)).max())
+        assert gap < tol_g, (name, gap)
+
+
+@pytest.mark.parametrize("bq,bk", [(256, 256), (128, 256), (64, 32)],
+                         ids=["aligned", "two_sizes", "small"])
+def test_flash_hop_bwd_offsets_match_dense_gradient(rng, bq, bk):
+    # the ring's backward hop with non-zero traced offsets: the
+    # contributions of the four (q half, k half) pairs (one of them wholly
+    # above the diagonal) add up to the dense causal gradient
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu.ops.pallas_attention import (
+        _LANE, _dense_attention_shd, flash_attention_hop_bwd)
+    S, H, D = 512, 2, 64
+    half = S // 2
+    q, k, v, g = (jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
+                  for _ in range(4))
+    scale = 1.0 / np.sqrt(D)
+    dense = lambda q, k, v: _dense_attention_shd(q, k, v, True, scale)
+    o, vjp = jax.vjp(dense, q, k, v)
+    want = vjp(g)
+    hf = lambda x: jnp.transpose(x, (1, 0, 2))
+    # final logsumexp and D rows of the whole sequence, lane-replicated
+    s = jnp.einsum("qhd,khd->hqk", q * scale, k)
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None], s, -jnp.inf)
+    lanes = lambda x: jnp.broadcast_to(x[:, :, None], (H, S, _LANE))
+    lse = lanes(jax.nn.logsumexp(s, axis=-1))
+    dd = lanes(jnp.einsum("shd,shd->hs", g, o))
+    got = [jnp.zeros((H, S, D), jnp.float32) for _ in range(3)]
+    hop = jax.jit(lambda qoff, koff, *a: flash_attention_hop_bwd(
+        *a, qoff, koff, causal=True, block_q=bq, block_k=bk))
+    for qoff in (0, half):
+        for koff in (0, half):
+            rq, rk = slice(qoff, qoff + half), slice(koff, koff + half)
+            dq, dk, dv = hop(qoff, koff, hf(q)[:, rq], hf(k)[:, rk],
+                             hf(v)[:, rk], hf(g)[:, rq], lse[:, rq],
+                             dd[:, rq])
+            got[0] = got[0].at[:, rq].add(dq)
+            got[1] = got[1].at[:, rk].add(dk)
+            got[2] = got[2].at[:, rk].add(dv)
+    for name, a, b in zip("qkv", got, want):
+        gap = float(jnp.abs(hf(a) - b).max() / jnp.abs(b).max())
+        assert gap < 1e-4, (name, gap)
+
+
+# ---------------------------------------------------------------------------
+# the plan: which blocks a program visits, and the gauge that says so
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("sweep", ["k", "q"])
+@pytest.mark.parametrize("s,bq,bk", [(1024, 1024, 1024), (1024, 512, 512),
+                                     (1536, 512, 512), (1024, 256, 512),
+                                     (2048, 1024, 256), (128, 128, 128)])
+def test_flash_step_counts_match_the_mask(s, bq, bk, sweep):
+    from distributedarrays_tpu.ops.pallas_attention import (
+        _count_steps, _tiles)
+    tq, tk = _tiles(bq, bk)
+    live = np.tril(np.ones((s, s), bool))
+    tiles = live.reshape(s // tq, tq, s // tk, tk)
+    some, all_ = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+    blocks = live.reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3))
+    got = _count_steps(s, bq, bk, tq, tk, True, sweep)
+    assert got == {"unmasked": int(all_.sum()),
+                   "masked": int((some & ~all_).sum()),
+                   "dead": int((~blocks).sum())}
+    full = _count_steps(s, bq, bk, tq, tk, False, sweep)
+    assert full == {"unmasked": (s // tq) * (s // tk), "masked": 0,
+                    "dead": 0}
+
+
+def test_flash_plan_gauge_at_the_benchmark_cell_shape():
+    # gpt2m_train calls flash_attention on (1024, 8 x 16, 64) bf16 causal
+    # with nothing named: no grid step is dead, under half of the tiles
+    # are masked, and the backward is one program
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu import telemetry as tm
+    from distributedarrays_tpu.ops import pallas_attention as PA
+    PA._build.cache_clear()
+    PA._build_bwd.cache_clear()
+    q = jax.ShapeDtypeStruct((1024, 128, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(
+        lambda q, k, v: jnp.sum(PA.flash_attention(q, k, v, causal=True)
+                                .astype(jnp.float32)), (0, 1, 2)), q, q, q)
+
+    def read(kernel, what):
+        return tm.gauge_value("pallas.flash_attention.plan", kernel=kernel,
+                              s=1024, d=64, causal=True, what=what)
+
+    for kernel in ("flash_fwd", "flash_bwd_dkv"):
+        assert read(kernel, "dead") == 0
+        masked, unmasked = read(kernel, "masked"), read(kernel, "unmasked")
+        assert 0 < masked < (masked + unmasked) / 2
+        assert [read(kernel, w) for w in ("bq", "bk", "fold")] == [
+            1024, 1024, 1]
+    # one backward program: a head's dQ fits VMEM
+    assert PA._fused_backward(1024, 64, jnp.bfloat16, 1, False)
+    # the blocks the caller used to force leave one dead step a head
+    jax.eval_shape(lambda q: PA.flash_attention(
+        q, q, q, causal=True, block_q=512, block_k=512), q)
+    assert read("flash_fwd", "dead") == 1
+    assert read("flash_fwd", "bq") == 512
+    PA._build.cache_clear()
+
+
+@pytest.mark.parametrize("s,d,dtype,fold,traced,want", [
+    (1024, 64, "bfloat16", 1, False, True),      # the benchmark cell
+    (8192, 128, "bfloat16", 1, False, True),
+    (16384, 128, "bfloat16", 1, False, False),   # dQ of a head: 16 MiB
+    (8192, 64, "float32", 1, False, False),      # 64 lanes pad to 128
+    (8192, 128, "bfloat16", 2, False, False),    # two heads a step
+    (256, 64, "float32", 1, True, False),        # the ring hop: two passes
+])
+def test_flash_backward_form_follows_from_the_shapes(s, d, dtype, fold,
+                                                     traced, want):
+    from distributedarrays_tpu.ops.pallas_attention import _fused_backward
+    assert _fused_backward(s, d, dtype, fold, traced) is want
+
+
+def test_flash_default_blocks_and_fold_follow_from_the_shapes():
+    from distributedarrays_tpu.ops.pallas_attention import (
+        tuned_flash_config)
+    # nothing named, no registry entry (the CPU's device key has none)
+    assert tuned_flash_config(1024, 128, 64, "bfloat16", True) == (
+        1024, 1024, 1)
+    assert tuned_flash_config(8192, 32, 128, "bfloat16", True) == (
+        1024, 1024, 1)
+    # a named block is kept and takes fold 1 with it, a named fold wins
+    assert tuned_flash_config(1024, 128, 64, "bfloat16", True,
+                              block_q=512) == (512, 1024, 1)
+    assert tuned_flash_config(1024, 128, 64, "bfloat16", True,
+                              head_fold=4) == (1024, 1024, 4)
