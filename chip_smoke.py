@@ -3,7 +3,7 @@
 
 The quickest proof that the system still starts on the chip.  Drives the
 public entry points (``import distributedarrays_tpu as dat``) at the sizes
-``BASELINE.json`` and ``bench.py`` state, compares every result with a plain
+``BASELINE.json`` states, compares every result with a plain
 reference that shares no code with the path under test (numpy, or float32
 ``jax.numpy`` written in this file), and fails on anything that hides the
 device: a ``RuntimeWarning``, a moved fallback counter, a kernel that was

@@ -9,7 +9,7 @@
 
 ``lint`` exits 0 when every finding is suppressed (or none exist), 1
 otherwise — the CI gate.  Default paths are the package's own
-lint surface: ``distributedarrays_tpu examples bench.py``.  Output
+lint surface: ``distributedarrays_tpu examples``.  Output
 formats: ``--format=text`` (default), ``json`` (one object per finding),
 ``github`` (workflow-command annotations rendered inline on PR diffs).
 ``--warn-unused-suppressions`` reports ``# dalint: disable=`` comments
@@ -48,7 +48,7 @@ from pathlib import Path
 from .engine import lint_file, unused_suppressions
 from .rules import RULES
 
-DEFAULT_TARGETS = ["distributedarrays_tpu", "examples", "bench.py"]
+DEFAULT_TARGETS = ["distributedarrays_tpu", "examples"]
 
 _SEV_GH = {"error": "error", "warning": "warning", "info": "notice"}
 
@@ -296,7 +296,7 @@ def main(argv=None) -> int:
 
     lint = sub.add_parser("lint", help="lint files/directories")
     lint.add_argument("paths", nargs="*", help="files or directories "
-                      "(default: distributedarrays_tpu examples bench.py)")
+                      "(default: distributedarrays_tpu examples)")
     lint.add_argument("--select", default=None,
                       help="comma-separated rule codes to run (e.g. "
                            "DAL001,DAL005)")
@@ -335,8 +335,7 @@ def main(argv=None) -> int:
                                     "module:Class.method)")
     eff.add_argument("paths", nargs="*",
                      help="analysis surface (default: "
-                          "distributedarrays_tpu examples tests "
-                          "bench.py)")
+                          "distributedarrays_tpu examples tests)")
 
     vs = sub.add_parser(
         "verify-spmd",
@@ -344,8 +343,7 @@ def main(argv=None) -> int:
              "gate (DAL010/011/012)")
     vs.add_argument("paths", nargs="*",
                     help="files or directories (default: "
-                         "distributedarrays_tpu examples tests "
-                         "bench.py)")
+                         "distributedarrays_tpu examples tests)")
     vs.add_argument("--format", choices=("text", "json", "github"),
                     default="text")
     vs.add_argument("--show-suppressed", action="store_true")

@@ -65,8 +65,7 @@ __all__ = ["analyze_sources", "analyze_paths", "findings_for_source",
 # scope: seeded-divergence fixtures there must carry suppressions, and a
 # *new* test helper with a real rank-gated collective is exactly the bug
 # this gate exists to stop
-DEFAULT_EFFECT_TARGETS = ("distributedarrays_tpu", "examples", "tests",
-                          "bench.py")
+DEFAULT_EFFECT_TARGETS = ("distributedarrays_tpu", "examples", "tests")
 
 # -- event vocabularies ------------------------------------------------------
 

@@ -657,12 +657,7 @@ class DArray:
             from .parallel import multihost
             return multihost.gather_global(g)
         if _tm.enabled():
-            from .telemetry import perf as _perf
-            nb = _tm.nbytes_of(g)
-            # cost stamp on the @traced gather span: the payload through
-            # HBM once (d2h transfer)
-            _tm.annotate(**_perf.transfer_cost(nb))
-            _tm.record_comm("d2h", nb, op="gather",
+            _tm.record_comm("d2h", _tm.nbytes_of(g), op="gather",
                             shape=list(self.dims))
         return jax.device_get(g)
 
@@ -1532,11 +1527,6 @@ def distribute(A, procs=None, dist=None, like: DArray | None = None) -> DArray:
     elif isinstance(A, SubDArray):
         A = A.materialize()
     A = jnp.asarray(A) if not isinstance(A, (np.ndarray, jax.Array)) else A
-    if _tm.enabled():
-        from .telemetry import perf as _perf
-        # cost stamp on the @traced distribute span: the payload through
-        # HBM once (h2d scatter)
-        _tm.annotate(**_perf.transfer_cost(_tm.nbytes_of(A)))
     if like is not None:
         dims, pids, idxs, cuts, sharding = _resolve_layout(
             np.shape(A), [int(p) for p in like.pids.flat], list(like.pids.shape))

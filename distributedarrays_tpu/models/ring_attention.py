@@ -308,18 +308,9 @@ def ring_attention(q: DArray, k: DArray, v: DArray,
             "ring attention needs the sequence dim sharded evenly over a "
             f"1-D grid; got grid {q.pids.shape} for dims {q.dims}")
     from ..ops import pallas_collectives as _pc
-    from ..telemetry import perf as _perf
     rdma = _pc.rdma_mode()
-    s, h, dh = (int(d) for d in q.dims)
     with _tm.span("ring_attention", ranks=n, causal=causal,
-                  dispatch="rdma" if rdma else "xla",
-                  # cost stamp: two s x s x dh GEMMs per head (halved
-                  # causal), q/k/v/o through HBM, k/v chunks rotating
-                  # p-1 ring steps over ICI — the doctor's overlap tier
-                  # reads comm-vs-compute per step from this
-                  **_perf.attention_cost(
-                      s, h, dh, np.dtype(q.dtype).itemsize, p=n,
-                      causal=causal)):
+                  dispatch="rdma" if rdma else "xla"):
         out = None
         if rdma:
             fn, _ = _ring_jit_1d(tuple(pids), causal, rdma)
@@ -543,9 +534,8 @@ def _tuned_hop_blocks(q, causal: bool, block_q, block_k):
 def tuned_hop_blocks_for(shape, dtype, causal: bool, block_q, block_k):
     """Per-hop block sizes: explicit values win; ``None`` consults the
     ``"ring_flash"`` autotune entry for this (local block, heads, d,
-    dtype, causal) — banked by bench.py's hardware hop sweep — falling
-    back to 512².  Shared by the contiguous and zigzag fused kernels
-    (the hop programs fit blocks to their half/full extents anyway;
+    dtype, causal), falling back to 512².  Shared by the contiguous and
+    zigzag fused kernels (the hop programs fit blocks to their half/full extents anyway;
     both thread a 3-tuple entry's head fold through
     ``flash_attention_hop``).  Callers that cache jitted programs must
     resolve through here OUTSIDE the cache and key on the resolved

@@ -62,8 +62,7 @@ class SPConfig(Config):
                  max_seq=128, dtype=jnp.bfloat16, block_q=None, block_k=None,
                  interpret=None, zigzag=False, head_fold=None):
         # block_q/block_k/head_fold None = take the autotune registry's
-        # tuned hop config (banked by bench.py's hardware sweep), falling
-        # back to the kernel's 512²/1 default.  The train-step factories
+        # tuned hop config, falling back to the kernel's 512²/1 default.  The train-step factories
         # resolve the Nones OUTSIDE their cached jits (``_resolve_cfg``)
         # so a tune banked after the first step is picked up, not
         # silently pinned at first trace (ADVICE round-4).

@@ -31,7 +31,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import layout as L
 from .. import telemetry as _tm
-from ..telemetry import perf as _perf
 from ..darray import DArray, SubDArray, _wrap_global, distribute
 from .broadcast import _unwrap, elementwise
 from ..parallel import reshard as _rs
@@ -206,7 +205,7 @@ def _impl_choice(m, n, k, a_dtype, b_dtype):
     """Consult the autotune registry for the GEMM implementation to use
     for this shape: ``"pallas"`` (hand-owned Pallas schedule) or ``"jnp"``
     (XLA).  Default is ``"jnp"`` — the owned schedules are promoted only
-    by a measured win banked by ``tune_matmul_impl`` / bench.py, never by
+    by a measured win banked by ``tune_matmul_impl``, never by
     assumption (VERDICT round-3 item 4)."""
     from ..utils import autotune
     return autotune.get(
@@ -296,14 +295,6 @@ def _ring_ag_gemm(A: DArray, B: DArray, out_dtype):
     rdma = _pc.rdma_mode()
     m, k = (int(d) for d in A.dims)
     n = int(B.dims[1])
-    # per-shape-class rdma-vs-xla preference (advisor-written); an
-    # explicit DA_TPU_RDMA env wins inside resolve_dispatch, and a
-    # preference can only demote to the XLA ring
-    dispatch_key = _pc.dispatch_key_for("ring_ag", m, n, k, p,
-                                        str(A.dtype))
-    pref, dispatch_src = _pc.resolve_dispatch(dispatch_key)
-    if pref == "xla":
-        rdma = None
     isz = np.dtype(A.dtype).itemsize
     osz = np.dtype(out_dtype).itemsize
     if rdma == "compiled" and not (
@@ -312,17 +303,10 @@ def _ring_ag_gemm(A: DArray, B: DArray, out_dtype):
         # decided HERE from the shapes, and said on the span: demanding
         # the compiled fused kernel for operands its scoped-VMEM gate
         # refuses would only be counted as a degradation further down
-        rdma, dispatch_src = None, "vmem_gate"
+        rdma = None
     with _tm.span("matmul.ring_ag", ranks=p,
                   dispatch="rdma" if rdma else "xla",
-                  dispatch_key=dispatch_key, dispatch_source=dispatch_src,
-                  shape=[m, k, n], dtype=str(A.dtype),
-                  # cost stamp: the ring all-gathers B (each rank's
-                  # chunk forwarded p-1 hops) overlapped into the
-                  # per-chunk matmuls — the doctor's overlap tier reads
-                  # bytes_ici against flops per ring step
-                  **_perf.gemm_cost(m, n, k, isz, out_itemsize=osz,
-                                    bytes_ici=(p - 1) * k * n * isz)):
+                  shape=[m, k, n], dtype=str(A.dtype)):
         mesh, ax, fn = _ring_ag_jit(procs, p, str(jnp.dtype(out_dtype)),
                                     rdma)
         with _tm.span("matmul.ring_ag.place", _journal=False):
@@ -352,7 +336,7 @@ def _dist_impl_choice(m, n, k, p, a_dtype, b_dtype):
     ring) or ``"jnp"`` (GSPMD).  Default ``"jnp"`` — same promotion-by-
     measurement policy as `_impl_choice` (XLA's own SPMD pass can overlap
     too, so the ring must earn its place on the target topology); banked
-    by ``tune_matmul_impl_dist`` / bench.py."""
+    by ``tune_matmul_impl_dist``."""
     from ..utils import autotune
     return autotune.get(
         "matmul_impl_dist", _impl_key(m, n, k, p, a_dtype, b_dtype)) or "jnp"
@@ -460,17 +444,7 @@ def _summa_gemm(A: DArray, B: DArray, out_dtype):
     the (r,c)-block-sharded result array."""
     r, c = A.pids.shape
     procs = tuple(int(q) for q in A.pids.flat)
-    m, k = (int(d) for d in A.dims)
-    n = int(B.dims[1])
-    isz = np.dtype(A.dtype).itemsize
-    with _tm.span("matmul.summa", grid=f"{r}x{c}", ranks=r * c,
-                  # cost stamp: panel broadcasts move each operand to
-                  # the rest of its grid row/column
-                  **_perf.gemm_cost(
-                      m, n, k, isz,
-                      out_itemsize=np.dtype(out_dtype).itemsize,
-                      bytes_ici=m * k * isz * (c - 1) // c
-                      + k * n * isz * (r - 1) // r)):
+    with _tm.span("matmul.summa", grid=f"{r}x{c}", ranks=r * c):
         mesh, (ax_r, ax_c), fn = _summa_jit(procs, r, c,
                                             str(jnp.dtype(out_dtype)))
         sh = NamedSharding(mesh, P(ax_r, ax_c))
@@ -483,7 +457,7 @@ def _summa_gemm(A: DArray, B: DArray, out_dtype):
 
 def _default_impl_timer(op, a, b):
     """Best-of-3 wall clock with a scalar-fetch sync (block_until_ready
-    does not synchronize through every transport — see bench.py)."""
+    does not synchronize through every transport)."""
     import time as _time
     op(a, b)                                  # compile + warm
     best = float("inf")
@@ -625,8 +599,7 @@ def tune_matmul_impl(m, n, k, dtype=jnp.float32, timer=None, persist=True):
     autotune registry under ``matmul_impl`` (consulted by ``matmul`` /
     ``DArray @ DArray``; the key includes the device kind, so a winner
     from one platform never drives another).  ``timer(op, a, b) ->
-    seconds`` is injectable (bench.py passes its scan-chain t(L)/L
-    method; tests pass deterministic stubs).  Returns
+    seconds`` is injectable (tests pass deterministic stubs).  Returns
     ``(winner, {impl: seconds})``."""
     from .pallas_gemm import pallas_matmul
     a = jax.random.normal(jax.random.PRNGKey(0), (m, k),
@@ -775,17 +748,7 @@ def matmul(A, B, out: DArray | None = None, alpha=1.0, beta=0.0):
         b_bytes = _tm.nbytes_of(bv)
         _tm.count("op.matmul")
         ici_est = a_bytes * (c - 1) + b_bytes * (r - 1)
-        # analytic cost stamp on the @traced matmul span (shapes were
-        # unknown when it opened): 2mnk flops, operands + result through
-        # HBM once, the SUMMA-volume ICI estimate — the doctor's
-        # roofline classification reads these.  Inline rather than
-        # perf.gemm_cost: A and B can carry different dtypes here, and
-        # a_bytes/b_bytes are the operands' actual byte counts
-        _tm.annotate(
-            flops=2 * m * n * k,
-            bytes_hbm=a_bytes + b_bytes
-            + m * n * np.dtype(out_dtype).itemsize,
-            bytes_ici=ici_est, grid=f"{r}x{c}")
+        _tm.annotate(grid=f"{r}x{c}")
         _tm.record_comm("collective", ici_est,
                         op="matmul", grid=f"{r}x{c}",
                         shape=[m, k, n])

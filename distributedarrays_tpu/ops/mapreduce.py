@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import functools
 import inspect
-import math
 from typing import Callable
 
 import numpy as np
@@ -134,17 +133,6 @@ def dmapreduce(f: Callable, op_name_or_fn, d, dims=None):
     """
     _tm.count("op.mapreduce")
     with _tm.span("mapreduce"):
-        if _tm.enabled():
-            # cost stamp: ~1 flop and one HBM read per element (the map
-            # cost is unknown — this floor classifies the sweep
-            # HBM-bound, which is what a reduction is)
-            from ..telemetry import perf as _perf
-            try:
-                n_elems = math.prod(int(n) for n in d.dims)
-                isz = np.dtype(d.dtype).itemsize
-            except (AttributeError, TypeError):
-                n_elems, isz = _tm.nbytes_of(d), 1
-            _tm.annotate(**_perf.reduce_cost(n_elems, isz))
         reducer = _REDUCERS.get(op_name_or_fn, op_name_or_fn) \
             if isinstance(op_name_or_fn, str) else op_name_or_fn
         if callable(reducer) and _is_binary_op(reducer):

@@ -105,8 +105,7 @@ from ..parallel.collectives import (axis_size as _axis_size, pall_to_all,
 from . import ring_schedules as _rs
 from .pallas_gemm import _on_tpu
 
-__all__ = ["rdma_mode", "resolve_chunks", "resolve_dispatch",
-           "dispatch_key_for", "a2a_chunks_key", "ring_all_gather",
+__all__ = ["rdma_mode", "resolve_chunks", "ring_all_gather",
            "ring_reduce_scatter", "ring_all_to_all",
            "ring_allgather_matmul", "ring_allgather_matmul_rhs",
            "ring_matmul_reducescatter", "gemm_ring_eligible"]
@@ -174,8 +173,7 @@ def resolve_chunks(local_bytes: int, *key_parts) -> tuple[int, str]:
     ``DA_TPU_RDMA_CHUNKS`` wins, else a valid ``"rdma_chunks"`` autotune
     entry for this shape/platform, else derived so one chunk stays under
     the ``DA_TPU_RESHARD_CHUNK_MB`` target.  Returns ``(chunks, source)``
-    — the source is banked as bench provenance and stamped on the
-    dispatch span."""
+    — the source is stamped on the dispatch span."""
     env = os.environ.get(CHUNKS_ENV)
     if env:
         try:
@@ -192,47 +190,6 @@ def resolve_chunks(local_bytes: int, *key_parts) -> tuple[int, str]:
     return min(max(derived, 1), 64), "derived"
 
 
-# registry namespace for per-shape-class rdma-vs-xla preferences: entries
-# are the literal strings "rdma" | "xla", written by the telemetry
-# advisor from dispatch-labeled side-by-side measurements
-DISPATCH_KERNEL = "rdma_dispatch"
-
-
-def dispatch_key_for(op: str, *parts) -> str:
-    """The ``rdma_dispatch`` registry key for one dispatch site — the op
-    name plus its shape class, device-fenced via ``device_key_for``.
-    Stamped on the site's span (``dispatch_key`` label) so the doctor's
-    side-by-side overlap stats and the advisor's preference writes
-    address the same entry."""
-    from ..utils import autotune
-    return autotune.device_key_for(op, *parts)
-
-
-def a2a_chunks_key(local_shape, dtype_str: str, p: int) -> str:
-    """The ``rdma_chunks`` registry key :func:`ring_all_to_all` resolves
-    its depth under (same parts as :func:`a2a_chunks_for`) — stamped on
-    reshard spans so a journaled transfer names the exact autotune entry
-    that shaped it."""
-    from ..utils import autotune
-    return autotune.device_key_for("a2a", *local_shape, dtype_str, p)
-
-
-def resolve_dispatch(key: str) -> tuple[str | None, str]:
-    """Per-shape-class dispatch preference for one call site: an explicit
-    ``DA_TPU_RDMA`` env always wins (``(None, "env")`` — the caller's
-    :func:`rdma_mode` result stands as-is); else a valid
-    ``"rdma_dispatch"`` autotune entry (``"rdma"`` | ``"xla"``, written
-    by the telemetry advisor) for ``key``; else ``(None, "default")``.
-    Malformed entries degrade to the default, never break dispatch."""
-    if os.environ.get(RDMA_ENV):
-        return None, "env"
-    from ..utils import autotune
-    entry = autotune.get(DISPATCH_KERNEL, key)
-    if isinstance(entry, str) and entry in ("rdma", "xla"):
-        return entry, "autotune"
-    return None, "default"
-
-
 def _record_dispatch(op: str, path: str, x, axis: str, p: int = 0,
                      **labels) -> None:
     """Trace-time dispatch telemetry: a labeled counter plus, on the
@@ -244,12 +201,7 @@ def _record_dispatch(op: str, path: str, x, axis: str, p: int = 0,
 
     ``p`` (the ring size) adds a ``bytes_ici`` provenance stamp to the
     comm record — PER-DEVICE ring volume, matching this record's
-    per-rank-block byte convention (``collectives._rec``).  The
-    execution-tier roofline stamp the doctor reads lives on the calling
-    op's span (``reshard``, ``matmul.ring_ag``, ``ring_attention``) in
-    aggregate-volume convention; a direct ring-kernel call inside a
-    user's own shard_map has no execution span and should be wrapped in
-    one (see docs/telemetry.md, *Performance observatory*)."""
+    per-rank-block byte convention (``collectives._rec``)."""
     _tm.count("pallas_collectives.dispatch", op=op, path=path)
     if path == "rdma" and _tm.enabled():
         if p and p > 1:

@@ -290,19 +290,9 @@ def pallas_matmul(a, b, block: tuple[int, int, int] | None = None,
     ab, bb = jnp.dtype(a.dtype).itemsize, jnp.dtype(b.dtype).itemsize
     ob = jnp.dtype(out_dtype).itemsize
     if _tm.enabled():
-        # cost stamp on the @traced dispatch span (shapes were unknown
-        # when it opened): single-device GEMM, no ICI.  Inline rather
-        # than perf.gemm_cost: a and b can carry different dtypes.  The
-        # autotune_key names the exact "pallas_matmul" block entry
-        # _resolve_block consults, so a low_roofline finding on this
-        # span addresses a re-sweepable registry slot.
-        from ..utils import autotune as _at
-        _tm.annotate(flops=2 * m * n * ka,
-                     bytes_hbm=m * ka * ab + ka * n * bb + m * n * ob,
-                     bytes_ici=0, shape=[m, ka, n],
-                     dtype=[str(a.dtype), str(b.dtype)],
-                     autotune_key=_at.device_key_for(m, n, ka, a.dtype,
-                                                     b.dtype))
+        # on the @traced dispatch span (shapes were unknown when it opened)
+        _tm.annotate(shape=[m, ka, n],
+                     dtype=[str(a.dtype), str(b.dtype)])
 
     bm, bn, bk = _resolve_block(
         m, n, ka, block, interpret, kernel="pallas_matmul",
@@ -394,12 +384,7 @@ def pallas_matmul_int8(qa, qb, a_scale, b_scale,
         raise ValueError(f"matmul dim mismatch {qa.shape} @ {qb.shape}")
     if interpret is None:
         interpret = not _on_tpu()
-    if _tm.enabled():
-        # cost stamp: int8 operands, dequantized output through HBM
-        from ..telemetry import perf as _perf
-        _tm.annotate(shape=[m, ka, n], **_perf.gemm_cost(
-            m, n, ka, 1,
-            out_itemsize=jnp.dtype(out_dtype).itemsize))
+    _tm.annotate(shape=[m, ka, n])
     safe_k = (2**31 - 1) // (127 * 127)
     if ka > safe_k:
         # worst-case saturated operands overflow the int32 accumulator

@@ -83,7 +83,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import layout as L
 from .. import telemetry as _tm
-from ..telemetry import perf as _perf
 from ..resilience import faults as _fl
 from .collectives import pall_to_all, pgather, shard_map_compat
 
@@ -1319,9 +1318,6 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
         rdma = None
         rdma_chunks = 0
         chunks_src = ""
-        autotune_key = ""
-        dispatch_key = ""
-        dispatch_src = ""
         if any(s[0] in ("a2a", "gather") for s in plan.steps):
             # a2a and gather steps ride the ring kernels when the platform
             # arms them (mesh-coordinate addressing on multi-axis meshes);
@@ -1332,32 +1328,17 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
         elif plan.collective and plan.strategy in ("all_to_all", "all_gather"):
             from ..ops import pallas_collectives as _pc
             rdma = _pc.rdma_mode()
-            dtype_str = str(getattr(x, "dtype", "float32"))
-            # per-shape-class dispatch preference (advisor-written
-            # "rdma_dispatch" entry); an explicit DA_TPU_RDMA env wins inside
-            # resolve_dispatch, and a preference can only demote to XLA — it
-            # never conjures RDMA on a platform rdma_mode rejected
-            dispatch_key = _pc.dispatch_key_for(
-                "reshard", plan.strategy, *plan.shape, dtype_str, plan.nparts)
-            pref, dispatch_src = _pc.resolve_dispatch(dispatch_key)
-            if pref == "xla":
-                rdma = None
             if rdma and plan.strategy == "all_to_all":
+                dtype_str = str(getattr(x, "dtype", "float32"))
                 lshape = tuple(s // plan.nparts if d == plan.src_dim else s
                                for d, s in enumerate(plan.shape))
                 # the kernel concats along the plan's src dim; clamping here
-                # keeps span/bench provenance equal to the depth it runs
+                # keeps the span's label equal to the depth it runs
                 rdma_chunks, chunks_src = _pc.a2a_chunks_for(
                     lshape, dtype_str, plan.nparts, plan.src_dim)
-                # the exact "rdma_chunks" registry key this depth resolved
-                # under — the advisor addresses its writes by this label
-                autotune_key = _pc.a2a_chunks_key(lshape, dtype_str,
-                                                  plan.nparts)
     with _tm.span("reshard", op=op, strategy=plan.strategy,
                   dispatch="rdma" if rdma else "xla",
                   rdma_chunks=rdma_chunks, rdma_chunks_source=chunks_src,
-                  autotune_key=autotune_key, dispatch_key=dispatch_key,
-                  dispatch_source=dispatch_src,
                   shape=list(plan.shape),
                   dtype=str(getattr(x, "dtype", "float32")),
                   src_dim=plan.src_dim, dst_dim=plan.dst_dim,
@@ -1365,14 +1346,7 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
                   # hierarchical-tier provenance: how many of the moved
                   # bytes stay on fast intra-domain links vs cross the DCN
                   intra_bytes=plan.intra_bytes,
-                  cross_bytes=plan.cross_bytes,
-                  # analytic cost stamp (telemetry.perf): every byte
-                  # read + rewritten through HBM, the plan's MOVED bytes
-                  # crossing a device boundary over ICI, zero flops —
-                  # the doctor classifies each occurrence against the
-                  # platform roofline from these
-                  **_perf.reshard_cost(plan.total_bytes,
-                                       plan.moved_bytes)):
+                  cross_bytes=plan.cross_bytes):
         if plan.collective:
             # chaos site: an armed fault plan can abort the planned
             # collective here — mid-reshard, before any chunk moves, so
@@ -1420,9 +1394,8 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
                     f"({type(e).__name__}: {e}); falling back to "
                     f"device_put")
         if plan.strategy == "device_put" and plan.moved_bytes:
-            # the residue the advisor targets: why does this move still
-            # fall back?  (placement-equal relabels move nothing and are
-            # not a residue)
+            # the residue: why does this move still fall back?
+            # (placement-equal relabels move nothing and are not one)
             _tm.count("reshard.collective_fallbacks",
                       reason=_fallback_reason(plan.reason))
         if _tm.enabled():

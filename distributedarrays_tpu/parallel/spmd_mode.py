@@ -470,7 +470,7 @@ def spmd(f: Callable, *args, pids: Sequence[int] | None = None,
                                             on_mismatch=ctx._failed.set)
         else:
             # the stderr warning is one-shot and easily lost — journal a
-            # typed event + counter so the doctor and incident
+            # typed event + counter so incident
             # reconstruction can see the coverage gap (this run was NOT
             # divergence-checked, even though the env var says it was)
             _tm.count("analysis.divergence_unchecked", backend=backend)

@@ -8,13 +8,10 @@ over resident per-sequence state.  A :class:`DecodeEngine` owns a
 - **prefill** — compute-bound: the whole prompt's attention in one shot,
   dispatched through the ring-attention prefill entry
   (``models.ring_attention.ring_attention_prefill``, RDMA when armed)
-  with K/V written back into cache pages.  Stamped with
-  ``perf.attention_cost`` (O(s²) flops over O(s) bytes) so the roofline
-  doctor classifies it compute-bound.
+  with K/V written back into cache pages (a ``serve.prefill`` span).
 - **decode** — HBM-bound: one token per sequence per round, a single
-  query row attending the sequence's entire gathered page set.  Stamped
-  with ``perf.decode_step_cost`` (~0.5 flop/byte) so the doctor shows
-  the memory-bound regime next to prefill's compute-bound one.
+  query row attending the sequence's entire gathered page set (a
+  ``serve.decode`` span).
 
 Scheduling: a per-round **token budget** is spent on the decode batch
 first (latency: admitted sequences keep streaming), then on prefills
@@ -54,7 +51,6 @@ import numpy as np
 
 from .. import telemetry as _tm
 from ..resilience import elastic, faults as _fl, recovery
-from ..telemetry import perf as _perf
 from .errors import (Cancelled, DeadlineExceeded, Draining, Overloaded,
                      Rejected, RequestFailed, ServeError)
 from .kvcache import KVCacheConfig, PagedKVCache
@@ -574,14 +570,10 @@ class DecodeEngine:
 
     def _dispatch_decode(self, batch: list[_Seq]) -> None:
         model = self.model
-        ctx_total = sum(len(s.tokens) for s in batch)
         t0 = time.monotonic()
         try:
             with _tm.span("serve.decode", endpoint=self.name,
-                          size=len(batch),
-                          **_perf.decode_step_cost(
-                              ctx_total, model.heads, model.head_dim,
-                              4, new_tokens=len(batch))):
+                          size=len(batch)):
                 def _run():
                     # chaos site: a fault plan can down a device
                     # mid-step; recovery probes, shrinks (re-laying the
@@ -697,10 +689,7 @@ class DecodeEngine:
         t0 = time.monotonic()
         try:
             with _tm.span("serve.prefill", endpoint=self.name, ntok=ntok,
-                          rebuild=rebuild,
-                          **_perf.attention_cost(
-                              ntok, model.heads, model.head_dim, 4,
-                              causal=True)):
+                          rebuild=rebuild):
                 def _run():
                     # chaos site: device loss mid-prefill probes,
                     # shrinks, and re-invokes this closure

@@ -210,8 +210,7 @@ class Server:
         tid = trace_id or f"req-{os.getpid()}-{next(_REQ_IDS)}"
         _tm.count("serve.submitted", tenant=tenant)
         with _tm.trace_ctx(tid), \
-                _tm.span("serve.submit", endpoint=endpoint, tenant=tenant,
-                         bytes_hbm=_tm.nbytes_of(payload)):
+                _tm.span("serve.submit", endpoint=endpoint, tenant=tenant):
             # ONE locked section from the draining check through the
             # enqueue: a request is admitted iff it is enqueued before
             # drain() flips _draining (so the flush is guaranteed to
@@ -361,9 +360,7 @@ class Server:
         _tm.count("serve.batches", endpoint=ep.name)
         try:
             with _tm.span("serve.dispatch", endpoint=ep.name,
-                          size=len(live),
-                          bytes_hbm=sum(_tm.nbytes_of(p)
-                                        for p in payloads)):
+                          size=len(live)):
                 def _run():
                     # chaos site: a fault plan can kill a device mid-batch
                     # here; recovery re-invokes this closure on retry

@@ -149,17 +149,6 @@ def _run(st: _Solve, segment, policy, devices) -> SolveResult:
         except BaseException:
             _close_all(st.x, st.b_owned)
             raise
-        if _tm.enabled():
-            # aggregate stamp on the solve span: per-matvec cost times
-            # the iterations run, plus ~10 whole-vector BLAS-1 passes
-            # per iteration — a stamped parent covers its subtree, so
-            # the doctor's coverage never opens a gap under a solve
-            per = st.A.apply_cost()
-            iters = max(st.iterations, 1)
-            vec = 10 * st.A.shape[0] * np.dtype(st.A.dtype).itemsize
-            _tm.annotate(flops=per["flops"] * iters,
-                         bytes_hbm=(per["bytes_hbm"] + vec) * iters,
-                         bytes_ici=per["bytes_ici"] * iters)
         return st.finish(outcome, detail)
 
 

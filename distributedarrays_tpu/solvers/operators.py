@@ -38,7 +38,6 @@ from jax.sharding import PartitionSpec as P
 
 from .. import layout as L
 from .. import telemetry as _tm
-from ..telemetry import perf as _perf
 from ..darray import DArray, _wrap_global, dzeros
 from ..ops.mapreduce import samedist
 from ..ops.sparse import ddata_bcoo, jsparse
@@ -81,12 +80,6 @@ class LinearOperator:
 
     def vector_layout(self) -> tuple[list[int], tuple[int, ...]]:
         raise NotImplementedError
-
-    def apply_cost(self) -> dict:
-        """Analytic roofline stamp for ONE ``apply`` (aggregate volumes;
-        see ``telemetry.perf``) — the solve span multiplies it out so an
-        unstamped-coverage gap never opens under the solver."""
-        return {"flops": 0, "bytes_hbm": 0, "bytes_ici": 0}
 
     def new_vector(self) -> DArray:
         """A zeroed solution/workspace vector on the preferred layout."""
@@ -143,10 +136,6 @@ class DenseOperator(LinearOperator):
     def vector_layout(self):
         procs = [int(p) for p in self._A.pids.flat]
         return procs, (self._A.pids.shape[0],)
-
-    def apply_cost(self):
-        n = self.shape[0]
-        return _perf.gemm_cost(n, 1, n, np.dtype(self.dtype).itemsize)
 
     def close(self):
         if self._owned:
@@ -310,13 +299,6 @@ class SparseOperator(LinearOperator):
     def vector_layout(self):
         return list(self._pids), (self._p,)
 
-    def apply_cost(self):
-        itemsize = np.dtype(self.dtype).itemsize
-        return _perf.spmv_cost(
-            self.nnz, self.shape[0], itemsize,
-            bytes_ici=(2 * (self._p - 1) * self._h * itemsize
-                       if self._p > 1 else 0))
-
     # -- apply -------------------------------------------------------------
 
     def apply(self, x: DArray) -> DArray:
@@ -325,8 +307,7 @@ class SparseOperator(LinearOperator):
         if [int(q) for q in x.pids.flat] != self._pids or x.pids.size != p:
             owned = x = self.align(x)
         try:
-            with _tm.span("solver.spmv", op="bcoo", n=n, ranks=p,
-                          **self.apply_cost()):
+            with _tm.span("solver.spmv", op="bcoo", n=n, ranks=p):
                 shards = {s.device: s.data
                           for s in x.garray.addressable_shards}
                 xs = [shards[d] for d in self._mesh.devices.flat]
@@ -417,14 +398,6 @@ class StencilOperator(LinearOperator):
     def vector_layout(self):
         return list(self._pids), (len(self._pids), 1)
 
-    def apply_cost(self):
-        nx, ny = self.grid
-        itemsize = np.dtype(self.dtype).itemsize
-        p = len(self._pids)
-        return _perf.spmv_cost(
-            5 * nx * ny, nx * ny, itemsize, index_itemsize=0,
-            bytes_ici=2 * (p - 1) * ny * itemsize if p > 1 else 0)
-
     def _vector_dims(self):
         return self.grid
 
@@ -437,7 +410,7 @@ class StencilOperator(LinearOperator):
         try:
             nx, ny = self.grid
             with _tm.span("solver.spmv", op="stencil", n=nx * ny,
-                          ranks=len(self._pids), **self.apply_cost()):
+                          ranks=len(self._pids)):
                 return stencil3x3(x, self.weights, iters=1)
         finally:
             if owned is not None:

@@ -48,12 +48,9 @@ from .tracing import (Span, span, traced, current_span, current_span_id,
 from .export import to_perfetto, to_prometheus
 from . import memory
 from . import flight
-from . import perf
-from . import regress
 from . import tracing
 from . import cluster
 from . import alerts
-from . import advisor
 from . import stream
 from . import agg
 from .memory import leak_census
@@ -73,8 +70,7 @@ __all__ = [
     "spans", "span_stats", "open_spans", "annotate", "trace_ctx",
     "current_trace_ids", "bind_trace_ids", "record_external_span",
     "to_perfetto", "to_prometheus",
-    "memory", "flight", "perf", "regress", "tracing", "cluster", "alerts",
-    "advisor", "stream", "agg",
+    "memory", "flight", "tracing", "cluster", "alerts", "stream", "agg",
     "leak_census", "postmortem", "record_crash",
     "merge_journals", "reconstruct_incidents",
     "AlertRule", "AlertManager", "default_rules",

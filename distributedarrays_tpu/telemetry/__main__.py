@@ -7,12 +7,6 @@ Usage::
     python -m distributedarrays_tpu.telemetry prom REPORT.json [-o out.prom]
     python -m distributedarrays_tpu.telemetry mem RUN.jsonl|REPORT.json [--json]
     python -m distributedarrays_tpu.telemetry postmortem BUNDLE.json [--json]
-    python -m distributedarrays_tpu.telemetry doctor RUN.jsonl [--platform P]
-        [--min-findings N] [--json]
-    python -m distributedarrays_tpu.telemetry regress FRESH.json
-        [--baseline DIR_OR_FILE ...] [--json] [--strict] [--explain]
-    python -m distributedarrays_tpu.telemetry advise RUN.jsonl
-        [--apply] [--json] [--platform P] [--min-actions N]
     python -m distributedarrays_tpu.telemetry incident RUN.jsonl [RUN2.jsonl
         ...] [--bundles DIR_OR_FILE ...] [--json] [--trace OUT.json]
         [--strict-bundles]
@@ -35,12 +29,7 @@ including an ``hbm_bytes`` counter track; ``prom`` renders a
 reconstructed from it — in Prometheus text exposition format; ``mem``
 renders the HBM-ledger view (live/peak bytes, per-device when given a
 report, the alloc/free timeline reconstruction when given a journal);
-``postmortem`` renders a flight-recorder bundle; ``doctor`` runs the
-performance observatory (roofline classification of cost-stamped spans,
-comm/compute overlap, the critical path, ranked findings — see
-``telemetry/perf.py``); ``regress`` judges a fresh bench run against the
-banked ``BENCH_r*`` trajectory with noise-aware thresholds and exits 1
-on a significant slowdown (``telemetry/regress.py``); ``incident``
+``postmortem`` renders a flight-recorder bundle; ``incident``
 merges one or more per-host journals onto a single timeline and
 reconstructs ordered incident reports from them plus any flight bundles
 (``telemetry/cluster.py``) — ``--trace`` additionally writes the merged
@@ -270,112 +259,6 @@ def _cmd_mem(args) -> int:
         sys.stdout.write("\n")
     else:
         _format_mem(mem, sys.stdout)
-    return 0
-
-
-# ---------------------------------------------------------------------------
-# doctor: the performance observatory
-# ---------------------------------------------------------------------------
-
-
-def _cmd_doctor(args) -> int:
-    from . import perf
-    events = _read_events_checked(args.journal)
-    analysis = perf.analyze(events, platform=args.platform)
-    if args.json:
-        json.dump(analysis, sys.stdout, indent=2, sort_keys=True)
-        sys.stdout.write("\n")
-    else:
-        perf.format_analysis(analysis, sys.stdout)
-    if args.min_findings and len(analysis["findings"]) < args.min_findings:
-        print(f"doctor: {len(analysis['findings'])} finding(s), "
-              f"required at least {args.min_findings}", file=sys.stderr)
-        return 2
-    return 0
-
-
-# ---------------------------------------------------------------------------
-# regress: the bench regression sentinel
-# ---------------------------------------------------------------------------
-
-
-def _cmd_regress(args) -> int:
-    from . import regress as rg
-    try:
-        with open(args.fresh) as f:
-            fresh_doc = json.load(f)
-    except ValueError:
-        print(f"regress: not JSON: {args.fresh}", file=sys.stderr)
-        return 2
-    row = fresh_doc.get("parsed") if isinstance(fresh_doc, dict) and \
-        isinstance(fresh_doc.get("parsed"), dict) else fresh_doc
-    if isinstance(row, dict) and rg.is_replay(row):
-        # a replay is the OLD number wearing a new timestamp — judging it
-        # would always pass; say so loudly and judge nothing
-        print(f"SKIPPED: {args.fresh} is a replayed row, not a fresh "
-              "measurement — nothing to judge", file=sys.stdout)
-        return 2 if args.strict else 0
-    fresh = rg.load_rows(args.fresh)
-    if not fresh:
-        print(f"regress: no judgeable metrics in {args.fresh}",
-              file=sys.stderr)
-        return 2
-    baseline = rg.load_baseline(args.baseline or ["."])
-    if not any(baseline.values()):
-        # an empty or all-replay bank is not a baseline: every banked row
-        # was itself a replay of an older number, so there is no live
-        # trajectory to judge drift against
-        print("NO_LIVE_TRAJECTORY: banked baseline has no live "
-              "measurements (empty or all replays) — nothing to judge "
-              "against", file=sys.stdout)
-        return 2 if args.strict else 0
-    results = rg.compare(fresh, baseline, mad_k=args.mad_k,
-                         rel_floor=args.rel_floor)
-    if args.json:
-        json.dump({"results": results}, sys.stdout, indent=2,
-                  sort_keys=True)
-        sys.stdout.write("\n")
-    else:
-        rg.format_results(results, sys.stdout, explain=args.explain)
-    judged = [r for r in results if r["status"] != "skipped"]
-    if not judged:
-        print("regress: no metric had a banked baseline to judge "
-              "against", file=sys.stderr)
-        return 2 if args.strict else 0
-    if any(r["status"] == "regression" for r in judged):
-        return 1
-    return 0
-
-
-# ---------------------------------------------------------------------------
-# advise: doctor findings -> guarded autotune writes
-# ---------------------------------------------------------------------------
-
-
-def _cmd_advise(args) -> int:
-    from . import advisor, perf
-    events = _read_events_checked(args.journal)
-    analysis = perf.analyze(events, platform=args.platform)
-    actions = advisor.advise(analysis)
-    if args.max_actions:
-        actions = actions[:args.max_actions]
-    results = None
-    if args.apply and actions:
-        results = advisor.apply(actions, repeats=args.repeats,
-                                mad_k=args.mad_k,
-                                rel_floor=args.rel_floor,
-                                persist=not args.no_persist)
-    if args.json:
-        json.dump({"actions": [a.to_dict() for a in actions],
-                   "results": results}, sys.stdout, indent=2,
-                  sort_keys=True)
-        sys.stdout.write("\n")
-    else:
-        advisor.format_results(actions, results, sys.stdout)
-    if args.min_actions and len(actions) < args.min_actions:
-        print(f"advise: {len(actions)} action(s), required at least "
-              f"{args.min_actions}", file=sys.stderr)
-        return 2
     return 0
 
 
@@ -674,8 +557,8 @@ def _cmd_stream(args) -> int:
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] in ("summarize", "trace", "prom", "mem",
-                            "postmortem", "doctor", "regress", "incident",
-                            "advise", "flame", "top", "agg", "stream"):
+                            "postmortem", "incident", "flame", "top",
+                            "agg", "stream"):
         ap = argparse.ArgumentParser(
             prog="python -m distributedarrays_tpu.telemetry",
             description="Summarize or export a telemetry journal/report.")
@@ -710,65 +593,6 @@ def main(argv=None) -> int:
         p.add_argument("--json", action="store_true",
                        help="re-emit the bundle as JSON")
         p.set_defaults(fn=_cmd_postmortem)
-        p = sub.add_parser("doctor",
-                           help="journal -> roofline/overlap/critical-path"
-                                " findings")
-        p.add_argument("journal", help="JSONL journal path ('-' = stdin)")
-        p.add_argument("--platform", default=None,
-                       help="peak-table platform (v5e/v5p/cpu; default "
-                            "cpu, DA_TPU_PEAKS overrides values)")
-        p.add_argument("--min-findings", type=int, default=0,
-                       help="exit 2 unless at least N findings (CI gate)")
-        p.add_argument("--json", action="store_true",
-                       help="emit the full analysis as JSON")
-        p.set_defaults(fn=_cmd_doctor)
-        p = sub.add_parser("regress",
-                           help="judge a fresh bench row/table against "
-                                "the banked BENCH_r* trajectory")
-        p.add_argument("fresh", help="fresh bench row / BENCH_r wrapper / "
-                                     "details table (JSON)")
-        p.add_argument("--baseline", action="append", default=None,
-                       help="baseline dir (BENCH_r*.json scanned) or "
-                            "file; repeatable; default '.'")
-        p.add_argument("--mad-k", type=float, default=3.0,
-                       help="MAD multiplier for the noise threshold")
-        p.add_argument("--rel-floor", type=float, default=0.15,
-                       help="relative degradation floor")
-        p.add_argument("--strict", action="store_true",
-                       help="exit 2 when nothing could be judged")
-        p.add_argument("--explain", action="store_true",
-                       help="print the per-metric median/MAD baseline "
-                            "and direction next to each verdict")
-        p.add_argument("--json", action="store_true",
-                       help="emit results as JSON")
-        p.set_defaults(fn=_cmd_regress)
-        p = sub.add_parser("advise",
-                           help="doctor findings -> tuning actions; "
-                                "--apply executes them under the "
-                                "micro-probe rollback guard")
-        p.add_argument("journal", help="JSONL journal path ('-' = stdin)")
-        p.add_argument("--platform", default=None,
-                       help="peak-table platform for the doctor pass")
-        p.add_argument("--apply", action="store_true",
-                       help="write the proposals (provenance-stamped), "
-                            "micro-probe before/after, auto-roll-back "
-                            "regressions")
-        p.add_argument("--repeats", type=int, default=3,
-                       help="micro-probe samples per side (default 3)")
-        p.add_argument("--mad-k", type=float, default=3.0,
-                       help="MAD multiplier for the rollback threshold")
-        p.add_argument("--rel-floor", type=float, default=0.15,
-                       help="relative regression floor for rollback")
-        p.add_argument("--max-actions", type=int, default=0,
-                       help="cap the number of actions taken (0 = all)")
-        p.add_argument("--min-actions", type=int, default=0,
-                       help="exit 2 unless at least N actions (CI gate)")
-        p.add_argument("--no-persist", action="store_true",
-                       help="keep applied tunes in-process only (default "
-                            "persists to the autotune cache file)")
-        p.add_argument("--json", action="store_true",
-                       help="emit actions + apply results as JSON")
-        p.set_defaults(fn=_cmd_advise)
         p = sub.add_parser("incident",
                            help="merge per-host journals and reconstruct "
                                 "ordered incident reports")

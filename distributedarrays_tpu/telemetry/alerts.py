@@ -19,11 +19,10 @@ the *while-it-degrades* half:
   Prometheus export, so a scraper sees exactly what the journal says.
 - **the health sampler** (:func:`start_sampler`): a daemon thread
   (``DA_TPU_TELEMETRY_SAMPLE_S``, default OFF) snapshotting HBM live
-  bytes, serve queue depth, train step rate and MFU (from PR 11's
-  ``train_step_cost`` stamps on ``train.step`` spans) as journaled
-  gauges every tick, and driving the alert manager — timelines get data
-  *between* spans, and alerts fire without any cooperation from the
-  workload.
+  bytes, serve queue depth and train step rate (from the ``train.step``
+  spans) as journaled gauges every tick, and driving the alert manager
+  — timelines get data *between* spans, and alerts fire without any
+  cooperation from the workload.
 
 Disabled telemetry (``DA_TPU_TELEMETRY=0``) keeps the PR 1 discipline:
 the sampler never starts, and every evaluation entry point is a single
@@ -42,7 +41,6 @@ from typing import Callable
 from . import core, memory
 
 __all__ = ["AlertRule", "AlertManager", "default_rules",
-           "autotune_regressed_rule", "ensure_autotune_rule",
            "start_sampler", "stop_sampler", "sampler_running",
            "SAMPLE_ENV"]
 
@@ -254,50 +252,6 @@ def default_rules(*, p99_slo_s: float = 0.5, shed_slo: float = 0.1,
     return rules
 
 
-def _rollback_delta_signal():
-    """Incremental ``autotune.advisor_rollbacks`` delta between
-    evaluations — same windowed-rate pattern as the shed-fraction
-    signal: a rollback is an *event*, and the process-lifetime total
-    would keep the alert firing forever."""
-    last = {"total": _counter_total("autotune.advisor_rollbacks")}
-
-    def signal() -> float | None:
-        total = _counter_total("autotune.advisor_rollbacks")
-        delta = total - last["total"]
-        last["total"] = total
-        return max(delta, 0.0)
-    return signal
-
-
-def autotune_regressed_rule(*, fast_window_s: float = 60.0,
-                            slow_window_s: float = 300.0) -> AlertRule:
-    """A self-tune that regressed under the advisor's micro-probe and was
-    rolled back is an *incident*, never a silent slowdown: the rule
-    breaches on any new rollback since the previous evaluation (burn
-    fractions near zero — one bad tune among healthy ticks must still
-    page) and clears once the rollback sample ages out of the fast
-    window."""
-    return AlertRule(
-        "autotune_regressed", _rollback_delta_signal(),
-        threshold=0.0, op=">",
-        fast_window_s=fast_window_s, slow_window_s=slow_window_s,
-        fast_burn=0.01, slow_burn=0.01,
-        description="advisor tune regressed under micro-probe; rolled back")
-
-
-def ensure_autotune_rule(manager: AlertManager | None = None) -> AlertRule:
-    """Idempotently register :func:`autotune_regressed_rule` on
-    ``manager`` (default: the process-wide manager); returns the rule
-    installed there."""
-    mgr = manager if manager is not None else _default_manager
-    for r in mgr.rules():
-        if r.name == "autotune_regressed":
-            return r
-    rule = autotune_regressed_rule()
-    mgr.add(rule)
-    return rule
-
-
 # ---------------------------------------------------------------------------
 # the always-on health sampler
 # ---------------------------------------------------------------------------
@@ -305,9 +259,8 @@ def ensure_autotune_rule(manager: AlertManager | None = None) -> AlertRule:
 
 class _HealthSampler(threading.Thread):
     """Daemon thread: one ``sample/health`` journal event + journaled
-    gauges per tick, plus one alert-manager evaluation.  Step rate and
-    MFU derive from the ``train.step`` span events in the core ring —
-    their ``train_step_cost`` flops stamps against the platform peak."""
+    gauges per tick, plus one alert-manager evaluation.  Step rate
+    derives from the ``train.step`` span events in the core ring."""
 
     def __init__(self, interval_s: float, manager: AlertManager):
         super().__init__(name="da-tpu-health-sampler", daemon=True)
@@ -315,16 +268,13 @@ class _HealthSampler(threading.Thread):
         self.manager = manager
         self._stop = threading.Event()
         self._last_seq = -1
-        self._peak_flops: float | None = None
 
     def stop(self) -> None:
         self._stop.set()
 
-    def _train_window(self) -> tuple[int, float, float]:
-        """(steps, seconds, flops) from train.step span events recorded
-        since the previous tick."""
+    def _train_steps(self) -> int:
+        """``train.step`` span events recorded since the previous tick."""
         steps = 0
-        dur = flops = 0.0
         last = self._last_seq
         for e in core.events("span"):
             seq = e.get("seq", -1)
@@ -332,13 +282,7 @@ class _HealthSampler(threading.Thread):
                 continue
             self._last_seq = max(self._last_seq, seq)
             steps += 1
-            dur += float(e.get("dur") or 0.0)
-            labels = e.get("labels") or {}
-            try:
-                flops += float(labels.get("flops") or 0.0)
-            except (TypeError, ValueError):
-                pass
-        return steps, dur, flops
+        return steps
 
     def _tick(self) -> None:
         if not core._ENABLED:
@@ -354,24 +298,11 @@ class _HealthSampler(threading.Thread):
         depth = core.gauge_value("serve.queue_depth")
         if depth is not None:
             fields["queue_depth"] = depth
-        steps, dur, flops = self._train_window()
+        steps = self._train_steps()
         if steps:
             rate = steps / self.interval_s
             core.set_gauge("health.step_rate", rate, journal=True)
             fields["step_rate"] = round(rate, 4)
-            if flops > 0 and dur > 0:
-                if self._peak_flops is None:
-                    try:
-                        from . import perf as _perf
-                        self._peak_flops = float(
-                            _perf.peaks_for(None)["flops"])
-                    except Exception:
-                        self._peak_flops = 0.0
-                if self._peak_flops:
-                    mfu = min(flops / dur / self._peak_flops, 1.0)
-                    core.set_gauge("health.mfu", round(mfu, 6),
-                                   journal=True)
-                    fields["mfu"] = round(mfu, 6)
         core.event("sample", "health", **fields)
         try:
             self.manager.evaluate()

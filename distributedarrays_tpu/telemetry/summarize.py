@@ -56,7 +56,6 @@ def summarize(events: list[dict]) -> dict:
     spans: dict[str, dict] = {}
     incidents: set = set()
     alerts: dict[str, int] = {}
-    tuning: list[dict] = []
     # per-host rollups: multihost journals are merged by concatenation
     # (every event carries host/pid), so the summary re-groups them
     by_host: dict[str, dict] = {}
@@ -82,13 +81,6 @@ def summarize(events: list[dict]) -> dict:
         if cat == "alert" and name is not None:
             ak = f"{name}:{e.get('state', '?')}"
             alerts[ak] = alerts.get(ak, 0) + 1
-        if cat == "autotune" and name in ("advise", "undo"):
-            # the advisor's provenance trail: what was written, from
-            # which finding, and whether the micro-probe kept it
-            tuning.append({k: e.get(k) for k in
-                           ("name", "kernel", "key", "kind", "finding",
-                            "old", "new", "status", "reason")
-                           if e.get(k) is not None})
         if cat == "comm":
             kind = str(name)
             c = comm.setdefault(kind, {"ops": 0, "bytes": 0,
@@ -136,7 +128,6 @@ def summarize(events: list[dict]) -> dict:
         "spans": dict(sorted(spans.items())),
         "incidents": sorted(incidents),
         "alerts": dict(sorted(alerts.items())),
-        "tuning": tuning,
     }
 
 
@@ -173,18 +164,6 @@ def format_summary(summary: dict, out: TextIO) -> None:
         out.write("\nalert transitions:\n")
         for key, n in alerts.items():
             out.write(f"  {key:<40} {n}\n")
-    tuning = summary.get("tuning") or []
-    if tuning:
-        out.write("\ntuning provenance (advisor writes):\n")
-        for t in tuning:
-            label = t.get("status") or t.get("name") or "?"
-            out.write(f"  {label.upper():<12} "
-                      f"{t.get('kernel', '?')}[{t.get('key', '?')}]: "
-                      f"{t.get('old')} -> {t.get('new')}"
-                      + (f"  ({t['finding']})" if t.get("finding")
-                         else "") + "\n")
-            if t.get("reason"):
-                out.write(f"               {t['reason']}\n")
     out.write("\nby category:\n")
     for cat, n in summary["by_category"].items():
         out.write(f"  {cat:<16} {n}\n")

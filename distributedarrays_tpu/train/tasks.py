@@ -15,8 +15,6 @@ and nothing else:
 - ``batch(step) -> tuple of host arrays`` — the deterministic data
   pipeline: the same step index must yield the same batch on every
   (re-)run, or a recovery retry could never reproduce the trajectory.
-- ``step_flops(batch_size)`` — analytic fwd+bwd flops for the perf
-  doctor's ``train.step`` stamps (0.0 when unknown).
 
 The two constructors reuse the existing model layer rather than define
 new networks: :func:`mlp_task` trains :mod:`..models.mlp`'s network on a
@@ -46,7 +44,6 @@ class TrainTask:
     init_params: Callable
     loss_sum: Callable            # (params, batch_tuple, w) -> scalar sum
     batch: Callable               # (step) -> tuple of host np arrays
-    step_flops: Callable = lambda batch_size: 0.0
 
 
 def _mix_rng(seed: int, step: int) -> np.random.Generator:
@@ -83,15 +80,9 @@ def mlp_task(sizes: Sequence[int] = (16, 32, 32, 4),
         y = np.tanh(x @ teacher).astype(np.float32)
         return x, y
 
-    def step_flops(bsz):
-        # fwd GEMMs: 2*B*in*out per layer; bwd ≈ 2x fwd
-        fwd = sum(2.0 * bsz * a * b for a, b in zip(sizes, sizes[1:]))
-        return 3.0 * fwd
-
     return TrainTask(name=f"mlp{ 'x'.join(map(str, sizes)) }",
                      batch_size=batch_size, init_params=init_params,
-                     loss_sum=loss_sum, batch=batch,
-                     step_flops=step_flops)
+                     loss_sum=loss_sum, batch=batch)
 
 
 def transformer_task(vocab: int = 64, dim: int = 32, heads: int = 2,
@@ -129,13 +120,6 @@ def transformer_task(vocab: int = 64, dim: int = 32, heads: int = 2,
         toks = (offs + np.arange(seq + 1)) % vocab
         return (toks.astype(np.int32),)
 
-    def step_flops(bsz):
-        # dominant GEMMs per token: qkv+proj (8*dim^2) + ffn
-        # (2*4*dim^2*2) per layer, + the vocab head; fwd+bwd ≈ 3x fwd
-        per_tok = layers * (8.0 * dim * dim + 16.0 * dim * dim) \
-            + 2.0 * dim * vocab
-        return 3.0 * bsz * seq * per_tok
-
     return TrainTask(name=f"transformer_d{dim}", batch_size=batch_size,
                      init_params=init_params, loss_sum=loss_sum,
-                     batch=batch, step_flops=step_flops)
+                     batch=batch)

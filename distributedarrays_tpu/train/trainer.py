@@ -382,7 +382,6 @@ class Trainer:
         ppad = -(-n_params // p) * p
         batch_darrs, b_real = self._batch_for(n, ranks)
         *bleaves, wq = [d.garray for d in batch_darrs]
-        b_pad = int(bleaves[0].shape[0])
         bshapes = tuple(tuple(int(s) for s in x.shape) for x in bleaves)
         bdtypes = tuple(str(x.dtype) for x in bleaves)
         grad_fn, sync_fn, progkey, fresh_build = self._programs(
@@ -390,13 +389,6 @@ class Trainer:
 
         epoch = n // self.save_every if self.save_every else 0
         with _tm.span("train.step", step=n, ranks=p):
-            if _tm.enabled():
-                from ..telemetry import perf as _perf
-                _tm.annotate(**_perf.train_step_cost(
-                    n_params=ppad, p=p,
-                    flops=float(self.task.step_flops(b_pad)),
-                    batch_bytes=sum(int(x.nbytes) for x in bleaves),
-                    nslots=self.opt.nslots))
             t0 = time.monotonic()
             # chaos site: the top of every step — the "host dies
             # mid-epoch" injection point (a hang here counts against the

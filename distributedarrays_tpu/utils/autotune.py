@@ -13,7 +13,7 @@ Three pieces:
 - ``sweep(...)``: time a list of candidate configs with an injectable
   timer and record the winner;
 - optional JSON persistence (``save``/``load``) so a one-off tuning run
-  (bench.py's hardware sweep, or a user-driven ``sweep``) carries across
+  (a ``sweep``) carries across
   processes via the ``DAT_AUTOTUNE_CACHE`` env var, loaded lazily on
   first lookup.
 """
@@ -29,25 +29,11 @@ from .. import telemetry as _tm
 
 __all__ = ["get", "record", "sweep", "save", "load", "clear", "key_for",
            "device_key_for", "valid_ints",
-           "default_cache_path", "save_default", "seed_path",
-           "provenance_for", "provenance_table", "undo", "undo_log"]
+           "default_cache_path", "save_default", "seed_path"]
 
 _LOCK = threading.RLock()
 _REGISTRY: dict[str, dict[str, Any]] = {}
 _LOADED_ENV = False
-
-# Provenance sidecar: (kernel, key) -> {"source": ..., "finding": ...,
-# "evidence": {...}, ...} for entries written with evidence attached
-# (the telemetry advisor).  Persisted under a reserved top-level key in
-# the cache JSON so the registry namespace itself stays entries-only.
-_PROV_KEY = "__provenance__"
-_PROVENANCE: dict[str, dict[str, dict]] = {}
-
-# Bounded undo journal for provenance-stamped writes: each entry captures
-# the pre-write state so a tune that regresses under the micro-probe can
-# be rolled back exactly (including "there was no entry before").
-_UNDO_LIMIT = 64
-_UNDO: list[dict] = []
 
 
 def key_for(*parts) -> str:
@@ -86,8 +72,8 @@ def valid_ints(entry, lengths: tuple[int, ...]):
 def default_cache_path() -> str:
     """Where tuning results persist across processes: the
     ``DAT_AUTOTUNE_CACHE`` env var if set; in a repo CHECKOUT, an
-    ``AUTOTUNE_CACHE.json`` next to the package (gitignored) so bench.py's
-    hardware sweep is picked up by every later process in the same tree;
+    ``AUTOTUNE_CACHE.json`` next to the package (gitignored) so a
+    persisted sweep is picked up by every later process in the same tree;
     for an installed package, a per-user cache dir (never site-packages,
     which may be read-only or shared across unrelated projects)."""
     env = os.environ.get("DAT_AUTOTUNE_CACHE")
@@ -166,98 +152,16 @@ def get(kernel: str, key: str, default=None):
     return entry
 
 
-def record(kernel: str, key: str, config, *,
-           provenance: Mapping | None = None) -> None:
-    """Store ``config`` for ``(kernel, key)``.
-
-    With ``provenance`` (a mapping — conventionally ``source``,
-    ``finding``, and ``evidence`` with the measured before-metrics), the
-    write is stamped in the provenance sidecar AND journaled in the
-    bounded undo log, so :func:`undo` can restore the exact pre-write
-    state.  A later plain ``record`` for the same key (a sweep, a user
-    write) drops the stale provenance — the entry no longer reflects the
-    stamped evidence."""
+def record(kernel: str, key: str, config) -> None:
+    """Store ``config`` for ``(kernel, key)``."""
     with _LOCK:
         _maybe_load_env()
-        entries = _REGISTRY.setdefault(kernel, {})
-        if provenance is not None:
-            _UNDO.append({
-                "kernel": kernel, "key": key,
-                "had_prev": key in entries,
-                "prev": entries.get(key),
-                "prev_provenance": _PROVENANCE.get(kernel, {}).get(key),
-                "config": config,
-                "provenance": dict(provenance),
-            })
-            del _UNDO[:-_UNDO_LIMIT]
-            _PROVENANCE.setdefault(kernel, {})[key] = dict(provenance)
-        else:
-            _PROVENANCE.get(kernel, {}).pop(key, None)
-        entries[key] = config
-
-
-def provenance_for(kernel: str, key: str) -> dict | None:
-    """The provenance stamp for ``(kernel, key)``, or None for entries
-    written without evidence (seed, sweep, hand edit)."""
-    with _LOCK:
-        _maybe_load_env()
-        prov = _PROVENANCE.get(kernel, {}).get(key)
-        return dict(prov) if prov is not None else None
-
-
-def provenance_table() -> dict[str, dict[str, dict]]:
-    """Snapshot of the whole provenance sidecar (kernel -> key -> stamp)."""
-    with _LOCK:
-        _maybe_load_env()
-        return {k: {key: dict(p) for key, p in v.items()}
-                for k, v in _PROVENANCE.items() if v}
-
-
-def undo_log() -> list[dict]:
-    """Snapshot of the bounded undo journal (oldest first)."""
-    with _LOCK:
-        return [dict(e) for e in _UNDO]
-
-
-def undo(kernel: str, key: str) -> bool:
-    """Roll back the most recent provenance-stamped write for
-    ``(kernel, key)``: the entry (and its provenance) is restored to the
-    exact pre-write state — including deletion when there was no entry
-    before.  Returns False when the undo journal holds no write for the
-    pair.  Counted as ``autotune.undo`` and journaled."""
-    with _LOCK:
-        _maybe_load_env()
-        for i in range(len(_UNDO) - 1, -1, -1):
-            e = _UNDO[i]
-            if e["kernel"] != kernel or e["key"] != key:
-                continue
-            del _UNDO[i]
-            entries = _REGISTRY.setdefault(kernel, {})
-            if e["had_prev"]:
-                entries[key] = e["prev"]
-            else:
-                entries.pop(key, None)
-            if e["prev_provenance"] is not None:
-                _PROVENANCE.setdefault(kernel, {})[key] = \
-                    dict(e["prev_provenance"])
-            else:
-                _PROVENANCE.get(kernel, {}).pop(key, None)
-            restored = e["prev"] if e["had_prev"] else None
-            break
-        else:
-            return False
-    _tm.count("autotune.undo", kernel=kernel)
-    if _tm.enabled():
-        _tm.event("autotune", "undo", kernel=kernel, key=key,
-                  restored=restored)
-    return True
+        _REGISTRY.setdefault(kernel, {})[key] = config
 
 
 def clear() -> None:
     with _LOCK:
         _REGISTRY.clear()
-        _PROVENANCE.clear()
-        del _UNDO[:]
 
 
 def save(path: str) -> None:
@@ -265,13 +169,9 @@ def save(path: str) -> None:
         parent = os.path.dirname(path)
         if parent:
             os.makedirs(parent, exist_ok=True)
-        data: dict[str, Any] = dict(_REGISTRY)
-        prov = {k: v for k, v in _PROVENANCE.items() if v}
-        if prov:
-            data[_PROV_KEY] = prov
         tmp = path + ".tmp"
         with open(tmp, "w") as f:
-            json.dump(data, f, indent=2, sort_keys=True)
+            json.dump(_REGISTRY, f, indent=2, sort_keys=True)
         os.replace(tmp, path)
 
 
@@ -281,16 +181,7 @@ def load(path: str) -> None:
     if not isinstance(data, dict):
         raise ValueError(f"autotune cache {path} is not a JSON object")
     with _LOCK:
-        prov = data.pop(_PROV_KEY, None)
-        if isinstance(prov, dict):
-            for kernel, stamps in prov.items():
-                if isinstance(stamps, dict):
-                    _PROVENANCE.setdefault(kernel, {}).update(
-                        {k: dict(v) for k, v in stamps.items()
-                         if isinstance(v, dict)})
         for kernel, entries in data.items():
-            if kernel.startswith("__"):
-                continue   # reserved sidecar namespaces, never entries
             _REGISTRY.setdefault(kernel, {}).update(entries)
 
 

@@ -266,12 +266,6 @@ def save(path: str | os.PathLike, tree: Any, store: str = "npz") -> None:
         arrays: dict[str, np.ndarray] = {}
         with _tm.span("checkpoint.save.encode", _journal=False):
             meta = _encode(tree, arrays)
-        if _tm.enabled():
-            from ..telemetry import perf as _perf
-            # cost stamp on the checkpoint.save span: payload bytes
-            # through the host once (disk I/O rides the HBM column)
-            _tm.annotate(**_perf.transfer_cost(
-                sum(a.nbytes for a in arrays.values())))
         with _tm.span("checkpoint.save.write", _journal=False):
             _write_store(Path(path), meta, arrays, store)
         _tm.count("checkpoint.saves")
@@ -321,11 +315,6 @@ def load(path: str | os.PathLike) -> Any:
         _verify_integrity(path, meta_doc, arrays)
         with _tm.span("checkpoint.restore.decode", _journal=False):
             out = _decode(meta, arrays)
-        if _tm.enabled():
-            from ..telemetry import perf as _perf
-            # cost stamp mirroring save's: restored payload bytes
-            _tm.annotate(**_perf.transfer_cost(
-                sum(a.nbytes for a in arrays.values())))
         _tm.count("checkpoint.restores")
         # cold path: checkpoint I/O dominates the event cost
         _tm.event("checkpoint", "restore_end", path=str(path),  # dalint: disable=DAL003

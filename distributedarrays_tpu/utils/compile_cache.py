@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives for this checkout.
 
 One rule, shared by every entry point that compiles for a chip
-(``chip_smoke.py``, ``bench.py``, ``examples/_setup.py``): where
+(``chip_smoke.py``, ``examples/_setup.py``): where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing is
 set in code; where it is not, the cache goes to ``<checkout>/.jax_cache``
 — a fixed path (the path is part of the cache key, so a directory that

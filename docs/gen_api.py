@@ -56,11 +56,8 @@ MODULES = [
     "distributedarrays_tpu.telemetry.flight",
     "distributedarrays_tpu.telemetry.export",
     "distributedarrays_tpu.telemetry.summarize",
-    "distributedarrays_tpu.telemetry.perf",
-    "distributedarrays_tpu.telemetry.regress",
     "distributedarrays_tpu.telemetry.cluster",
     "distributedarrays_tpu.telemetry.alerts",
-    "distributedarrays_tpu.telemetry.advisor",
     "distributedarrays_tpu.telemetry.stream",
     "distributedarrays_tpu.telemetry.agg",
     "distributedarrays_tpu.analysis",

@@ -16,7 +16,7 @@ compiled collective schedules run as ONE shard_map program each:
   unrolled contraction steps, O(one panel) peak memory.
 
 Dispatch from plain ``A @ B`` promotes to these only by measurement
-(``tune_matmul_impl_summa`` / bench.py) — this demo calls them directly
+(``tune_matmul_impl_summa``) — this demo calls them directly
 and checks the dense oracle.  Runs on the virtual CPU mesh.
 """
 

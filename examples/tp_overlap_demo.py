@@ -10,7 +10,7 @@ Two round-3 performance features on one page:
    MXU: dynamic per-row/per-column symmetric quantization, exact int32
    accumulation, dequant fused into the tile flush.  On e-class TPUs
    the int8 MXU rate is 2x bf16, so this path can beat the chip's bf16
-   peak (bench.py's ``int8_gemm`` config measures it).
+   peak (not measured: no benchmark cell runs it).
 """
 
 import _setup  # noqa: F401
@@ -74,7 +74,7 @@ if len(jax.devices()) >= 4:
     B2 = rng.standard_normal((M, M)).astype(np.float32)
     ga = dat.distribute(A2, procs=range(4), dist=(2, 2))
     gb = dat.distribute(B2, procs=range(4), dist=(2, 2))
-    # promotion is by measurement (tune_matmul_impl_summa / bench.py);
+    # promotion is by measurement (tune_matmul_impl_summa);
     # force the registry here so the demo exercises the owned schedule
     autotune.record("matmul_impl_dist",
                     la._impl_key(M, M, M, "2x2", ga.dtype, gb.dtype),

@@ -1,8 +1,7 @@
 """Cluster observatory suite: cross-host journal merge (clock-offset /
 wall-anchor / first-common-event alignment, dedup, rotated siblings),
 causal incident reconstruction (episode grouping, bundle attribution,
-orphan witnesses), bundle schema versioning, the incident CLI, the
-regress empty-baseline guard — and the slow two-process partition
+orphan witnesses), bundle schema versioning, the incident CLI — and the slow two-process partition
 incident acceptance soak (quorum side in-process with the sampler and a
 burn-rate alert armed, minority side in a subprocess, the two journals
 merged into ONE complete incident story with zero orphans).
@@ -419,28 +418,6 @@ def test_cli_incident_rc2_on_empty_journal(tmp_path, capsys):
     empty.write_text("")
     assert _cli(["incident", str(empty)]) == 2
     assert "journal is empty" in capsys.readouterr().err
-
-
-# ---------------------------------------------------------------------------
-# regress: the empty / all-replay baseline guard
-# ---------------------------------------------------------------------------
-
-
-def test_regress_no_live_trajectory_is_typed_not_crash(tmp_path, capsys):
-    fresh = tmp_path / "fresh.json"
-    fresh.write_text(json.dumps({"metric": "step_ms", "value": 1.2}))
-    bank = tmp_path / "bank"
-    bank.mkdir()
-    # empty bank: rc 0 with the one-line typed message
-    assert _cli(["regress", str(fresh), "--baseline", str(bank)]) == 0
-    assert "NO_LIVE_TRAJECTORY" in capsys.readouterr().out
-    # an all-replay bank is just as judgeless; --strict makes it rc 2
-    (bank / "BENCH_r1.json").write_text(json.dumps(
-        {"metric": "step_ms", "value": 1.0, "replayed": True}))
-    assert _cli(["regress", str(fresh), "--baseline", str(bank)]) == 0
-    assert "NO_LIVE_TRAJECTORY" in capsys.readouterr().out
-    assert _cli(["regress", str(fresh), "--baseline", str(bank),
-                 "--strict"]) == 2
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,7 @@ from distributedarrays_tpu.serve import (Cancelled, DeadlineExceeded,
                                          Draining, Overloaded, Rejected,
                                          ServeError)
 from distributedarrays_tpu.serve.decode import _decode_attention
-from distributedarrays_tpu.telemetry import export, flight, perf
+from distributedarrays_tpu.telemetry import export, flight
 from distributedarrays_tpu.telemetry import memory as tmem
 from distributedarrays_tpu.telemetry.fixtures import telemetry_capture  # noqa: F401 (fixture)
 from distributedarrays_tpu.telemetry.summarize import read_journal
@@ -472,23 +472,25 @@ def test_aio_generate_streams_and_cancels_on_exit():
 
 
 # ---------------------------------------------------------------------------
-# the two regimes under the roofline doctor + per-endpoint SLO histograms
+# the two batch classes as spans + per-endpoint SLO histograms
 # ---------------------------------------------------------------------------
 
 
-def test_doctor_classifies_prefill_compute_decode_hbm(telemetry_capture):
+def test_prefill_and_decode_spans_say_what_ran(telemetry_capture):
     model = _model()
     rng = np.random.default_rng(11)
     prompt = rng.integers(0, model.vocab, size=64).tolist()
     with _engine(model, use_ring_prefill=True, max_new_tokens=4) as eng:
         eng.submit(prompt).result(timeout=30)
-    occs = perf.classify(read_journal(telemetry_capture.journal_path()),
-                         perf.peaks_for("cpu"))
-    pre = [o for o in occs if o["name"] == "serve.prefill"]
-    dec = [o for o in occs if o["name"] == "serve.decode"]
-    assert pre and dec
-    assert all(o["bound"] == "compute" for o in pre), pre
-    assert all(o["bound"] == "hbm" for o in dec), dec
+    spans = [e for e in read_journal(telemetry_capture.journal_path())
+             if e.get("cat") == "span"]
+    pre = [s["labels"] for s in spans if s["name"] == "serve.prefill"]
+    dec = [s["labels"] for s in spans if s["name"] == "serve.decode"]
+    # one prefill of the whole prompt, then one decode round a new token
+    # after the first (which the prefill's last row yields)
+    assert [(int(p["ntok"]), p["rebuild"]) for p in pre] == [(64, False)]
+    assert len(dec) == 3 and all(int(d["size"]) == 1 for d in dec), dec
+    assert {d["endpoint"] for d in pre + dec} == {"decode"}
     # both regimes land in the per-endpoint SLO histogram family
     text = export.to_prometheus(telemetry_capture.report())
     assert 'da_tpu_serve_slo_request_s_bucket{endpoint="decode.prefill"' \

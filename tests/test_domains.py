@@ -243,6 +243,10 @@ def test_cross_domain_reshard_survives_seeded_slow_link(rng):
     from distributedarrays_tpu.parallel import reshard as R
 
     domains.configure("4,4")
+    # the plan cache is keyed on the layout pair, not on the domain
+    # topology (ROADMAP Queue 3 item 16): a plan of this pair made by
+    # another file's test on the same worker would carry its stamps
+    R._plan_cached.cache_clear()
     faults.configure(seed=1234, plan=[
         {"site": "reshard.chunk", "action": "slow_link", "at": 1,
          "count": -1, "hang_s": 0.01}])

@@ -6,7 +6,7 @@ The star-import / export-hygiene checks run through the ``analysis`` rule
 engine (DAL005) — the ad-hoc AST walks this file used to carry moved into
 ``distributedarrays_tpu.analysis.rules``; this file asserts the package is
 clean under them, plus the dalint self-lint gate over the whole lint
-surface (package, examples/, bench.py)."""
+surface (package, examples/)."""
 
 import importlib
 import pkgutil
@@ -79,7 +79,7 @@ def test_dalint_self_clean():
     # lint_paths runs EVERY registered rule, so this also arms the PR 9
     # DAL008/DAL009 lock analyses — a new blocking-under-lock site or
     # lock-order cycle fails here before CI
-    targets = [PKG_ROOT, REPO_ROOT / "examples", REPO_ROOT / "bench.py"]
+    targets = [PKG_ROOT, REPO_ROOT / "examples"]
     active = [f for f in lint_paths(targets) if not f.suppressed]
     assert active == [], "\n".join(f.format() for f in active)
     assert {"DAL008", "DAL009"} <= set(RULES), "lock rules must be armed"
@@ -92,7 +92,7 @@ def test_dalint_no_rotted_suppressions():
     from distributedarrays_tpu.analysis.engine import (lint_file,
                                                        unused_suppressions)
     from distributedarrays_tpu.analysis.engine import iter_python_files
-    targets = [PKG_ROOT, REPO_ROOT / "examples", REPO_ROOT / "bench.py"]
+    targets = [PKG_ROOT, REPO_ROOT / "examples"]
     stale = []
     for f in iter_python_files(targets):
         per_file = lint_file(f)

@@ -380,41 +380,6 @@ def test_cli_flame_journal_min_frac(telemetry_capture, capsys, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# regress guards the widened banking trajectory
-# ---------------------------------------------------------------------------
-
-
-def test_regress_directions_cover_partial_banked_metrics():
-    # every metric the widened bench partial-banking can leave behind
-    # must be judged in the right direction by `telemetry regress` —
-    # a partial row is only useful if the guard reads it correctly
-    from distributedarrays_tpu.telemetry import regress as tregress
-    lower = ["reshard_even_s", "reshard_multiaxis_s",
-             "reshard_multiaxis_device_put_s", "ring_gemm_xla_s",
-             "train_step_s", "serve_decode_slo_s", "cg_poisson_time_s",
-             "cg_poisson_iters", "cg_poisson_residual"]
-    higher = ["reshard_even_gbps", "reshard_multiaxis_gbps",
-              "ring_gemm_xla_tflops", "train_step_tflops",
-              "serve_decode_single_stream_tokens_per_s",
-              "serve_decode_tokens_per_s"]
-    for m in lower:
-        assert tregress.direction(m) == -1, m
-    for m in higher:
-        assert tregress.direction(m) == 1, m
-
-
-def test_bench_partial_rows_not_treated_as_banked():
-    import bench
-    for label in ("reshard_even", "reshard_multiaxis", "ring_gemm",
-                  "train_step", "serve_decode", "cg_poisson"):
-        sent = bench.BANKED_SENTINELS[label]
-        details = {sent: 1.0, f"{label}_partial": True}
-        assert not bench._banked_in(details, label), label
-        details.pop(f"{label}_partial")
-        assert bench._banked_in(details, label), label
-
-
-# ---------------------------------------------------------------------------
 # two-host soak (slow): live plane matches post-hoc, alert round-trip
 # ---------------------------------------------------------------------------
 

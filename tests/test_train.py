@@ -532,22 +532,18 @@ def test_chaos_soak_replay_is_deterministic(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_train_step_spans_are_stamped_and_doctor_sees_them():
-    from distributedarrays_tpu.telemetry import perf
+def test_train_step_spans_say_what_ran():
     ev0 = len(tm.events())
     with _trainer() as t:
         t.fit(3)
-    events = tm.events()[ev0:]
-    steps = [e for e in events
-             if e.get("cat") == "span" and e.get("name") == "train.step"]
-    assert len(steps) == 3
+    spans = [e for e in tm.events()[ev0:] if e.get("cat") == "span"]
+    steps = [e for e in spans if e.get("name") == "train.step"]
+    assert [int(e["labels"]["step"]) for e in steps] == [0, 1, 2]
     for e in steps:
-        labels = e.get("labels") or {}
-        assert float(labels.get("bytes_ici", 0)) > 0    # stamped
-        assert float(labels.get("flops", 0)) > 0
-        assert labels.get("dispatch") in ("rdma", "xla")
-    per_step = perf.train_step_overlap(events)
-    assert [o["step"] for o in per_step] == [0, 1, 2]
-    for o in per_step:
-        assert o["comm_s"] > 0                          # sync measured
-        assert 0.0 <= o["overlap_frac"] <= 1.0
+        assert int(e["labels"]["ranks"]) >= 1
+        assert e["labels"].get("dispatch") in ("rdma", "xla")
+        # the step's two phases are its children: the gradient program
+        # (compute) and the reduce-scatter/all-gather sync (comm)
+        kids = {(k["name"], k["labels"].get("kind")) for k in spans
+                if k.get("parent_id") == e["span_id"]}
+        assert {("train.grad", "compute"), ("train.sync", "comm")} <= kids

@@ -1,20 +1,16 @@
 #!/usr/bin/env python
-"""Scripted telemetry workload for the performance-observatory gates.
+"""Scripted telemetry workload for the journal, trace and live-plane gates.
 
 Runs a small but representative slice of the framework — h2d distribute,
 a distributed GEMM, an RDMA-armed (interpret-mode) single-axis reshard
 NEXT TO its XLA twin, a serve round trip over an SPMD endpoint, a
-mapreduce, and a d2h gather — with the journal enabled, so
+solver, a mapreduce, and a d2h gather — with the journal enabled:
 
     python tools/perf_workload.py /tmp/journal.jsonl
-    python -m distributedarrays_tpu.telemetry doctor /tmp/journal.jsonl \
-        --min-findings 1
+    python -m distributedarrays_tpu.telemetry trace /tmp/journal.jsonl
 
-exercises the whole doctor pipeline (roofline classification, the
-rdma-vs-xla reshard overlap comparison, request-trace flows, ranked
-findings).  Shared by the CI observability leg and
-tests/test_perf.py's CLI round-trip, so the acceptance workload cannot
-drift between the two.
+Shared by the CI observability leg (Perfetto export, live-plane streaming
+gate) and the trace-id tests, so the workload cannot drift between them.
 """
 
 import os
@@ -42,7 +38,7 @@ import distributedarrays_tpu as dat  # noqa: E402
 from distributedarrays_tpu.parallel import spmd_mode as sm  # noqa: E402
 from distributedarrays_tpu.serve import Server, ServeConfig  # noqa: E402
 
-# -- h2d + distributed GEMM (cost-stamped matmul span) ----------------------
+# -- h2d + distributed GEMM -------------------------------------------------
 A = dat.distribute(np.arange(64 * 64, dtype=np.float32).reshape(64, 64))
 B = dat.distribute(np.ones((64, 64), dtype=np.float32))
 C = A @ B
@@ -50,7 +46,7 @@ C = A @ B
 # -- the RDMA-armed (interpret) reshard vs its XLA twin ---------------------
 # an eligible single-axis repartition: (8,1) -> (1,8) lowers to the
 # planner's compiled all_to_all; DA_TPU_RDMA flips which ring runs and
-# the reshard span carries dispatch=rdma|xla + the bytes_ici stamp
+# the reshard span carries dispatch=rdma|xla
 src = np.arange(64 * 64, dtype=np.float32).reshape(64, 64)
 for dispatch in ("interpret", "0"):
     os.environ["DA_TPU_RDMA"] = dispatch
@@ -80,9 +76,7 @@ futs = [srv.submit("echo", np.full((8, 8), i, dtype=np.float32),
 results = [f.result(timeout=60) for f in futs]
 srv.close()
 
-# -- solver: sparse + stencil SpMV under CG (solver.spmv cost stamps) -------
-# the doctor must classify these HBM-bound (nnz-proportional HBM bytes,
-# halo ICI bytes — arithmetic intensity far under the ridge)
+# -- solver: sparse + stencil SpMV under CG (solver.spmv spans) -------------
 from distributedarrays_tpu import solvers  # noqa: E402
 
 sop = solvers.StencilOperator((32, 32))
