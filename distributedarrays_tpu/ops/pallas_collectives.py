@@ -21,10 +21,12 @@ collective communication" (arXiv:2112.01075):
   write-once (no reuse race by construction); the next local block's
   HBM→VMEM copy overlaps the partial's RDMA hop; chunk-to-chunk slot
   reuse is gated by a credit DMA from the consuming neighbor.
-- :func:`ring_all_to_all` — chunked bidirectional all-to-all: every
-  piece is DMA'd directly into its final offset of the destination
-  rank's output (write-once, zero staging), alternating ring direction
-  per destination distance so both ICI link directions carry traffic.
+- :func:`ring_all_to_all` — chunked all-to-all: every piece is DMA'd
+  directly into its final offset of the destination rank's output
+  (write-once, zero staging).  Chunk ``c`` goes out to every peer before
+  chunk ``c+1`` to any, each peer behind a send window of its own, so a
+  transfer to every peer is in flight at once and every link that leads
+  to one carries traffic; the local block's copy runs under the wire.
 - :func:`ring_allgather_matmul` / :func:`ring_allgather_matmul_rhs` /
   :func:`ring_matmul_reducescatter` — the fused collective GEMMs: the
   next chunk's RDMA is STARTED before the resident chunk's ``jnp.dot``
@@ -202,7 +204,10 @@ def _record_dispatch(op: str, path: str, x, axis: str, p: int = 0,
     ``p`` (the ring size) adds a ``bytes_ici`` provenance stamp to the
     comm record — PER-DEVICE ring volume, matching this record's
     per-rank-block byte convention (``collectives._rec``)."""
-    _tm.count("pallas_collectives.dispatch", op=op, path=path)
+    # ``inflight`` (the all-to-all's send window) is also a label of the
+    # counter, so a report says what engaged without the journal
+    window = {"inflight": labels["inflight"]} if "inflight" in labels else {}
+    _tm.count("pallas_collectives.dispatch", op=op, path=path, **window)
     if path == "rdma" and _tm.enabled():
         if p and p > 1:
             # every ring kernel forwards each resident/received piece
@@ -466,9 +471,10 @@ def _a2a_call(axis: str, p: int, shape: tuple, dtype_str: str,
         out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.DMA],
+        scratch_shapes=[
+            pltpu.SemaphoreType.DMA((sched.sem_slots()["send"],)),
+            pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.DMA],
         name="ring_all_to_all",
         interpret=interpret,
     )
@@ -490,15 +496,26 @@ def a2a_chunks_for(local_shape, dtype_str: str, p: int,
     return nc, src
 
 
+def a2a_inflight(p: int, nc: int) -> str:
+    """The send window :func:`ring_all_to_all` runs at ring size ``p``
+    and chunk depth ``nc``, as ``"<destinations in flight>x<depth>"`` —
+    the ``inflight`` label of the dispatch counter and record, and the
+    ``reshard`` span's ``rdma_inflight``."""
+    return "{}x{}".format(*_rs.a2a_window(p, nc))
+
+
 def ring_all_to_all(x, axis: str, *, split_dim: int, concat_dim: int,
                     chunks: int | None = None,
                     interpret: bool | None = None,
                     mesh_axes: tuple | None = None):
     """``lax.all_to_all(x, axis, split_dim, concat_dim, tiled=True)`` as
-    chunked bidirectional direct RDMA (bit-identical: pure data movement;
-    every piece lands at its final output offset, zero staging).
-    ``split_dim == concat_dim`` keeps the ``lax`` path.  ``mesh_axes``
-    arms per-axis sub-rings with MESH device ids — compiled TPU only."""
+    chunked direct RDMA (bit-identical: pure data movement; every piece
+    lands at its final output offset, zero staging).  A transfer to each
+    of the ``p-1`` peers is in flight at once (``inflight`` on the
+    dispatch record says how many, and how deep), and the local block is
+    copied meanwhile.  ``split_dim == concat_dim`` keeps the ``lax``
+    path.  ``mesh_axes`` arms per-axis sub-rings with MESH device ids —
+    compiled TPU only."""
     p = _axis_size(axis)
     if p == 1:
         return x
@@ -515,10 +532,13 @@ def ring_all_to_all(x, axis: str, *, split_dim: int, concat_dim: int,
         _record_dispatch("ring_all_to_all", "xla", x, axis)
         return pall_to_all(x, axis, split_dim=split_dim,
                            concat_dim=concat_dim)
-    nc, src = (chunks, "arg") if chunks else a2a_chunks_for(
-        shape, str(x.dtype), p, concat_dim)
+    # a caller's depth is clamped as the kernel clamps it (the derived one
+    # already is), so the stamp names the depth and the window that run
+    nc, src = (_chunk_fit(shape[concat_dim], chunks), "arg") if chunks \
+        else a2a_chunks_for(shape, str(x.dtype), p, concat_dim)
     _record_dispatch("ring_all_to_all", "rdma", x, axis, p=p, mode=mode,
-                     chunks=nc, chunks_source=src)
+                     chunks=nc, chunks_source=src,
+                     inflight=a2a_inflight(p, nc))
     return _a2a_call(axis, p, shape, str(x.dtype), split_dim, concat_dim,
                      nc, mode == "interpret", mesh_axes)(x)
 

@@ -46,13 +46,14 @@ import functools
 from typing import Any
 
 __all__ = [
-    "ME", "Var", "Bin", "mod", "ev",
+    "ME", "Var", "Bin", "mod", "xor", "ev",
     "Dma", "Start", "WaitSend", "WaitRecv", "WaitLocal", "Compute",
     "BufferSpec", "Schedule", "SCHEDULES", "build",
     "all_gather_schedule", "all_to_all_schedule",
     "reduce_scatter_schedule", "ag_matmul_schedule",
     "ag_matmul_rhs_schedule", "matmul_reducescatter_schedule",
-    "a2a_offsets", "mesh_subrings", "mesh_peer", "mesh_axis_size",
+    "a2a_offsets", "a2a_peer", "a2a_window", "mesh_subrings", "mesh_peer",
+    "mesh_axis_size",
 ]
 
 
@@ -79,7 +80,7 @@ class Var:
 
 @dataclasses.dataclass(frozen=True)
 class Bin:
-    """A binary expression node; ``op`` in add/sub/mul/mod."""
+    """A binary expression node; ``op`` in add/sub/mul/mod/xor."""
 
     op: str
     a: Any
@@ -100,6 +101,13 @@ def mod(e, n: int):
     return Bin("mod", e, n)
 
 
+def xor(e, k: int):
+    """``e ^ k`` (bitwise); folds when ``e`` is concrete."""
+    if isinstance(e, int):
+        return e ^ k
+    return Bin("xor", e, k)
+
+
 def ev(x, env: dict):
     """Evaluate an expression/tuple against ``env``: needs ``env["me"]``
     and ``env["mod"]`` (a nonnegative-mod callable — ``%`` for concrete
@@ -116,6 +124,8 @@ def ev(x, env: dict):
             return a * b
         if x.op == "mod":
             return env["mod"](a, b)
+        if x.op == "xor":
+            return a ^ b
         raise ValueError(f"unknown op {x.op!r}")
     if isinstance(x, tuple):
         return tuple(ev(e, env) for e in x)
@@ -286,13 +296,20 @@ def all_gather_schedule(p: int) -> Schedule:
 
 
 # ---------------------------------------------------------------------------
-# chunked bidirectional all-to-all (direct scatter, zero staging)
+# chunked all-to-all (direct scatter, zero staging, every destination
+# in flight)
 # ---------------------------------------------------------------------------
 
 
 def a2a_offsets(p: int) -> list:
-    """Destination distances, bidirectionally interleaved (+1, -1, +2,
-    -2, ...) so both ICI link directions carry traffic."""
+    """Destination distances in the order a chunk's round of starts
+    names them: interleaved by ring direction (+1, -1, +2, -2, ...).
+    What puts more than one link to work is :func:`all_to_all_schedule`'s
+    chunk-major issue, which keeps a transfer to every one of these in
+    flight at once; the order inside a round still counts on the chip
+    (on the 2x2, ``[1, 3, 2]`` with :func:`a2a_peer`'s pairing is X
+    neighbour, diagonal, Y neighbour, the fastest of the six orders
+    measured: ``PERF.md`` section 6, PR 32)."""
     offs = []
     for s in range(1, p // 2 + 1):
         offs.append(s)
@@ -301,40 +318,74 @@ def a2a_offsets(p: int) -> list:
     return offs
 
 
+def a2a_peer(off: int, p: int):
+    """The rank my piece at distance ``off`` goes to.
+
+    Where ``p`` is a power of two the ranks pair up, ``me ^ off``
+    (pairwise exchange), so a round of starts names the same kind of
+    route on every rank: on a 2x2 with row-major ids ``^1`` is every
+    chip's X neighbour, ``^2`` its Y neighbour and ``^3`` the diagonal,
+    where ``+1`` is the X neighbour of the even chips and the diagonal
+    of the odd ones.  Elsewhere it is ``me + off`` round the ring.
+    Either way the distances ``1..p-1`` reach every other rank once."""
+    return xor(ME, off) if p & (p - 1) == 0 else mod(ME + off, p)
+
+
+def a2a_window(p: int, nc: int) -> tuple:
+    """``(destinations in flight, depth per destination)`` of the
+    all-to-all's send window: every one of the ``p-1`` destinations has
+    a semaphore slot pair of its own (one slot when there is one chunk),
+    so up to ``(p-1) * depth`` remote DMAs are in flight."""
+    return p - 1, min(nc, 2)
+
+
 @functools.lru_cache(maxsize=None)
 def all_to_all_schedule(p: int, nc: int) -> Schedule:
     """Every piece is DMA'd directly into its final offset of the
-    destination rank's output (write-once); sends revolve through a
-    2-slot sem window; the single receive sem accumulates the
-    ``(p-1)*nc`` equal-sized landings and is drained at the end.
-    Remote ``out`` regions are keyed by (sender, chunk) — each is
-    written exactly once by exactly one peer."""
+    destination rank's output (write-once).  The remote DMAs are issued
+    chunk-major and destination-minor: chunk ``c`` goes out to all
+    ``p-1`` destinations before chunk ``c+1`` to any, and each
+    destination revolves through a send-semaphore window of its own
+    (:func:`a2a_window`), so from the first start to the last every
+    destination that still has pieces to send has one in flight, and a
+    slow stream holds the others back by at most the piece they have in
+    flight.  The local block's copy is started first and waited after
+    the sends have drained, so it runs under the wire.  The single
+    receive sem accumulates the ``(p-1)*nc`` equal-sized landings and is
+    drained at the end.  Remote ``out`` regions are keyed by (sender,
+    chunk) — each is written exactly once by exactly one peer."""
     offs = a2a_offsets(p)
+    _, depth = a2a_window(p, nc)
     prog: list = []
     loc = Dma(src=("x", (ME, "all")), dst=("out", (ME, "all")),
               sem=("copy", 0), token=("piece", ME, ME, "all"))
-    prog += [Start(loc), WaitLocal(loc)]
-    k = 0
-    for off in offs:
-        dst = mod(ME + off, p)
-        for c in range(nc):
-            d = Dma(src=("x", (dst, c)), dst=("out", (ME, c)),
-                    send=("send", k % 2), recv=("recv", 0), peer=dst,
-                    token=("piece", ME, dst, c))
-            if k >= 2:
-                prog.append(WaitSend(d))       # free the revolving slot
+    prog.append(Start(loc))
+
+    def piece(j, c):
+        dst = a2a_peer(offs[j], p)
+        return Dma(src=("x", (dst, c)), dst=("out", (ME, c)),
+                   send=("send", j * depth + c % depth), recv=("recv", 0),
+                   peer=dst, token=("piece", ME, dst, c))
+
+    for c in range(nc):
+        for j in range(len(offs)):
+            d = piece(j, c)
+            if c >= depth:
+                prog.append(WaitSend(d))       # free this destination's slot
             prog.append(Start(d))
-            k += 1
-    drain = Dma(src=("x", (ME, 0)), dst=("out", (ME, 0)),
-                send=("send", 0), recv=("recv", 0), peer=ME)
-    for j in range(max(k - 2, 0), k):
-        prog.append(WaitSend(dataclasses.replace(drain,
-                                                 send=("send", j % 2))))
+    for c in range(max(nc - depth, 0), nc):
+        for j in range(len(offs)):
+            prog.append(WaitSend(piece(j, c)))
+    prog.append(WaitLocal(loc))
+    landing = Dma(src=("x", (ME, 0)), dst=("out", (ME, 0)),
+                  send=("send", 0), recv=("recv", 0), peer=ME)
     for _ in range((p - 1) * nc):
-        prog.append(WaitRecv(drain))
+        prog.append(WaitRecv(landing))
     final = [(("out", (ME, "all")), ("piece", ME, ME, "all"))]
     for off in offs:
-        src_rank = mod(ME - off, p)            # who lands at distance off
+        # every other rank lands its pieces for me: as sources too the
+        # peers are all of them, each once
+        src_rank = a2a_peer(off, p)
         for c in range(nc):
             final.append(((("out", (src_rank, c))),
                           ("piece", src_rank, ME, c)))
@@ -342,7 +393,7 @@ def all_to_all_schedule(p: int, nc: int) -> Schedule:
         "ring_all_to_all", p, (("nc", nc),),
         (("x", BufferSpec("input")),
          ("out", BufferSpec("output", write_once=True))),
-        (("send", 2), ("recv", 0), ("copy", 0)),
+        (("send", (p - 1) * depth), ("recv", 0), ("copy", 0)),
         tuple(prog), tuple(final))
 
 

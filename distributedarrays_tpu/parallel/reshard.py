@@ -1317,7 +1317,7 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
         # it (flipping DA_TPU_RDMA re-jits) and the span says which path ran
         rdma = None
         rdma_chunks = 0
-        chunks_src = ""
+        chunks_src = rdma_inflight = ""
         if any(s[0] in ("a2a", "gather") for s in plan.steps):
             # a2a and gather steps ride the ring kernels when the platform
             # arms them (mesh-coordinate addressing on multi-axis meshes);
@@ -1336,10 +1336,11 @@ def reshard(x, dst_sharding, *, op: str = "reshard",
                 # keeps the span's label equal to the depth it runs
                 rdma_chunks, chunks_src = _pc.a2a_chunks_for(
                     lshape, dtype_str, plan.nparts, plan.src_dim)
+                rdma_inflight = _pc.a2a_inflight(plan.nparts, rdma_chunks)
     with _tm.span("reshard", op=op, strategy=plan.strategy,
                   dispatch="rdma" if rdma else "xla",
                   rdma_chunks=rdma_chunks, rdma_chunks_source=chunks_src,
-                  shape=list(plan.shape),
+                  rdma_inflight=rdma_inflight, shape=list(plan.shape),
                   dtype=str(getattr(x, "dtype", "float32")),
                   src_dim=plan.src_dim, dst_dim=plan.dst_dim,
                   nparts=plan.nparts, nsteps=len(plan.steps),

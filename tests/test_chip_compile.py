@@ -207,6 +207,44 @@ def test_ring_all_to_all_4chips(ring_mesh, on_tpu):
     assert "tpu_custom_call" in txt
 
 
+@pytest.mark.parametrize("leg", ["leg1-p4-single-axis", "leg3-p2-mesh-axis"])
+def test_ring_all_to_all_at_the_reshard_cell_shapes(topo, ring_mesh, on_tpu,
+                                                    leg):
+    # the two owned kernels of the benchmark's 2x2 cycle at X 32768x49152
+    # f32, with the send window they run there: leg 1 is (4,1)->(1,4) on
+    # the 1-D mesh, three destinations in flight behind a slot pair each
+    # (4 chunks of 96 MiB a destination); leg 3's a2a step is the p=2
+    # sub-ring along d1 of the (2,2) mesh, MESH device ids, 12 chunks
+    from distributedarrays_tpu import telemetry as tm
+    if leg.startswith("leg1"):
+        mesh, p, local = ring_mesh, 4, (8192, 49152)
+        f = lambda x: PC.ring_all_to_all(  # noqa: E731
+            x, "d0", split_dim=1, concat_dim=0, interpret=False)
+        spec, ospec, shape = P("d0", None), P(None, "d0"), (32768, 49152)
+        window = "3x2"
+    else:
+        mesh = Mesh(np.asarray(topo.devices, dtype=object).reshape(2, 2),
+                    ("d0", "d1"))
+        p, local = 2, (16384, 24576)
+        f = lambda x: PC.ring_all_to_all(  # noqa: E731
+            x, "d1", split_dim=0, concat_dim=1, interpret=False,
+            mesh_axes=("d0", "d1"))
+        spec, ospec, shape = P("d0", "d1"), P(("d0", "d1"), None), \
+            (32768, 49152)
+        window = "1x2"
+    nc, _ = PC.a2a_chunks_for(local, "float32", p,
+                              0 if leg.startswith("leg1") else 1)
+    assert nc > 2 and PC.a2a_inflight(p, nc) == window
+    before = tm.counter_value("pallas_collectives.dispatch",
+                              op="ring_all_to_all", path="rdma",
+                              inflight=window)
+    txt = _ring_text(mesh, f, (spec,), ospec, (shape, jnp.float32))
+    assert "tpu_custom_call" in txt and "ring_all_to_all" in txt
+    assert tm.counter_value("pallas_collectives.dispatch",
+                            op="ring_all_to_all", path="rdma",
+                            inflight=window) == before + 1
+
+
 def test_ring_reduce_scatter_4chips(ring_mesh, on_tpu):
     # the derived chunk depth is 1 at this size and the p-1 receive slots
     # then exceed scoped VMEM (the kernel would give way, and say so);

@@ -171,7 +171,7 @@ def test_reshard_strategy_ran(telemetry_capture, monkeypatch, rng, case):
     # what ran, and nothing else, is what the span says
     assert set(labels) == {
         "op", "strategy", "dispatch", "rdma_chunks", "rdma_chunks_source",
-        "shape", "dtype", "src_dim", "dst_dim", "nparts", "nsteps",
+        "rdma_inflight", "shape", "dtype", "src_dim", "dst_dim", "nparts", "nsteps",
         "intra_bytes", "cross_bytes"}
 
 

@@ -56,6 +56,92 @@ def test_schedules_are_pure_data():
 
 
 # ---------------------------------------------------------------------------
+# the all-to-all's send schedule: every destination's link kept busy
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("nc", [1, 2, 4])
+@pytest.mark.parametrize("p", [2, 3, 4, 5, 8])
+def test_all_to_all_keeps_every_destination_in_flight(p, nc):
+    # read off the program as a rank issues it, a DMA counted in flight
+    # from its Start to the WaitSend on its slot (the latest it can be)
+    sched = rs.all_to_all_schedule(p, nc)
+    dests, depth = rs.a2a_window(p, nc)
+    assert (dests, depth) == (p - 1, min(nc, 2))
+    assert sched.sem_slots()["send"] == dests * depth
+    for me in range(p):
+        env = {"me": me, "mod": lambda a, n: a % n}
+        others = set(range(p)) - {me}
+        busy = {}                   # send slot -> destination in flight
+        owner = {}                  # send slot -> its one destination
+        issued = []                 # (destination, chunk), issue order
+        waited_send = waited_local = False
+        for ins in sched.program:
+            d = ins.dma
+            if d.peer is None:
+                if isinstance(ins, rs.Start):
+                    assert not issued       # the local copy starts first
+                else:
+                    # ... and is waited under the wire: after the first
+                    # remote start, and in fact after the sends' drain
+                    assert issued and not busy
+                    waited_local = True
+                continue
+            if isinstance(ins, rs.Start):
+                dst = rs.ev(d.peer, env)
+                assert d.send not in busy, "slot restarted before its wait"
+                assert owner.setdefault(d.send, dst) == dst
+                busy[d.send] = dst
+                issued.append((dst, rs.ev(d.src[1], env)[1]))
+            elif isinstance(ins, rs.WaitSend):
+                if not waited_send:
+                    # no wait before every destination has been started
+                    assert {dst for dst, _ in issued} == others
+                    waited_send = True
+                del busy[d.send]            # KeyError: waited an idle slot
+            left = {dst for dst in others
+                    if sum(1 for x, _ in issued if x == dst) < nc}
+            if len(issued) >= dests and len(issued) < dests * nc:
+                # between the first round and the last start, whoever has
+                # pieces left to send has one in flight
+                assert left <= set(busy.values())
+        assert waited_local and not busy
+        # chunk-major, destination-minor: chunk c to everyone before c+1
+        assert [c for _, c in issued] == sorted(c for _, c in issued)
+        assert len(set(issued)) == len(issued) == dests * nc
+        # every piece still lands once, at the offset it always had
+        final = {(rs.ev(k, env), rs.ev(t, env))
+                 for (_b, k), t in sched.final}
+        want = {((me, "all"), ("piece", me, me, "all"))} | {
+            ((s, c), ("piece", s, me, c)) for s in others
+            for c in range(nc)}
+        assert final == want
+
+
+@pytest.mark.parametrize("p", [2, 3, 4, 5, 8])
+def test_all_to_all_round_names_the_same_route_on_every_rank(p):
+    # where p is a power of two the ranks pair up (me ^ off): whom I send
+    # to in a round's j-th place sends to me in its own j-th place;
+    # elsewhere the j-th place is me + off round the ring
+    offs = rs.a2a_offsets(p)
+    assert sorted(offs) == list(range(1, p))
+    sched = rs.all_to_all_schedule(p, 1)
+
+    def round_of(me):
+        env = {"me": me, "mod": lambda a, n: a % n}
+        return [rs.ev(i.dma.peer, env) for i in sched.program
+                if isinstance(i, rs.Start) and i.dma.peer is not None]
+
+    for me in range(p):
+        dests = round_of(me)
+        if p & (p - 1) == 0:
+            assert dests == [me ^ off for off in offs]
+            assert all(round_of(d)[j] == me for j, d in enumerate(dests))
+        else:
+            assert dests == [(me + off) % p for off in offs]
+
+
+# ---------------------------------------------------------------------------
 # the mutation harness: every mutant refuted, with a counterexample
 # ---------------------------------------------------------------------------
 
