@@ -32,7 +32,7 @@ from ..ops.pallas_attention import flash_attention
 from .mlp import make_mesh
 
 __all__ = ["init_params", "forward", "loss_fn", "train_step",
-           "make_optax_train_step", "generate",
+           "make_optax_train_step", "optax_f32_step", "generate",
            "shard_params", "make_mesh", "Config"]
 
 
@@ -302,6 +302,11 @@ def _optax_f32_step(tx, grad_fn):
         return _optax_f32_init(tx, params)
 
     return step, init
+
+
+# the float32-master step under its public name: what every model of the
+# package that trains through optax goes through (models/sambay.py)
+optax_f32_step = _optax_f32_step
 
 
 def _as_f32(t):

@@ -1,7 +1,7 @@
 from . import broadcast, conv, fft, linalg, mapreduce, sort, sparse  # noqa: F401
 
 _LAZY = ("pallas_attention", "pallas_gemm", "pallas_collectives",
-         "pallas_stencil", "collective_matmul")
+         "pallas_stencil", "pallas_selective_scan", "collective_matmul")
 
 
 def __getattr__(name):

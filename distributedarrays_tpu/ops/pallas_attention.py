@@ -170,6 +170,54 @@ def _q_bounds(col0, tk, row0, tq, n):
     return t_live, t_full
 
 
+def _band(delta, tq: int, tk: int, window: int):
+    """A (tq, tk) tile of a WINDOWED causal sweep whose first column minus
+    first row is the int ``delta``: a score is live iff
+    ``delta <= (row - column inside the tile) < delta + window``.  Returns
+    (any score live, the diagonal crosses the tile, the window's edge
+    crosses it): the two edges a tile may have to mask."""
+    live = tq - 1 >= delta and 1 - tk < delta + window
+    return live, 1 - tk < delta, tq - 1 >= delta + window
+
+
+def _band_loop(n: int, delta_of, tq: int, tk: int, window: int, body,
+               span: int = 1):
+    """The windowed sweep over tiles 0..n-1, all at trace time (a windowed
+    step's offsets are always ints): tiles outside the band are not
+    visited, a tile an edge crosses goes alone as ``body(t, 1, (diagonal,
+    edge))``, adjacent whole ones ``span`` at a time as ``body(t, w,
+    False)``."""
+    t = 0
+    while t < n:
+        live, diag, edge = _band(delta_of(t), tq, tk, window)
+        if not live:
+            t += 1
+        elif diag or edge:
+            body(t, 1, (diag, edge))
+            t += 1
+        else:
+            w = 1
+            while (w < span and t + w < n and _band(
+                    delta_of(t + w), tq, tk, window) == (True, False, False)):
+                w += 1
+            body(t, w, False)
+            t += w
+
+
+def _live(rel, delta, masked, window):
+    """The live scores of a tile: ``masked`` True is the causal diagonal
+    alone (one compare); a pair (diagonal, edge) names which of the two
+    edges of a windowed band cross the tile."""
+    if masked is True:
+        return rel >= delta
+    diag, edge = masked
+    live = (rel >= delta) if diag else None
+    if edge:
+        inside = rel < delta + window
+        live = inside if live is None else live & inside
+    return live
+
+
 def _loop(lo, hi, body, span: int = 1):
     """Run ``body(t, w)`` over the tiles [lo, hi), ``w`` adjacent tiles at
     a time.  Static bounds of few tiles are unrolled at trace time, and
@@ -187,6 +235,25 @@ def _loop(lo, hi, body, span: int = 1):
                 t += w
             return
     jax.lax.fori_loop(lo, hi, lambda t, c: (body(t, 1), c)[1], 0)
+
+
+def _each_window_kind(shift, b: int, n_blocks: int, window: int, sweep):
+    """``_each_kind`` for a windowed causal program (static offsets, square
+    blocks of ``b``): a step's k block lies ``m = -shift / b`` blocks
+    behind its q block.  The steps whose every score is live share one
+    body, ``sweep(None)``; each distance at which the diagonal or the
+    window's edge crosses the block gets a body of its own with the int
+    shift, so that its sweep is laid out at trace time; every other step
+    is dead."""
+    if isinstance(shift, (int, np.integer)):
+        return sweep(int(shift))
+    far = min(n_blocks - 1, -(-(window - 1) // b))      # last live distance
+    if 2 * b - 1 < window:
+        pl.when((shift + b - 1 <= 0) & (b - 1 - shift < window))(
+            lambda: sweep(None))
+    for m in range(far + 1):
+        if m == 0 or (m + 1) * b - 1 >= window:         # an edge crosses
+            pl.when(shift == -m * b)(lambda m=m: sweep(-m * b))
 
 
 def _each_kind(shift, bq: int, bk: int, causal: bool, static: bool, sweep):
@@ -250,7 +317,7 @@ def _store_row(ref, idx, x):
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
             causal: bool, bq: int, bk: int, tq: int, tk: int, nq: int,
-            nk: int, hfold: int):
+            nk: int, hfold: int, window: int | None = None):
     """Forward.  ``q`` arrives multiplied by the softmax scale (the
     wrapper does it once on (S, D), not here on every (tq, tk) tile).  A
     grid step holds a (bq, d) block of q and a (bk, d) block of k and v;
@@ -260,7 +327,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
     lane-replicated (rows, 128), as in jax's reference kernel."""
     qi = pl.program_id(1) if nq > 1 else 0
     ki = pl.program_id(2) if nk > 1 else 0
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]          # the value head's width: acc's and o's
     n = bk // tk
 
     @_when(ki == 0)
@@ -284,7 +351,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
             s = jax.lax.dot_general(q, k, _NT,
                                     preferred_element_type=jnp.float32)
             if masked:
-                s = jnp.where(rel >= shift + t * tk - qs * tq, s, _MASK)
+                s = jnp.where(_live(rel, shift + t * tk - qs * tq, masked,
+                                    window), s, _MASK)
             m_prev = m_s[hh, rows, :]                       # (tq, 128)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -301,9 +369,17 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         # every row's first live tile holds its column 0 or its own
         # diagonal, so m is finite from the first tile on and a masked
         # score's exp is an exact 0 with no guard
-        n_full, n_live = _k_bounds(qs * tq, tq, shift, tk, n)
-        _loop(0, n_full, lambda t, w: tile(t, w, False), _SPAN)
-        _loop(n_full, n_live, lambda t, w: tile(t, w, True))
+        if window is None or shift is None:
+            n_full, n_live = _k_bounds(qs * tq, tq, shift, tk, n)
+            _loop(0, n_full, lambda t, w: tile(t, w, False), _SPAN)
+            _loop(n_full, n_live, lambda t, w: tile(t, w, True))
+        else:
+            # a row whose scores in the window's edge tile are all masked
+            # leaves m at _MASK there and sums weights of 1; the first
+            # live score that follows (its own diagonal at the latest)
+            # rescales that sum by exp(_MASK - m) = 0 exactly
+            _band_loop(n, lambda t: shift + t * tk - qs * tq, tq, tk,
+                       window, tile, _SPAN)
 
     def sweep(shift):
         for hh in range(hfold):
@@ -313,7 +389,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
     # a k block wholly above the q block's rows is dead: no kind claims
     # its step, and the index map repeats the last live block so that no
     # DMA is issued for it either
-    _each_kind(ki * bk - qi * bq, bq, bk, causal, True, sweep)
+    if window is None:
+        _each_kind(ki * bk - qi * bq, bq, bk, causal, True, sweep)
+    else:
+        _each_window_kind(ki * bk - qi * bq, bq, nq, window, sweep)
 
     @_when(ki == nk - 1)
     def _flush():
@@ -338,16 +417,24 @@ def _tiles(bq: int, bk: int):
 
 
 def _count_steps(s: int, bq: int, bk: int, tq: int, tk: int, causal: bool,
-                 sweep: str):
+                 sweep: str, window: int | None = None):
     """What a static-offset program does for one head: tiles computed
     without a mask, tiles computed with one, and grid steps visited but
     skipped.  ``sweep`` is "k" (forward, dQ: q strips sweep k tiles) or
-    "q" (dK/dV and the fused backward: k strips sweep q tiles)."""
+    "q" (dK/dV and the fused backward: k strips sweep q tiles).  With a
+    ``window`` both sweeps visit the same tiles (``_band``)."""
     out = {"unmasked": 0, "masked": 0, "dead": 0}
     for qi in range(s // bq):
         for ki in range(s // bk):
             r0, c0 = qi * bq, ki * bk
-            if not causal:
+            if window is not None:
+                kinds = [_band(c0 + t * tk - r0 - qs * tq, tq, tk, window)
+                         for qs in range(bq // tq) for t in range(bk // tk)]
+                live = [k for k in kinds if k[0]]
+                out["dead"] += not live
+                out["masked"] += sum(k[1] or k[2] for k in live)
+                out["unmasked"] += sum(not (k[1] or k[2]) for k in live)
+            elif not causal:
                 out["unmasked"] += (bq // tq) * (bk // tk)
             elif c0 > r0 + bq - 1:
                 out["dead"] += 1
@@ -365,58 +452,81 @@ def _count_steps(s: int, bq: int, bk: int, tq: int, tk: int, causal: bool,
 
 
 def _record_plan(kernel: str, s: int, d: int, causal: bool, sweep: str,
-                 bq: int, bk: int, tq: int, tk: int, fold: int):
+                 bq: int, bk: int, tq: int, tk: int, fold: int,
+                 window: int | None = None):
     """The mechanism's gauge (docs/telemetry.md): when a static-offset
     program is built (trace time, never a step), what was chosen for
     (kernel, S, D, causal) and what its grid then does a head, one value
     for each ``what``: bq, bk, tq, tk, fold, and the ``_count_steps``
-    kinds."""
+    kinds.  A windowed program's gauge carries its ``window`` as one more
+    label."""
     plan = dict(bq=bq, bk=bk, tq=tq, tk=tk, fold=fold,
-                **_count_steps(s, bq, bk, tq, tk, causal, sweep))
+                **_count_steps(s, bq, bk, tq, tk, causal, sweep, window))
+    more = {} if window is None else {"window": window}
     for what, n in plan.items():
         _tm.set_gauge("pallas.flash_attention.plan", n, kernel=kernel, s=s,
-                      d=d, causal=causal, what=what)
+                      d=d, causal=causal, what=what, **more)
+
+
+def _group_map(group: int):
+    """The head index of a k or v array that serves ``group`` query heads
+    each (no copy of it per query head exists in HBM): head ``hh`` of q
+    reads head ``hh // group``; one to one where ``group`` is 1."""
+    return (lambda hh: hh) if group == 1 else (lambda hh: hh // group)
 
 
 @functools.lru_cache(maxsize=64)
-def _build(h, s, d, bq, bk, dtype_str, causal, interpret, hfold: int = 1):
+def _build(h, s, d, bq, bk, dtype_str, causal, interpret, hfold: int = 1,
+           window: int | None = None, gk: int = 1, gv: int = 1,
+           dv: int | None = None):
     """The forward program: ``call(q * scale, k, v) -> (out, lse)`` on
-    (H, S, D) arrays; ``lse`` is (H, 1, S) float32, rows."""
+    (H, S, D) arrays; ``lse`` is (H, 1, S) float32, rows.  ``gk`` and
+    ``gv`` query heads share a head of k and of v ((H/gk, S, D) and
+    (H/gv, S, dv) arrays); ``dv`` is the value head's width where it is
+    not ``d``; ``window`` keeps the scores of the last ``window`` positions
+    only."""
+    dv = d if dv is None else dv
     nq, nk = s // bq, s // bk
     tq, tk = _tiles(bq, bk)
     kern = functools.partial(_kernel, causal=causal, bq=bq, bk=bk, tq=tq,
-                             tk=tk, nq=nq, nk=nk, hfold=hfold)
-    _record_plan("flash_fwd", s, d, causal, "k", bq, bk, tq, tk, hfold)
+                             tk=tk, nq=nq, nk=nk, hfold=hfold, window=window)
+    _record_plan("flash_fwd", s, d, causal, "k", bq, bk, tq, tk, hfold,
+                 window)
+    kh, vh = _group_map(gk), _group_map(gv)
 
     def qmap(hh, qi, ki):
         return (hh, qi, 0)
 
-    def kmap(hh, qi, ki):
+    def k_of(qi, ki):
         if causal:
             # dead steps re-name the last live k block: no new DMA
             ki = jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
-        return (hh, ki, 0)
+        if window is not None:
+            ki = jnp.maximum(ki, jnp.maximum(qi * bq - window + 1, 0) // bk)
+        return ki
 
     call = pl.pallas_call(
         kern,
         grid=(h // hfold, nq, nk),
         in_specs=[
             pl.BlockSpec((hfold, bq, d), qmap),
-            pl.BlockSpec((hfold, bk, d), kmap),
-            pl.BlockSpec((hfold, bk, d), kmap),
+            pl.BlockSpec((hfold, bk, d),
+                         lambda hh, qi, ki: (kh(hh), k_of(qi, ki), 0)),
+            pl.BlockSpec((hfold, bk, dv),
+                         lambda hh, qi, ki: (vh(hh), k_of(qi, ki), 0)),
         ],
         out_specs=(
-            pl.BlockSpec((hfold, bq, d), qmap),
+            pl.BlockSpec((hfold, bq, dv), qmap),
             pl.BlockSpec((hfold, 1, bq), lambda hh, qi, ki: (hh, 0, qi)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((h, s, d), jnp.dtype(dtype_str)),
+            jax.ShapeDtypeStruct((h, s, dv), jnp.dtype(dtype_str)),
             jax.ShapeDtypeStruct((h, 1, s), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.VMEM((hfold, bq, _LANE), jnp.float32),
             pltpu.VMEM((hfold, bq, _LANE), jnp.float32),
-            pltpu.VMEM((hfold, bq, d), jnp.float32),
+            pltpu.VMEM((hfold, bq, dv), jnp.float32),
         ],
         name="flash_fwd",
         interpret=interpret,
@@ -467,7 +577,7 @@ def _offsets(refs, traced: bool):
 
 
 def _bwd_dq_kernel(*refs, scale, causal, bq, bk, tq, tk, nq, nk, traced,
-                   hfold):
+                   hfold, window=None):
     off, refs = _offsets(refs, traced)
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc_s = refs
     qi = pl.program_id(1) if nq > 1 else 0
@@ -491,23 +601,32 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, tq, tk, nq, nk, traced,
             cols = pl.ds(_mult(t * tk, tk), w * tk)
             k = k_ref[hh, cols, :]
             v = v_ref[hh, cols, :]
-            live = (rel >= shift + t * tk - qs * tq) if masked else None
+            live = _live(rel, shift + t * tk - qs * tq, masked,
+                         window) if masked else None
             _, ds = _recompute(q, k, do, v, _lanes(lse, w * tk),
                                _lanes(dd, w * tk), live)
             acc_s[hh, rows, :] += jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        n_full, n_live = _k_bounds(qs * tq, tq, shift, tk, n)
-        _loop(0, n_full, lambda t, w: tile(t, w, False), _SPAN)
-        _loop(n_full, n_live, lambda t, w: tile(t, w, True))
+        if window is None or shift is None:
+            n_full, n_live = _k_bounds(qs * tq, tq, shift, tk, n)
+            _loop(0, n_full, lambda t, w: tile(t, w, False), _SPAN)
+            _loop(n_full, n_live, lambda t, w: tile(t, w, True))
+        else:
+            _band_loop(n, lambda t: shift + t * tk - qs * tq, tq, tk,
+                       window, tile, _SPAN)
 
     def sweep(shift):
         for hh in range(hfold):
             for qs in range(bq // tq):
                 strip(hh, qs, shift)
 
-    _each_kind(ki * bk + off - qi * bq, bq, bk, causal, not traced, sweep)
+    if window is None:
+        _each_kind(ki * bk + off - qi * bq, bq, bk, causal, not traced,
+                   sweep)
+    else:
+        _each_window_kind(ki * bk - qi * bq, bq, nq, window, sweep)
 
     @_when(ki == nk - 1)
     def _flush():
@@ -515,7 +634,7 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, tq, tk, nq, nk, traced,
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, nq, nk, traced,
-                    hfold, with_dq):
+                    hfold, with_dq, window=None):
     off, refs = _offsets(refs, traced)
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref = refs[:6]
     if with_dq:
@@ -556,7 +675,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, nq, nk, traced,
             qrows = pl.ds(_mult(t * tq, tq), w * tq)
             q = q_ref[hh, qrows, :]                     # (w * tq, d), scaled
             do = do_ref[hh, qrows, :]
-            live = (rel >= shift + ks * tk - t * tq) if masked else None
+            live = _live(rel, shift + ks * tk - t * tq, masked,
+                         window) if masked else None
             p, ds = _recompute(k, q, v, do, rows_of(lse_ref, t, w),
                                rows_of(dd_ref, t, w), live)  # (tk, w * tq)
             dv_s[hh, krows, :] += jax.lax.dot_general(
@@ -571,17 +691,25 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, nq, nk, traced,
                 dq_s[hh, grows, :] += jax.lax.dot_general(
                     ds, k, _TN, preferred_element_type=jnp.float32)
 
-        t_live, t_full = _q_bounds(
-            None if shift is None else shift + ks * tk, tk, 0, tq, n)
-        _loop(t_live, t_full, lambda t, w: tile(t, w, True))
-        _loop(t_full, n, lambda t, w: tile(t, w, False), _SPAN)
+        if window is None or shift is None:
+            t_live, t_full = _q_bounds(
+                None if shift is None else shift + ks * tk, tk, 0, tq, n)
+            _loop(t_live, t_full, lambda t, w: tile(t, w, True))
+            _loop(t_full, n, lambda t, w: tile(t, w, False), _SPAN)
+        else:
+            _band_loop(n, lambda t: shift + ks * tk - t * tq, tq, tk,
+                       window, tile, _SPAN)
 
     def sweep(shift):
         for hh in range(hfold):
             for ks in range(bk // tk):
                 strip(hh, ks, shift)
 
-    _each_kind(ki * bk + off - qi * bq, bq, bk, causal, not traced, sweep)
+    if window is None:
+        _each_kind(ki * bk + off - qi * bq, bq, bk, causal, not traced,
+                   sweep)
+    else:
+        _each_window_kind(ki * bk - qi * bq, bq, nq, window, sweep)
 
     @_when(qi == nq - 1)
     def _flush():
@@ -605,7 +733,9 @@ def _fused_backward(s: int, d: int, out_dtype, hfold: int,
 
 @functools.lru_cache(maxsize=64)
 def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
-               out_dtype_str=None, traced: bool = False, hfold: int = 1):
+               out_dtype_str=None, traced: bool = False, hfold: int = 1,
+               window: int | None = None, gk: int = 1, gv: int = 1,
+               dv: int | None = None, kv_dtype_str=None):
     """The backward programs, ``(dq_call, dkv_call)``.
 
     Operands of both: ``[qoff, koff,] q * scale, k, v, dO, lse, D`` on
@@ -614,20 +744,28 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
     (H, S/tq, 1, tq) (``_stat_rows``), ``dq_call`` as lane-replicated
     columns (H, S, 128).  Where ``_fused_backward`` holds ``dq_call`` is
     None and ``dkv_call`` returns (dq, dk, dv); else it returns (dk, dv).
+    ``window``, ``gk``, ``gv`` and ``dv`` as in ``_build``: k is (H/gk, S,
+    D), v (H/gv, S, dv) and dO (H, S, dv), while dK and dV come back one a
+    QUERY head, (H, S, D) and (H, S, dv), in ``kv_dtype_str`` (float32
+    where the caller adds them up over each group; dQ keeps the output
+    type, which is what decides whether it fits VMEM).
     """
     out_dtype = jnp.dtype(out_dtype_str or dtype_str)
+    kv_dtype = jnp.dtype(kv_dtype_str or out_dtype)
+    dv = d if dv is None else dv
     nq, nk = s // bq, s // bk
     tq, tk = _tiles(bq, bk)
     fused = _fused_backward(s, d, out_dtype, hfold, traced)
     common = dict(scale=scale, causal=causal, bq=bq, bk=bk, tq=tq, tk=tk,
-                  nq=nq, nk=nk, traced=traced, hfold=hfold)
+                  nq=nq, nk=nk, traced=traced, hfold=hfold, window=window)
     clamp = causal and not traced
     offs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2 if traced else []
     if not traced:
-        plan = (bq, bk, tq, tk, hfold)
+        plan = (bq, bk, tq, tk, hfold, window)
         _record_plan("flash_bwd_dkv", s, d, causal, "q", *plan)
         if not fused:
             _record_plan("flash_bwd_dq", s, d, causal, "k", *plan)
+    kh, vh = _group_map(gk), _group_map(gv)
 
     # --- dK/dV (and dQ when fused): k blocks outer, q blocks swept -------
     def q_of(ki, qi):
@@ -635,16 +773,29 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
             # q blocks wholly above the k block are dead steps: re-name
             # the first live one, so no DMA is issued for them
             qi = jnp.maximum(qi, (ki * bk) // bq)
+        if window is not None:
+            # and so do those wholly past the window of its last column
+            qi = jnp.minimum(qi, ((ki + 1) * bk + window - 2) // bq)
         return qi
 
-    qspec = pl.BlockSpec((hfold, bq, d),
-                         lambda hh, ki, qi: (hh, q_of(ki, qi), 0))
-    kspec = pl.BlockSpec((hfold, bk, d), lambda hh, ki, qi: (hh, ki, 0))
+    def qspec(w):
+        return pl.BlockSpec((hfold, bq, w),
+                            lambda hh, ki, qi: (hh, q_of(ki, qi), 0))
+
+    def kspec(w, head):
+        return pl.BlockSpec((hfold, bk, w),
+                            lambda hh, ki, qi: (head(hh), ki, 0))
+
     rowspec = pl.BlockSpec((hfold, bq // tq, 1, tq),
                            lambda hh, ki, qi: (hh, q_of(ki, qi), 0, 0))
-    out_specs = [kspec, kspec]
-    out_shape = [jax.ShapeDtypeStruct((h, s, d), out_dtype)] * (2 + fused)
-    scratch = [pltpu.VMEM((hfold, bk, d), jnp.float32)] * 2
+    own = lambda hh: hh
+    out_specs = [kspec(d, own), kspec(dv, own)]
+    out_shape = [jax.ShapeDtypeStruct((h, s, d), kv_dtype),
+                 jax.ShapeDtypeStruct((h, s, dv), kv_dtype)]
+    if fused:
+        out_shape.insert(0, jax.ShapeDtypeStruct((h, s, d), out_dtype))
+    scratch = [pltpu.VMEM((hfold, bk, d), jnp.float32),
+               pltpu.VMEM((hfold, bk, dv), jnp.float32)]
     if fused:
         out_specs.insert(0, pl.BlockSpec((hfold, s, d),
                                          lambda hh, ki, qi: (hh, 0, 0)))
@@ -652,7 +803,8 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
     dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, with_dq=fused, **common),
         grid=(h // hfold, nk, nq),
-        in_specs=offs + [qspec, kspec, kspec, qspec, rowspec, rowspec],
+        in_specs=offs + [qspec(d), kspec(d, kh), kspec(dv, vh), qspec(dv),
+                         rowspec, rowspec],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
@@ -663,20 +815,28 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
         return None, jax.jit(dkv_call)
 
     # --- dQ: q blocks outer, k blocks swept -------------------------------
-    def kmap(hh, qi, ki):
+    def k_of(qi, ki):
         if clamp:
             ki = jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
-        return (hh, ki, 0)
+        if window is not None:
+            ki = jnp.maximum(ki, jnp.maximum(qi * bq - window + 1, 0) // bk)
+        return ki
 
-    qspec = pl.BlockSpec((hfold, bq, d), lambda hh, qi, ki: (hh, qi, 0))
-    kspec = pl.BlockSpec((hfold, bk, d), kmap)
+    def qspec(w):
+        return pl.BlockSpec((hfold, bq, w), lambda hh, qi, ki: (hh, qi, 0))
+
+    def kspec(w, head):
+        return pl.BlockSpec((hfold, bk, w),
+                            lambda hh, qi, ki: (head(hh), k_of(qi, ki), 0))
+
     colspec = pl.BlockSpec((hfold, bq, _LANE),
                            lambda hh, qi, ki: (hh, qi, 0))
     dq_call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(h // hfold, nq, nk),
-        in_specs=offs + [qspec, kspec, kspec, qspec, colspec, colspec],
-        out_specs=qspec,
+        in_specs=offs + [qspec(d), kspec(d, kh), kspec(dv, vh), qspec(dv),
+                         colspec, colspec],
+        out_specs=qspec(d),
         out_shape=jax.ShapeDtypeStruct((h, s, d), out_dtype),
         scratch_shapes=[pltpu.VMEM((hfold, bq, d), jnp.float32)],
         name="flash_bwd_dq",
@@ -919,26 +1079,48 @@ def _heads_first(x):
     return jnp.transpose(x, (1, 0, 2))
 
 
-def _forward(q, k, v, causal, scale, bq, bk, interpret, hfold):
+def _variant(q, k, v, window):
+    """What a call may have beyond equal heads of one width, as the
+    keywords of ``_build`` / ``_build_bwd``."""
+    H = q.shape[1]
+    return dict(window=window, gk=H // k.shape[1], gv=H // v.shape[1],
+                dv=v.shape[2])
+
+
+def _forward(q, k, v, causal, scale, bq, bk, interpret, hfold, window=None):
     S, H, D = q.shape
     out, lse = _build(H, S, D, bq, bk, str(q.dtype), causal, interpret,
-                      hfold)(_heads_first(_scaled(q, scale)),
-                             _heads_first(k), _heads_first(v))
+                      hfold, **_variant(q, k, v, window))(
+                          _heads_first(_scaled(q, scale)),
+                          _heads_first(k), _heads_first(v))
     return _heads_first(out), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_core(q, k, v, causal, scale, bq, bk, interpret, hfold=1):
-    return _forward(q, k, v, causal, scale, bq, bk, interpret, hfold)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_core(q, k, v, causal, scale, bq, bk, interpret, hfold=1,
+                window=None):
+    return _forward(q, k, v, causal, scale, bq, bk, interpret, hfold,
+                    window)[0]
 
 
-def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, hfold=1):
-    o, lse = _forward(q, k, v, causal, scale, bq, bk, interpret, hfold)
+def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, hfold=1,
+               window=None):
+    o, lse = _forward(q, k, v, causal, scale, bq, bk, interpret, hfold,
+                      window)
     # the residual lse is one float a row, (H, S): the kernel wrote rows
     return o, (q, k, v, o, lse[:, 0, :])
 
 
-def _flash_bwd(causal, scale, bq, bk, interpret, hfold, res, g):
+def _group_sum(t, heads: int, dtype):
+    """(H, S, W) gradients, one a query head, added up over the query
+    heads that share each of the ``heads`` heads, as (S, heads, W)."""
+    H, S, W = t.shape
+    if H != heads:
+        t = jnp.sum(t.reshape(heads, H // heads, S, W), axis=1)
+    return _heads_first(t).astype(dtype)
+
+
+def _flash_bwd(causal, scale, bq, bk, interpret, hfold, window, res, g):
     # FlashAttention-2-style backward: recompute P blockwise from the saved
     # per-row logsumexp — O(S·d) memory, no S×S materialization.  One
     # sweep where a head's dQ fits VMEM, two passes otherwise
@@ -950,16 +1132,21 @@ def _flash_bwd(causal, scale, bq, bk, interpret, hfold, res, g):
     # D_i = rowsum(dO ∘ O), per (head, row)
     dd = jnp.einsum("shd,shd->hs", g.astype(jnp.float32),
                     o.astype(jnp.float32))
+    more = _variant(q, k, v, window)
+    if more["gk"] > 1 or more["gv"] > 1:
+        # a group's dK, dV are summed from its query heads' in float32
+        more["kv_dtype_str"] = "float32"
     dq_call, dkv_call = _build_bwd(H, S, D, bq, bk, str(q.dtype), scale,
-                                   causal, interpret, hfold=hfold)
+                                   causal, interpret, hfold=hfold, **more)
     rows = (_stat_rows(lse, bq), _stat_rows(dd, bq))
     if dq_call is None:
         dq, dk, dv = dkv_call(qh, kh, vh, doh, *rows)
     else:
         dq = dq_call(qh, kh, vh, doh, _stat_cols(lse), _stat_cols(dd))
         dk, dv = dkv_call(qh, kh, vh, doh, *rows)
-    back = lambda t: _heads_first(t).astype(q.dtype)
-    return back(dq), back(dk), back(dv)
+    return (_heads_first(dq).astype(q.dtype),
+            _group_sum(dk, k.shape[1], k.dtype),
+            _group_sum(dv, v.shape[1], v.dtype))
 
 
 _flash_core.defvjp(_flash_fwd, _flash_bwd)
@@ -1019,9 +1206,18 @@ def tuned_flash_config(S, H, D, dtype, causal: bool,
 def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
                     block_q: int | None = None, block_k: int | None = None,
                     head_fold: int | None = None,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None,
+                    window: int | None = None):
     """Exact attention over (seq, heads, head_dim) arrays without
     materializing the S×S score matrix.
+
+    ``k`` and ``v`` may have fewer heads than ``q`` (grouped heads: each
+    serves ``H / heads`` consecutive query heads, read in place through the
+    kernels' index maps, never repeated in HBM), each its own number, and
+    ``v`` a head width of its own, which is then the result's.  ``window``
+    (with ``causal``) keeps for every row the scores of its last
+    ``window`` positions, itself included; one that covers the sequence
+    is the plain causal call.
 
     ``block_q`` / ``block_k`` are the rows of q and of k, v resident a
     grid step, ``head_fold`` the heads a step takes.  Unnamed,
@@ -1037,16 +1233,32 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
     per-rank compute inside ring attention, or standalone single-chip.
     """
     q, k, v = (jnp.asarray(x) for x in (q, k, v))
-    if q.shape != k.shape or q.shape != v.shape or q.ndim != 3:
-        raise ValueError(f"q/k/v must share (S, H, D), got {q.shape}, "
-                         f"{k.shape}, {v.shape}")
+    if (q.ndim != 3 or k.ndim != 3 or v.ndim != 3
+            or k.shape[::2] != q.shape[::2] or v.shape[0] != q.shape[0]
+            or q.shape[1] % k.shape[1] or q.shape[1] % v.shape[1]):
+        raise ValueError(f"q/k/v must share S, k's width be q's and their "
+                         f"heads divide q's: q (S, H, D), k (S, H/gk, D), v "
+                         f"(S, H/gv, Dv); got {q.shape}, {k.shape}, "
+                         f"{v.shape}")
     S, H, D = q.shape
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("window needs causal=True and window >= 1")
+        window = None if window >= S else int(window)
     block_q, block_k, head_fold = tuned_flash_config(
         S, H, D, q.dtype, bool(causal), block_q, block_k, head_fold)
     bq, bk = _fit_block(block_q, S), _fit_block(block_k, S)
     hfold = _fit_block(max(int(head_fold), 1), H)
+    if k.shape[1] != H or v.shape[1] != H:
+        hfold = 1             # a step's heads would straddle the groups
     if interpret is None:
         interpret = not _on_tpu()
     sc = float(1.0 / np.sqrt(D) if scale is None else scale)
-    return _flash_core(q, k, v, bool(causal), sc, bq, bk, bool(interpret),
-                       hfold)
+    if window is None:
+        return _flash_core(q, k, v, bool(causal), sc, bq, bk,
+                           bool(interpret), hfold)
+    # a windowed program's grid steps are told apart by their distance
+    # from the diagonal in whole blocks: square blocks
+    bq = bk = min(bq, bk)
+    return _flash_core(q, k, v, True, sc, bq, bk, bool(interpret), hfold,
+                       window)

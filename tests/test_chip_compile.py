@@ -164,6 +164,38 @@ def test_flash_kernels_at_the_benchmark_shapes(one_chip, s, heads, d,
     assert ("flash_bwd_dq" in txt) == ("flash_bwd_dq" in backward)
 
 
+@pytest.mark.parametrize("window", [512, None], ids=["window", "full"])
+def test_flash_kernels_at_the_hybrid_cell_shapes(one_chip, window):
+    # what phi4mf_train_s8k calls: 40 query heads of 64 on 20 key heads and
+    # 10 value heads of 128, 8192 positions, windowed and not
+    sds = lambda h, d: jax.ShapeDtypeStruct((8192, h, d), jnp.bfloat16,
+                                            sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(PA.flash_attention(q, k, v, causal=True, window=window,
+                                          interpret=False)
+                       .astype(jnp.float32))
+
+    txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), sds(40, 64),
+                         sds(20, 64), sds(10, 128))
+    for name in ("flash_fwd", "flash_bwd_dkv"):
+        assert name in txt, name
+
+
+def test_selective_scan_kernels_at_the_hybrid_cell_shapes(one_chip):
+    from distributedarrays_tpu.ops import pallas_selective_scan as PS
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(PS.selective_scan(x, dt, a, b, c, interpret=False))
+
+    txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                         sds(8192, 5120), sds(8192, 5120), sds(5120, 16),
+                         sds(8192, 16), sds(8192, 16))
+    for name in ("selective_scan_fwd", "selective_scan_bwd"):
+        assert name in txt, name
+
+
 def test_stencil5_block_8192(one_chip):
     x = jax.ShapeDtypeStruct((8192, 8192), jnp.float32, sharding=one_chip)
     h = jax.ShapeDtypeStruct((1, 8192), jnp.float32, sharding=one_chip)

@@ -69,6 +69,47 @@ def test_spelling_the_benchmark_readers_rely_on():
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_carry"}
     assert {n for n in names if n.startswith("ring_")} == {
         "ring_all_gather", "ring_all_to_all", "ring_reduce_scatter"}
+    assert {n for n in names if n.startswith("selective_scan_")} == {
+        "selective_scan_fwd", "selective_scan_bwd"}
+
+
+@pytest.mark.parametrize("scope", [
+    "embed", "mamba", "window", "full", "gmu", "cross", "mlp", "head_loss",
+    "optimizer", "selective_scan_fwd", "selective_scan_bwd", "flash_fwd",
+    "flash_bwd_dkv"])
+def test_sambay_step_carries_its_scopes_and_kernel_names(scope):
+    # the scopes docs/telemetry.md lists for models/sambay.py reach the
+    # step program's op names: forward, recomputed and backward
+    from distributedarrays_tpu.models import sambay as S
+    cut = tuple((i, k) for i, k in S.layer_kinds(32, 2) if 14 <= i <= 19)
+    cfg = S.Config(vocab=96, dim=128, ffn=256, heads=8, kv_heads=4,
+                   head_dim=16, window=24, layers=cut, loss_rows=32)
+    text = _sambay_step_text(cfg)
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    if scope in S.KINDS or scope == "mlp":
+        hits = [n for n in names if f"block/{scope}/" in n
+                or f"jvp(block)/{scope}/" in n]
+        # a layer is computed forward, again in the backward, and backward
+        assert any("rematted_computation" in n for n in hits)
+        assert any(n.startswith("jit(step)/jvp(") for n in hits)
+    else:
+        hits = [n for n in names if scope in n]
+    assert hits, scope
+
+
+_STEP_TEXT = {}
+
+
+def _sambay_step_text(cfg):
+    if cfg not in _STEP_TEXT:
+        import optax
+        from distributedarrays_tpu.models import sambay as S
+        step, init = S.make_optax_train_step(cfg, optax.adamw(1e-3))
+        p = jax.eval_shape(lambda: S.init_params(jax.random.key(0), cfg))
+        _STEP_TEXT[cfg] = step.lower(
+            p, jax.eval_shape(init, p),
+            jax.ShapeDtypeStruct((1, 33), jnp.int32)).as_text(debug_info=True)
+    return _STEP_TEXT[cfg]
 
 
 # ---------------------------------------------------------------------------
