@@ -811,8 +811,17 @@ def phase_multichip(ctx):
         plan = R.plan_reshard(garr(src), like.sharding)
         ctx.require(f"reshard {frm}->{dst}: planned as a collective",
                     plan.collective, strategy=plan.strategy)
+        relayed0 = tm.counter_value("reshard.exchange_relayed")
         out, cold, warm = _timed(
             lambda: R.reshard(garr(src), like.sharding))
+        relayed = tm.counter_value("reshard.exchange_relayed") - relayed0
+        if ctx.on_tpu and p == 4:
+            # the 2x2's real coords: the exchange relays its two diagonal
+            # pieces over the idle links, the ring legs relay nothing
+            exchange = [s[0] for s in plan.steps] == ["exchange"]
+            ctx.require(f"reshard {frm}->{dst}: pieces relayed by coords",
+                        (relayed > 0) == exchange, relayed=relayed,
+                        coords=[list(d.coords) for d in jax.devices()[:p]])
         put = jax.device_put(garr(src), like.sharding)
         got_sh, put_sh = _shards(out), _shards(put)
         ctx.require(f"reshard {frm}->{dst}: placed as asked",

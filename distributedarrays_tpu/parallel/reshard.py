@@ -68,6 +68,7 @@ DArray buffers outside this module, so new code routes through here.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import itertools
@@ -583,6 +584,169 @@ def _exchange_dests(sizes, a_comp, b_comp, r, s):
     return tuple(dests)
 
 
+_MAX_ROUTES = 16        # shortest routes weighed for one piece
+
+
+def _device_coords(mesh):
+    """For each rank of ``mesh`` (row-major) the physical coordinates of
+    its chip, as plain tuples.  None where the devices report none (CPU,
+    interpret), where two ranks share coordinates (cores of one chip,
+    chips of different slices), and where the chips are not the four of
+    a 2x2: which links join the ranks, and which of them XLA takes, has
+    been read on that slice alone (PERF.md, PRs 26 and 34), so every
+    other one keeps XLA's routes until it can be measured."""
+    coords = []
+    for dev in mesh.devices.flat:
+        c = getattr(dev, "coords", None)
+        if c is None:
+            return None
+        coords.append(tuple(int(v) for v in c))
+    extents = sorted(len({c[ax] for c in coords})
+                     for ax in range(len(coords[0])))
+    if len(set(coords)) != len(coords) or len(coords) != 4 \
+            or extents[-2:] != [2, 2]:
+        return None
+    return tuple(coords)
+
+
+def _dimension_ordered(a, b):
+    """The chips a piece passes from ``a`` to ``b`` when XLA routes its
+    ``collective-permute``: one axis after the other, the first axis
+    first (read on the 2x2: 1->0->2 and 2->3->1, PERF.md, PR 26)."""
+    path, cur = [a], list(a)
+    for ax in range(len(a)):
+        while cur[ax] != b[ax]:
+            cur[ax] += 1 if b[ax] > cur[ax] else -1
+            path.append(tuple(cur))
+    return tuple(path)
+
+
+def _shortest_routes(a, b, chips):
+    """Every shortest route from ``a`` to ``b`` over ``chips``: a step
+    along one axis at a time, always towards ``b``.  ``coords`` say
+    nothing of wrap-around links, so none is assumed."""
+    if a == b:
+        yield (a,)
+        return
+    for ax in range(len(a)):
+        if a[ax] != b[ax]:
+            nxt = a[:ax] + (a[ax] + (1 if b[ax] > a[ax] else -1),) \
+                + a[ax + 1:]
+            if nxt in chips:
+                for rest in _shortest_routes(nxt, b, chips):
+                    yield (a,) + rest
+
+
+def _links(route):
+    """The directed links a route crosses."""
+    return set(zip(route, route[1:]))
+
+
+def _route_exchange(dests, coords):
+    """Choose, per piece of a block exchange, the route its bytes take
+    over the chips' links.  ``dests`` are :func:`_exchange_dests`'
+    rounds, ``coords`` each rank's chip (:func:`_device_coords`).  All
+    pieces have one size and all rounds are in flight together, so a
+    directed link's load is the number of pieces that cross it in any
+    round.  A piece between neighbours (one step on one axis) stays a
+    direct ``ppermute``; so does one that a torus would send the other
+    way round an axis (more than half its extent: whether that link
+    exists cannot be seen).  Every other piece starts on the route XLA
+    gives it (:func:`_dimension_ordered`) and is moved to another
+    shortest route through chips of this mesh as long as that takes it
+    off a busiest link without making another link as busy: a descent,
+    which never raises the busiest link's load.  Ties go to the direct
+    ``ppermute``.
+
+    Returns ``(relays, direct_load, load)``: per round the chains of
+    ranks ``(source, relay, ..., destination)`` of the pieces to relay
+    hop by hop, and the busiest link's load in pieces under XLA's routes
+    and under the chosen ones.  No relay unless the load falls; without
+    ``coords`` no relay and loads of 0."""
+    none = ((),) * len(dests)
+    if coords is None:
+        return none, 0, 0
+    chips = {c: rank for rank, c in enumerate(coords)}
+    extent = [max(c[ax] for c in coords) - min(c[ax] for c in coords) + 1
+              for ax in range(len(coords[0]))]
+    load = collections.Counter()
+    pieces = {}         # (round, source) -> [XLA's route, chosen, others]
+    for t, dest in enumerate(dests):
+        for src, dst in enumerate(dest):
+            a, b = coords[src], coords[dst]
+            direct = _dimension_ordered(a, b)
+            load.update(_links(direct))
+            if len(direct) > 2 and all(
+                    2 * abs(x - y) <= e for x, y, e in zip(a, b, extent)):
+                others = [r for r in itertools.islice(
+                    _shortest_routes(a, b, chips), _MAX_ROUTES)
+                    if r != direct]
+                if others:
+                    pieces[t, src] = [direct, direct, others]
+    direct_load = max(load.values(), default=0)
+
+    def move(piece, route, below, off=None):
+        """Put ``piece`` on ``route`` if that takes it off a link that
+        carries ``off`` pieces (where given) and every link new to it
+        stays under ``below``."""
+        old, new = _links(piece[1]) - _links(route), \
+            _links(route) - _links(piece[1])
+        if any(load[ln] + 1 >= below for ln in new) or \
+                (off is not None and all(load[ln] != off for ln in old)):
+            return False
+        load.subtract(old)
+        load.update(new)
+        piece[1] = route
+        return True
+
+    # each move lowers (busiest load, links that busy): it ends
+    moved = True
+    while moved:
+        moved = False
+        top = max(load.values(), default=0)
+        for piece in pieces.values():
+            for route in [piece[0]] + piece[2]:
+                moved |= move(piece, route, top, off=top)
+    top = max(load.values(), default=0)
+    if top >= direct_load:
+        return none, direct_load, direct_load
+    relays = [[] for _ in dests]
+    for (t, _src), piece in pieces.items():
+        # ties go to the direct ppermute
+        if piece[1] != piece[0] and not move(piece, piece[0], top + 1):
+            relays[t].append(tuple(chips[c] for c in piece[1]))
+    return tuple(tuple(r) for r in relays), direct_load, top
+
+
+def _hop_groups(pairs, relays):
+    """Pack a round's transfers into ``ppermute``s.  ``pairs`` are its
+    direct ``(source, destination)`` pairs, ``relays`` its chains from
+    :func:`_route_exchange`.  A group is a list of levels
+    ``(pairs, ends)``: level ``h`` is one ``ppermute`` that carries what
+    level ``h - 1`` delivered one hop on (level 0 carries the ranks' own
+    pieces), and ``ends`` are the ranks whose piece has arrived with it.
+    A chain's hops join the first group in which every level stays a
+    partial permutation (a rank sends at most once and receives at most
+    once), the direct pairs' group first, and open a group where none
+    does."""
+    groups = [[(list(pairs), [to for _c, to in pairs])]] if pairs else []
+    for chain in relays:
+        hops = list(zip(chain, chain[1:]))
+        for g in groups:
+            if not any(s == a or d == b
+                       for (s, d), (prs, _e) in zip(hops, g)
+                       for a, b in prs):
+                break
+        else:
+            g = []
+            groups.append(g)
+        g.extend(([], []) for _ in range(len(hops) - len(g)))
+        for hop, (prs, _e) in zip(hops, g):
+            prs.append(hop)
+        g[len(hops) - 1][1].append(chain[-1])
+    return groups
+
+
 def _fuse_detours(ops, sizes, src_comp, work):
     """Replace every detour of the schedule — a digit gathered and later
     sliced back, with whatever runs in between — by ONE ``exchange`` op
@@ -1084,15 +1248,18 @@ def _comp_spec(comp, ndim):
     return P(*entries)
 
 
-def _exchange_blocks(x, names, sizes, a_comp, b_comp, chunk_axis, nchunks):
+def _exchange_blocks(x, names, sizes, a_comp, b_comp, chunk_axis, nchunks,
+                     route):
     """The direct block exchange of a chain, inside its shard_map body:
     per round every rank cuts the piece that leaves it out of its source
     block, one ``ppermute`` over the whole refined mesh carries the
     pieces, and each lands at its final offset in the output block.  A
     piece whose owner does not change is copied locally.  Chunked along
-    ``chunk_axis`` so one transient stays under the chunk target."""
-    r, s = _exchange_ratios(sizes, a_comp, b_comp)
-    dests = _exchange_dests(sizes, a_comp, b_comp, r, s)
+    ``chunk_axis`` so one transient stays under the chunk target.
+    ``route`` is the step's entry of :func:`_chain_routes`: the rounds'
+    destinations and, where pieces are relayed, their chains; those
+    travel one ``ppermute`` a hop (:func:`_exchange_relayed`)."""
+    r, s, dests, relays = route
     rounds = len(dests)
     coords = [lax.axis_index(n) for n in names]
     _a, j, k = _exchange_slots(sizes, a_comp, b_comp, r, s, coords)
@@ -1115,11 +1282,17 @@ def _exchange_blocks(x, names, sizes, a_comp, b_comp, chunk_axis, nchunks):
         keeps = jnp.asarray([to == c for c, to in enumerate(dest)])[me] \
             if len(pairs) < len(dest) else None
         sends.append((at_src, at_dst, pairs, keeps))
+    steps = [[c * chunk[d] if d == chunk_axis else 0 for d in range(x.ndim)]
+             for c in range(nchunks)]
+    if any(relays):
+        return _exchange_relayed(x, names, me, len(dests[0]), sends, relays,
+                                 chunk, steps, out)
     # chunk by chunk through all rounds, so that the rounds' transfers,
-    # which ride different links, are in flight together
-    for c in range(nchunks):
-        step = [c * chunk[d] if d == chunk_axis else 0
-                for d in range(x.ndim)]
+    # which ride different links, are in flight together.  Not the ticks
+    # of _exchange_relayed: with nothing relayed their barriers order
+    # nothing and cost a quarter more ((1,4)->(2,2) on the 2x2 under
+    # XLA's routes: 53.0 ms against this loop's 41.7; PERF.md, PR 34)
+    for step in steps:
         for at_src, at_dst, pairs, keeps in sends:
             got = part = lax.dynamic_slice(
                 x, [o + e for o, e in zip(at_src, step)], chunk)
@@ -1132,6 +1305,118 @@ def _exchange_blocks(x, names, sizes, a_comp, b_comp, chunk_axis, nchunks):
     return out
 
 
+def _exchange_relayed(x, names, me, nranks, sends, relays, chunk, steps,
+                      out):
+    """The chunk loop of :func:`_exchange_blocks` where pieces are
+    relayed: a tick a chunk, and in a tick every transfer in flight goes
+    one hop, so hop 2 of chunk ``c`` flies with hop 1 of chunk ``c + 1``
+    and with the round of direct pairs, each on links of its own.  Left
+    to XLA's scheduler two of the three streams fly and the third
+    follows them, and the placing bunches at the end (PERF.md, PR 34);
+    so each tick's operands pass one ``optimization_barrier`` together
+    with what arrived a tick before, the output block and the source
+    block.  Between two barriers lie a tick's ``ppermute``s and, under
+    their wire, the placing of the earlier tick's pieces and the cutting
+    of the next tick's.  A relay holds the chunk in transit and nothing
+    more."""
+    plans = []
+    for (at_src, at_dst, pairs, keeps), chains in zip(sends, relays):
+        sent = {chain[0] for chain in chains}
+        groups = _hop_groups([p for p in pairs if p[0] not in sent], chains)
+        arrivals = [ends for g in groups for _prs, ends in g if ends]
+        pick = keeps
+        if len(arrivals) > 1:
+            # pieces arrive with different ppermutes: which one is this
+            # rank's (0: the piece it keeps)
+            which = np.zeros(nranks, np.int32)
+            for n, ends in enumerate(arrivals):
+                which[ends] = n + 1
+            pick = jnp.asarray(which)[me]
+        plans.append((at_src, at_dst, groups, len(arrivals), pick))
+
+    def cut(x, step):
+        return [lax.dynamic_slice(x, [o + e for o, e in zip(p[0], step)],
+                                  chunk) for p in plans]
+
+    def transfer(plan, part, step):
+        """One chunk of one round's pieces: yields the operand of each
+        ``ppermute`` and takes it back from the tick's barrier; returns
+        what :func:`place` needs."""
+        _at_src, at_dst, groups, narrive, pick = plan
+        arrived = []
+        for g in groups:
+            carry = part
+            for prs, ends in g:
+                carry = lax.ppermute((yield carry), names, prs)
+                if ends:
+                    arrived.append(carry)
+        return part, arrived, narrive, pick, \
+            [o + e for o, e in zip(at_dst, step)]
+
+    def place(out, part, arrived, narrive, pick, at):
+        got = part
+        if narrive > 1:
+            got = lax.select_n(pick, part, *arrived)
+        elif narrive:
+            got = arrived[0]
+            if pick is not None:
+                got = jnp.where(pick, part, got)
+        return lax.dynamic_update_slice(out, got, at)
+
+    flying = []         # [transfer, operand of its next ppermute]
+    due = []            # what the transfers that ended a tick ago return
+    parts = cut(x, steps[0])
+    tick = 0
+    while tick < len(steps) or flying or due:
+        ended = []
+        if tick < len(steps):
+            for plan, part in zip(plans, parts):
+                f = transfer(plan, part, steps[tick])
+                try:
+                    flying.append([f, next(f)])
+                except StopIteration as e:   # every rank keeps its piece
+                    ended.append(e.value)
+        tick += 1
+        ops, held, out, x = lax.optimization_barrier(
+            ([op for _f, op in flying], [d[:2] for d in due], out, x))
+        still = []
+        for (f, _op), op in zip(flying, ops):
+            try:
+                still.append([f, f.send(op)])
+            except StopIteration as e:
+                ended.append(e.value)
+        flying = still
+        for (part, arrived), d in zip(held, due):
+            out = place(out, part, list(arrived), *d[2:])
+        if tick < len(steps):
+            parts = cut(x, steps[tick])
+        due = ended
+    return out
+
+
+@functools.lru_cache(maxsize=512)
+def _chain_routes(mesh, steps):
+    """What a planned chain's ``exchange`` steps send where, decided
+    once for the program that emits it and the counter that reports it:
+    per step (None for the other kinds) ``(r, s, dests, relays)`` for
+    :func:`_exchange_blocks`, then the number of pieces the chain sends
+    by relay, and the busiest link's load in pieces under XLA's routes
+    and under the chosen ones (:func:`_route_exchange`)."""
+    coords = _device_coords(mesh)
+    routes, relayed, direct_load, load = [], 0, 0, 0
+    for step in steps:
+        if step[0] != "exchange":
+            routes.append(None)
+            continue
+        r, s = _exchange_ratios(mesh.axis_sizes, step[3], step[4])
+        dests = _exchange_dests(mesh.axis_sizes, step[3], step[4], r, s)
+        relays, was, now = _route_exchange(dests, coords)
+        routes.append((r, s, dests, relays))
+        relayed += sum(len(chains) for chains in relays)
+        direct_load, load = max(direct_load, was), max(load, now)
+    return tuple(routes), relayed, direct_load, load
+
+
 @functools.lru_cache(maxsize=512)
 def _chain_jit(mesh, ndim, src_comp, dst_comp, steps, rdma=None):
     """ONE compiled shard_map program running a planned per-axis
@@ -1142,13 +1427,15 @@ def _chain_jit(mesh, ndim, src_comp, dst_comp, steps, rdma=None):
     multi-axis; interpret mode demotes multi-axis arming to the lax
     fallback inside the kernel, so CPU runs stay correct."""
     _tm.count("jit.builds", fn="reshard_chain")
-    # cold path: lru-miss body, once per distinct planned chain
-    _tm.event("jit", "build", fn="reshard_chain",  # dalint: disable=DAL003
-              steps=len(steps), rdma=str(rdma))
     in_spec = _comp_spec(src_comp, ndim)
     out_spec = _comp_spec(dst_comp, ndim)
     names = mesh.axis_names
     mesh_axes = tuple(names) if len(names) > 1 else None
+    routes, _relayed, direct_load, load = _chain_routes(mesh, steps)
+    # cold path: lru-miss body, once per distinct planned chain
+    _tm.event("jit", "build", fn="reshard_chain",  # dalint: disable=DAL003
+              steps=len(steps), rdma=str(rdma),
+              link_load_direct=direct_load, link_load=load)
 
     @jax.named_scope("reshard.chain")
     def kernel(x):
@@ -1178,7 +1465,7 @@ def _chain_jit(mesh, ndim, src_comp, dst_comp, steps, rdma=None):
                     # i, j: the composites before and after; ppermute
                     # whether or not the ring kernels are armed
                     x = _exchange_blocks(x, tuple(names), mesh.axis_sizes,
-                                         i, j, ca, nc)
+                                         i, j, ca, nc, routes[n])
                 else:                        # slice: local, no comm
                     r = lax.axis_index(name)
                     blk = x.shape[j] // q
@@ -1223,6 +1510,9 @@ def _run_chain(x, dst_sharding, plan: ReshardPlan, rdma=None):
         y = fn(x)
         for step in plan.steps:
             _tm.count("reshard.chain_steps", kind=step[0])
+        relayed = _chain_routes(mesh, plan.steps)[1]
+        if relayed:
+            _tm.count("reshard.exchange_relayed", relayed)
         if plan.pad_shape:
             return _slice_back_jit(dst_sharding, plan.shape)(y)
         if plan.strategy == "gather_put":

@@ -277,6 +277,47 @@ def test_ring_all_to_all_at_the_reshard_cell_shapes(topo, ring_mesh, on_tpu,
                             inflight=window) == before + 1
 
 
+def test_block_exchange_at_the_reshard_cell_shape_relays_by_coords(topo):
+    # leg 2 of the benchmark's 2x2 cycle, (1,4)->(2,2) of X 32768x49152
+    # f32, built on the described chips, whose coords are the real ones:
+    # the two diagonal pieces go 1->3->2 and 2->0->1, a ppermute a hop,
+    # and a tick's three permutes (hop 1, hop 2 of the chunk before, the
+    # neighbours' round) are in flight together, start start start, done
+    # done done; the temporaries are a few chunks, not the pieces
+    import re
+    from distributedarrays_tpu import layout as L
+    from distributedarrays_tpu.parallel import reshard as R
+    shape = (32768, 49152)
+    plan = R.plan_reshard(
+        shape, L.sharding_for(list(range(4)), (2, 2), shape),
+        src_sharding=L.sharding_for(list(range(4)), (1, 4), shape),
+        itemsize=4)
+    assert [s[0] for s in plan.steps] == ["exchange"] and plan.nchunks == 16
+    mesh = Mesh(np.asarray(topo.devices, dtype=object).reshape(2, 2),
+                ("d0", "d1"))
+    assert R._device_coords(mesh) == ((0, 0, 0), (1, 0, 0), (0, 1, 0),
+                                      (1, 1, 0))
+    fn = R._chain_jit(mesh, 2, plan.src_comp, plan.dst_comp, plan.steps,
+                      None)
+    assert R._chain_routes(mesh, plan.steps)[1:] == (2, 2, 1)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=NamedSharding(
+        mesh, R._comp_spec(plan.src_comp, 2)))
+    compiled = fn.lower(x).compile()
+    txt = compiled.as_text()
+    pairs = re.findall(
+        r"collective-permute-start\(.*source_target_pairs=(\{[^ ]*\}),", txt)
+    assert len(pairs) == 3 * plan.nchunks
+    assert set(pairs) == {"{{1,3},{2,0}}", "{{3,2},{0,1}}",
+                          "{{0,2},{1,0},{2,3},{3,1}}"}
+    flying, most, full = 0, 0, 0
+    for kind in re.findall(r"= .* collective-permute-(start|done)\(", txt):
+        flying += 1 if kind == "start" else -1
+        most = max(most, flying)
+        full += flying == 3 and kind == "start"
+    assert most == 3 and full >= plan.nchunks - 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 def test_ring_reduce_scatter_4chips(ring_mesh, on_tpu):
     # the derived chunk depth is 1 at this size and the p-1 receive slots
     # then exceed scoped VMEM (the kernel would give way, and say so);
