@@ -56,7 +56,9 @@ _DISPATCH = "pallas_collectives.dispatch"
 REAL = dict(
     n_gemm=4096, n_chain=8192, n_vec=100_000_000, n_big=16384,
     n_stencil=8192, stencil_iters=3, stencil_k=8, check_rows=256,
-    flash_s=8192, flash_bwd_s=2048, flash_heads={64: 8, 128: 4},
+    flash_s=8192, flash_bwd_s=2048, flash_heads={64: 8, 128: 4, 256: 2},
+    moe=dict(tokens=4096, load=(700, 0, 4096, 300, 511, 512, 513, 60),
+             d=2048, f=1536),
     model=dict(vocab=8192, dim=1024, heads=16, layers=8, ffn_mult=4,
                max_seq=2048),
     batch=4, train_steps=5, trainer_steps=3, lr=1.0,
@@ -67,7 +69,8 @@ REAL = dict(
 TINY = dict(
     n_gemm=256, n_chain=256, n_vec=1 << 16, n_big=512,
     n_stencil=256, stencil_iters=3, stencil_k=2, check_rows=32,
-    flash_s=256, flash_bwd_s=128, flash_heads={64: 2, 128: 1},
+    flash_s=256, flash_bwd_s=128, flash_heads={64: 2, 128: 1, 256: 1},
+    moe=dict(tokens=40, load=(5, 0, 40, 8), d=32, f=24),
     model=dict(vocab=256, dim=128, heads=2, layers=2, ffn_mult=4,
                max_seq=64),
     batch=2, train_steps=5, trainer_steps=3, lr=1.0,
@@ -452,6 +455,48 @@ def phase_kernels(ctx):
             ctx.check(f"flash bwd S={Sb} H={2 * H} d{D} {name}",
                       _rel_err(g, wnt), 3e-2, cold_s=cold, seconds=warm)
         del q, k, v, w, w32, got, want
+
+    # -- an expert layer's grouped products: uneven and empty groups --------
+    from distributedarrays_tpu.models.moe import held_experts_apply
+    g = sz["moe"]
+    T, kk, n_e = g["tokens"], 4, len(g["load"])
+    idx = np.full((T, kk), n_e, np.int32) + np.arange(kk, dtype=np.int32)
+    for e, load in enumerate(g["load"]):    # the first load[e] tokens choose e
+        idx[:load, e % kk] = e
+    idx = jnp.asarray(idx)
+    u = jax.random.normal(next(keys), (T, g["d"]), jnp.bfloat16)
+    wts = jax.random.uniform(next(keys), (T, kk), jnp.float32, 0.1, 1.0)
+    w1 = (jax.random.normal(next(keys), (n_e, g["d"], 2 * g["f"]),
+                            jnp.float32) / np.sqrt(g["d"])
+          ).astype(jnp.bfloat16)
+    w2 = (jax.random.normal(next(keys), (n_e, g["f"], g["d"]), jnp.float32)
+          / np.sqrt(g["f"])).astype(jnp.bfloat16)
+    ct = jax.random.normal(next(keys), (T, g["d"]), jnp.float32)
+    loss = lambda u, w1, w2: jnp.sum(held_experts_apply(
+        u, idx, wts, w1, w2, held=(0, n_e)).astype(jnp.float32) * ct)
+
+    def oracle(u, w1, w2):       # every expert on every token, under a mask
+        y = 0.0
+        for e in range(n_e):
+            w_e = jnp.sum(jnp.where(idx == e, wts, 0.0), axis=-1)
+            gg, v = jnp.split(jnp.dot(u, w1[e], precision=hp), 2, axis=-1)
+            y = y + w_e[:, None] * jnp.dot(jax.nn.silu(gg) * v, w2[e],
+                                           precision=hp)
+        return jnp.sum(y * ct)
+
+    grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    (val, got), cold, warm = _timed(lambda: grad(u, w1, w2))
+    want_val, want = jax.value_and_grad(oracle, argnums=(0, 1, 2))(
+        *(t.astype(jnp.float32) for t in (u, w1, w2)))
+    ctx.check(f"held_experts_apply {T} tokens, loads {list(g['load'])}: sum",
+              abs(float(val) - float(want_val)) / abs(float(want_val)), 2e-2,
+              cold_s=cold, seconds=warm)
+    for name, a_, b_ in zip(("du", "dw1", "dw2"), got, want):
+        ctx.check(f"held_experts_apply {name}", _rel_err(a_, b_), 3e-2,
+                  cold_s=cold, seconds=warm)
+    ctx.require("held_experts_apply: an empty group's dW is zeros",
+                not bool(jnp.any(got[1][1])) and not bool(jnp.any(got[2][1])))
+    del u, w1, w2, ct, got, want
 
     # -- stencil kernels: streaming single step, temporal k-step ----------
     n, kk = sz["n_stencil"], sz["stencil_k"]

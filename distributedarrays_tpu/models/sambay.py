@@ -46,7 +46,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pallas_attention import flash_attention
 from ..ops.pallas_selective_scan import selective_scan
-from .transformer import optax_f32_step
+from .transformer import blocked_nll, optax_f32_step
 
 __all__ = ["Config", "KINDS", "layer_kinds", "lambda_init", "init_params",
            "forward", "loss_fn", "make_optax_train_step"]
@@ -368,26 +368,7 @@ def loss_fn(params, tokens, cfg: Config):
     tok, tgt = tokens[:, :-1], tokens[:, 1:]
     x = _trunk(params, tok, cfg, remat=True)
     with jax.named_scope("head_loss"):
-        B, S, D = x.shape
-        rows = B * S
-        blk = min(cfg.loss_rows, rows)
-        while rows % blk:
-            blk -= 1
-        xb = x.reshape(rows // blk, blk, D)
-        tb = tgt.reshape(rows // blk, blk)
-
-        @jax.checkpoint
-        def block_nll(emb, xr, tr):
-            logits = jnp.einsum("sd,vd->sv", xr, emb,
-                                preferred_element_type=jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
-            return jnp.sum(lse - picked)
-
-        total = jax.lax.scan(
-            lambda acc, xt: (acc + block_nll(params["embed"], *xt), None),
-            jnp.zeros((), jnp.float32), (xb, tb))[0]
-        return total / rows
+        return blocked_nll(x, params["embed"], tgt, cfg.loss_rows) / tgt.size
 
 
 def make_optax_train_step(cfg: Config, tx):

@@ -32,8 +32,8 @@ from ..ops.pallas_attention import flash_attention
 from .mlp import make_mesh
 
 __all__ = ["init_params", "forward", "loss_fn", "train_step",
-           "make_optax_train_step", "optax_f32_step", "generate",
-           "shard_params", "make_mesh", "Config"]
+           "make_optax_train_step", "optax_f32_step", "blocked_nll",
+           "generate", "shard_params", "make_mesh", "Config"]
 
 
 class Config:
@@ -181,6 +181,34 @@ def loss_fn(params, tokens, cfg: Config):
         logp = jax.nn.log_softmax(logits, axis=-1)
         ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
         return -jnp.mean(ll)
+
+
+def blocked_nll(x, table, targets, loss_rows: int):
+    """The summed cross-entropy of ``x`` (B, S, D) against ``targets``
+    (B, S) under the head ``table`` (vocab, D), float32: the logits exist
+    one block of at most ``loss_rows`` positions at a time, in both
+    directions (a block's are computed again in the backward), so a long
+    row's float32 logits are never held whole (``models/sambay.py``,
+    ``models/mla_moe.py``)."""
+    B, S, D = x.shape
+    rows = B * S
+    blk = min(loss_rows, rows)
+    while rows % blk:
+        blk -= 1
+    xb = x.reshape(rows // blk, blk, D)
+    tb = targets.reshape(rows // blk, blk)
+
+    @jax.checkpoint
+    def block_nll(tab, xr, tr):
+        logits = jnp.einsum("sd,vd->sv", xr, tab,
+                            preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
+        return jnp.sum(lse - picked)
+
+    return jax.lax.scan(
+        lambda acc, xt: (acc + block_nll(table, *xt), None),
+        jnp.zeros((), jnp.float32), (xb, tb))[0]
 
 
 def _decode_attn(h, blk, heads, kc, vc, i, t, max_seq):

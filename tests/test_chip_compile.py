@@ -17,6 +17,7 @@ library, and every xdist worker imports every test file).  Code that asks
 """
 
 import json
+import re
 from pathlib import Path
 
 import numpy as np
@@ -193,6 +194,23 @@ def test_selective_scan_kernels_at_the_hybrid_cell_shapes(one_chip):
                          sds(8192, 5120), sds(8192, 5120), sds(5120, 16),
                          sds(8192, 16), sds(8192, 16))
     for name in ("selective_scan_fwd", "selective_scan_bwd"):
+        assert name in txt, name
+
+
+def test_flash_kernels_at_the_latent_cell_shape(one_chip):
+    # what glm47f_train_s8k calls: 20 heads of 256 (192 + 64 rotary) on
+    # values of 256, 8192 positions; a head's dQ (16 MiB resident) does not
+    # fit VMEM beside its blocks, so the backward is two passes
+    q = jax.ShapeDtypeStruct((8192, 20, 256), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(PA.flash_attention(q, k, v, causal=True,
+                                          interpret=False)
+                       .astype(jnp.float32))
+
+    txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
         assert name in txt, name
 
 
@@ -383,4 +401,42 @@ def test_transformer_train_step_full_width(one_chip, on_tpu):
     # 8 layers x (flash forward + the one-sweep backward)
     assert compiled.as_text().count("tpu_custom_call") >= 16
     mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 14 * 2**30
+
+
+def test_mla_moe_train_step_at_the_benchmark_size(one_chip, monkeypatch):
+    # the step glm47f_train_s8k times, at its size: published widths, the
+    # dense layer, four expert layers with 8 of 64 experts and the MTP
+    # block, 19360 vocabulary rows, one row of 8194 ids, AdamW
+    import optax
+    from distributedarrays_tpu.models import mla_moe as M
+    monkeypatch.setattr(PA, "_on_tpu", lambda: True)
+    cfg = M.Config(vocab=19360, dim=2048, heads=20,
+                   q_rank=768, kv_rank=512, nope=192, rope=64, v_dim=256,
+                   ffn=10240, moe_ffn=1536, n_experts=64, held=(0, 8),
+                   top_k=4, route_scale=1.8,
+                   layers=M.published_layers(5), mtp=47)
+    step, init = M.make_optax_train_step(
+        cfg, optax.adamw(1e-3, weight_decay=0.1))
+    on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    shapes = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(shapes)) \
+        == 706_518_848
+    params = jax.tree_util.tree_map(on, shapes)
+    state = jax.tree_util.tree_map(on, jax.eval_shape(init, shapes))
+    tokens = jax.ShapeDtypeStruct((1, 8194), jnp.int32, sharding=one_chip)
+    compiled = step.lower(params, state, tokens).compile()
+    mem = compiled.memory_analysis()
+    print(f"mla_moe step for v5e:2x2: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, scratch "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB; {mem}")
+    txt = compiled.as_text()
+    count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", txt))
+    # six attention layers: a forward and a two-pass backward each
+    assert count("flash_fwd") == count("flash_bwd_dkv") == 6
+    assert count("flash_bwd_dq") == 6
+    # five expert blocks: two grouped products forward, four backward, and
+    # none computed again (the recomputed FFN half keeps their results)
+    print("grouped products:", count("ragged-dot-none"))
+    assert count("ragged-dot-none") == 30
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 14 * 2**30

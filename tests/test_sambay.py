@@ -392,6 +392,9 @@ def test_importing_the_package_loads_neither_the_model_nor_pallas():
         for name in ("distributedarrays_tpu.models.sambay",
                      "distributedarrays_tpu.models.sambay_reference",
                      "distributedarrays_tpu.ops.pallas_selective_scan",
+                     "distributedarrays_tpu.models.mla_moe",
+                     "distributedarrays_tpu.models.mla_moe_reference",
+                     "distributedarrays_tpu.models.moe",
                      "jax.experimental.pallas"):
             assert name not in sys.modules, name
         print("clean")

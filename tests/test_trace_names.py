@@ -97,6 +97,37 @@ def test_sambay_step_carries_its_scopes_and_kernel_names(scope):
     assert hits, scope
 
 
+@pytest.mark.parametrize("scope", [
+    "embed", "block/mla", "block/mlp", "block/moe/route",
+    "block/moe/experts", "block/moe/shared", "mtp", "head_loss", "optimizer",
+    "ragged_dot", "flash_fwd", "flash_bwd_dkv"])
+def test_mla_moe_step_carries_its_scopes_and_kernel_names(scope):
+    # the scopes docs/telemetry.md lists for models/mla_moe.py reach the
+    # step program's op names; the FFN half forward, recomputed and backward
+    from distributedarrays_tpu.models import mla_moe as M
+    cfg = M.Config(vocab=96, dim=64, heads=4, q_rank=24, kv_rank=16, nope=24,
+                   rope=8, v_dim=32, ffn=128, moe_ffn=32, n_experts=16,
+                   held=(4, 4), layers=((0, "dense"), (1, "moe")), mtp=47,
+                   loss_rows=32)
+    if cfg not in _STEP_TEXT:
+        import optax
+        step, init = M.make_optax_train_step(cfg, optax.adamw(1e-3))
+        p = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+        _STEP_TEXT[cfg] = step.lower(
+            p, jax.eval_shape(init, p),
+            jax.ShapeDtypeStruct((1, 34), jnp.int32)).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', _STEP_TEXT[cfg]))
+    hits = [n for n in names if scope.replace("block/", "block)/") in n
+            or scope in n]
+    assert hits, scope
+    if scope.startswith(("block/mlp", "block/moe")):
+        assert any("rematted_computation" in n for n in hits)
+        assert any(n.startswith("jit(step)/jvp(") for n in hits)
+    if scope == "mtp":
+        assert any("mtp/block" in n or "mtp)/block" in n for n in hits)
+        assert any("head_loss" in n for n in hits)
+
+
 _STEP_TEXT = {}
 
 
