@@ -42,7 +42,10 @@ Differentiable end to end with FlashAttention-2-style BACKWARD KERNELS
 recomputes P tile by tile, as TRANSPOSED scores (k.q^T with lse and D as
 rows) so that dV += P^T.dO and dK += dS^T.q are plain products.  Where a
 head's dQ (S x D float32) fits VMEM it is ONE sweep, the kernel named
-``flash_bwd_dkv``, that also produces dQ; otherwise, and on the ring hop,
+``flash_bwd_dkv``, that also produces dQ: under Mosaic's default scoped
+limit where the resident dQ takes at most half of it, and past that under
+a ``vmem_limit_bytes`` the kernel reckons from its own buffers, up to half
+of the chip's VMEM (``_fused_backward``).  Otherwise, and on the ring hop,
 a second K-sweep kernel ``flash_bwd_dq`` produces dQ.  Training memory
 stays O(S.d).  Gradients match the dense formulation to ~1e-6 in float32
 (tested).  The chosen blocks and the tiles a head computes by kind are
@@ -121,10 +124,16 @@ _UNROLL = 4
 _SPAN = 4
 # The fused backward keeps the whole dQ of a grid step's heads in VMEM
 # beside its blocks: S x D float32 of scratch and the output block in two
-# buffers.  Above this many bytes (half of the 16 MiB a kernel may use;
-# the q, k, v, dO blocks, dK and dV take the rest) the two-pass backward
-# is taken.
+# buffers.  Up to this many bytes (half of the 16 MiB a kernel may use
+# under Mosaic's default scoped limit; the q, k, v, dO blocks, dK and dV
+# take the rest) the fused kernel is built as it stands, no limit named.
 _FUSED_DQ_BYTES = 8 * 1024 * 1024
+# Past that the fused kernel names its own ``vmem_limit_bytes``, reckoned
+# from its buffers (``_fused_vmem_bytes``), as long as that stays within
+# this cap: half of the 128 MiB of VMEM a v5e core has, the rest left to
+# the fusions XLA runs beside the kernel.  A head's dQ at (8192, 256) in
+# bf16 asks for 38 MiB and twice that dQ for 58; past the cap, two passes.
+_FUSED_VMEM_CAP = 64 * 1024 * 1024
 
 
 def _when(cond):
@@ -453,15 +462,18 @@ def _count_steps(s: int, bq: int, bk: int, tq: int, tk: int, causal: bool,
 
 def _record_plan(kernel: str, s: int, d: int, causal: bool, sweep: str,
                  bq: int, bk: int, tq: int, tk: int, fold: int,
-                 window: int | None = None):
+                 window: int | None = None, vmem_limit: int | None = None):
     """The mechanism's gauge (docs/telemetry.md): when a static-offset
     program is built (trace time, never a step), what was chosen for
     (kernel, S, D, causal) and what its grid then does a head, one value
     for each ``what``: bq, bk, tq, tk, fold, and the ``_count_steps``
     kinds.  A windowed program's gauge carries its ``window`` as one more
-    label."""
+    label; a fused backward built under a raised VMEM limit one more
+    ``what``, ``vmem_limit`` (bytes)."""
     plan = dict(bq=bq, bk=bk, tq=tq, tk=tk, fold=fold,
                 **_count_steps(s, bq, bk, tq, tk, causal, sweep, window))
+    if vmem_limit is not None:
+        plan["vmem_limit"] = vmem_limit
     more = {} if window is None else {"window": window}
     for what, n in plan.items():
         _tm.set_gauge("pallas.flash_attention.plan", n, kernel=kernel, s=s,
@@ -722,13 +734,52 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, nq, nk, traced,
             dq_ref[:] = (dq_s[:] * scale).astype(dq_ref.dtype)
 
 
-def _fused_backward(s: int, d: int, out_dtype, hfold: int,
-                    traced: bool) -> bool:
-    """One backward sweep (dK, dV and dQ) where the resident dQ fits VMEM
-    and the offsets are static; two passes otherwise."""
-    lanes = -(-d // _LANE) * _LANE          # VMEM rows are whole registers
-    resident = hfold * s * lanes * (4 + 2 * jnp.dtype(out_dtype).itemsize)
-    return not traced and resident <= _FUSED_DQ_BYTES
+def _whole_lanes(w: int) -> int:
+    """A width as VMEM holds it: rows are whole 128-lane registers."""
+    return -(-w // _LANE) * _LANE
+
+
+def _fused_vmem_bytes(s: int, d: int, dv: int, bq: int, bk: int, dtype,
+                      out_dtype, kv_dtype, hfold: int) -> int:
+    """What the fused backward holds in VMEM a grid step, from its specs:
+    the resident dQ (float32 scratch and the output block), the q, dO, k,
+    v blocks, lse and D rows (a (1, tq) row takes 8 sublanes), dK and dV
+    (float32 scratch and their blocks), every block in two buffers; and a
+    body's float32 tiles (s, p, dP, dS and the copies the products take,
+    six of ``_SPAN`` tiles)."""
+    size = lambda t: jnp.dtype(t).itemsize
+    tq, tk = _tiles(bq, bk)
+    wide = _whole_lanes(d) + _whole_lanes(dv)
+    dq = s * _whole_lanes(d) * (4 + 2 * size(out_dtype))
+    blocks = 2 * (bq + bk) * wide * size(dtype)
+    rows = 2 * 2 * (bq // tq) * 8 * _whole_lanes(tq) * 4
+    dkv = bk * wide * (4 + 2 * size(kv_dtype))
+    tiles = 6 * tk * min(_SPAN * tq, bq) * 4
+    return hfold * (dq + blocks + rows + dkv) + tiles
+
+
+def _fused_backward(s: int, d: int, out_dtype, hfold: int, traced: bool,
+                    dv: int | None = None, bq: int = 1024, bk: int = 1024,
+                    dtype=None, kv_dtype=None):
+    """The backward's form, from the shapes alone, as ``(fused,
+    vmem_limit)``.  One sweep (dK, dV and dQ) with no limit named where
+    the offsets are static and the resident dQ fits beside the blocks
+    under the default limit (``_FUSED_DQ_BYTES``); one sweep under a
+    ``vmem_limit`` of the kernel's own buffers and a quarter more for
+    Mosaic's internal scratch, in whole MiB, where that stays within
+    ``_FUSED_VMEM_CAP``; two passes otherwise, and on the ring hop."""
+    if traced:
+        return False, None
+    resident = hfold * s * _whole_lanes(d) * (
+        4 + 2 * jnp.dtype(out_dtype).itemsize)
+    if resident <= _FUSED_DQ_BYTES:
+        return True, None
+    need = _fused_vmem_bytes(s, d, d if dv is None else dv, bq, bk,
+                             dtype or out_dtype, out_dtype,
+                             kv_dtype or out_dtype, hfold)
+    mib = 1024 * 1024
+    limit = -(-(need + need // 4) // mib) * mib
+    return (True, limit) if limit <= _FUSED_VMEM_CAP else (False, None)
 
 
 @functools.lru_cache(maxsize=64)
@@ -742,8 +793,9 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
     (H, S, D) arrays (the two int32[1] offsets only when ``traced``).
     ``dkv_call`` takes lse and D as rows cut to the q tile,
     (H, S/tq, 1, tq) (``_stat_rows``), ``dq_call`` as lane-replicated
-    columns (H, S, 128).  Where ``_fused_backward`` holds ``dq_call`` is
-    None and ``dkv_call`` returns (dq, dk, dv); else it returns (dk, dv).
+    columns (H, S, 128).  Where ``_fused_backward`` says one sweep
+    ``dq_call`` is None and ``dkv_call`` returns (dq, dk, dv), built under
+    the VMEM limit it names if it names one; else it returns (dk, dv).
     ``window``, ``gk``, ``gv`` and ``dv`` as in ``_build``: k is (H/gk, S,
     D), v (H/gv, S, dv) and dO (H, S, dv), while dK and dV come back one a
     QUERY head, (H, S, D) and (H, S, dv), in ``kv_dtype_str`` (float32
@@ -755,14 +807,15 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
     dv = d if dv is None else dv
     nq, nk = s // bq, s // bk
     tq, tk = _tiles(bq, bk)
-    fused = _fused_backward(s, d, out_dtype, hfold, traced)
+    fused, vmem_limit = _fused_backward(s, d, out_dtype, hfold, traced, dv,
+                                        bq, bk, dtype_str, kv_dtype)
     common = dict(scale=scale, causal=causal, bq=bq, bk=bk, tq=tq, tk=tk,
                   nq=nq, nk=nk, traced=traced, hfold=hfold, window=window)
     clamp = causal and not traced
     offs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2 if traced else []
     if not traced:
         plan = (bq, bk, tq, tk, hfold, window)
-        _record_plan("flash_bwd_dkv", s, d, causal, "q", *plan)
+        _record_plan("flash_bwd_dkv", s, d, causal, "q", *plan, vmem_limit)
         if not fused:
             _record_plan("flash_bwd_dq", s, d, causal, "k", *plan)
     kh, vh = _group_map(gk), _group_map(gv)
@@ -800,6 +853,10 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
         out_specs.insert(0, pl.BlockSpec((hfold, s, d),
                                          lambda hh, ki, qi: (hh, 0, 0)))
         scratch.insert(0, pltpu.VMEM((hfold, s, d), jnp.float32))
+    # a program that fits the default limit is built as before, to the
+    # letter: no compiler_params
+    more = {} if vmem_limit is None else dict(
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit))
     dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, with_dq=fused, **common),
         grid=(h // hfold, nk, nq),
@@ -810,6 +867,7 @@ def _build_bwd(h, s, d, bq, bk, dtype_str, scale, causal, interpret,
         scratch_shapes=scratch,
         name="flash_bwd_dkv",
         interpret=interpret,
+        **more,
     )
     if fused:
         return None, jax.jit(dkv_call)
@@ -1229,8 +1287,11 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
     ``head_fold`` is clipped to a divisor of H.  Inside a step the sweep
     goes tile by tile (``_TILE``), skips the tiles above the causal
     diagonal and masks only those it crosses; the backward is one fused
-    sweep where the resident dQ fits VMEM (``_fused_backward``).  Use as the
-    per-rank compute inside ring attention, or standalone single-chip.
+    sweep where the resident dQ fits VMEM: under the default scoped limit,
+    or under a larger one that the kernel names from its buffers, within
+    half of the chip's VMEM (``_fused_backward``); past that, two passes.
+    Use as the per-rank compute inside ring attention, or standalone
+    single-chip.
     """
     q, k, v = (jnp.asarray(x) for x in (q, k, v))
     if (q.ndim != 3 or k.ndim != 3 or v.ndim != 3

@@ -144,13 +144,21 @@ def test_flash_backward_2048(one_chip, heads, d):
     "s,heads,d,backward",
     [(1024, 128, 64, ("flash_bwd_dkv",)),
      (8192, 32, 128, ("flash_bwd_dkv",)),
-     (16384, 8, 128, ("flash_bwd_dq", "flash_bwd_dkv"))],
-    ids=["gpt2m_cell_d64", "s8k_d128", "s16k_d128"])
+     (16384, 8, 128, ("flash_bwd_dkv",)),
+     (8192, 20, 256, ("flash_bwd_dkv",)),
+     (16384, 4, 256, ("flash_bwd_dkv",)),
+     (32768, 4, 256, ("flash_bwd_dq", "flash_bwd_dkv"))],
+    ids=["gpt2m_cell_d64", "s8k_d128", "s16k_d128", "glm_cell_d256",
+         "s16k_d256", "s32k_d256_past_the_cap"])
 def test_flash_kernels_at_the_benchmark_shapes(one_chip, s, heads, d,
                                                backward):
     # the shape gpt2m_train calls and the long wide-head shape (one fused
-    # backward sweep each), and a sequence whose dQ a head does not fit
-    # VMEM (two passes), blocks and fold as flash_attention picks them
+    # backward sweep each under the default VMEM limit), the shapes whose
+    # resident dQ asks for a limit of its own (16 MiB of dQ a head at
+    # s16k_d128 and at glm47f_train_s8k's width 256, 32 MiB at s16k_d256:
+    # the chip's compiler has to take the kernel under the limit the
+    # shapes reckon), and a sequence whose need passes the cap (two
+    # passes), blocks and fold as flash_attention picks them
     q = jax.ShapeDtypeStruct((s, heads, d), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
@@ -199,8 +207,9 @@ def test_selective_scan_kernels_at_the_hybrid_cell_shapes(one_chip):
 
 def test_flash_kernels_at_the_latent_cell_shape(one_chip):
     # what glm47f_train_s8k calls: 20 heads of 256 (192 + 64 rotary) on
-    # values of 256, 8192 positions; a head's dQ (16 MiB resident) does not
-    # fit VMEM beside its blocks, so the backward is two passes
+    # values of 256, 8192 positions; a head's dQ (16 MiB resident) fits
+    # VMEM under the limit the fused kernel names for itself, so the
+    # backward is one sweep and nothing reaches it replicated over lanes
     q = jax.ShapeDtypeStruct((8192, 20, 256), jnp.bfloat16,
                              sharding=one_chip)
 
@@ -209,9 +218,14 @@ def test_flash_kernels_at_the_latent_cell_shape(one_chip):
                                           interpret=False)
                        .astype(jnp.float32))
 
+    limit = PA._fused_backward(8192, 256, "bfloat16", 1, False)[1]
     txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    for name in ("flash_fwd", "flash_bwd_dkv"):
         assert name in txt, name
+    assert "flash_bwd_dq" not in txt
+    # the kernel's scoped VMEM is the limit the shapes reckoned
+    assert f'"size":"{limit}"' in txt
+    assert "f32[20,8192,128]" not in txt
 
 
 def test_stencil5_block_8192(one_chip):
@@ -432,9 +446,10 @@ def test_mla_moe_train_step_at_the_benchmark_size(one_chip, monkeypatch):
           f"{mem.temp_size_in_bytes / 1e9:.2f} GB; {mem}")
     txt = compiled.as_text()
     count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", txt))
-    # six attention layers: a forward and a two-pass backward each
+    # six attention layers: a forward and a backward of one sweep each
+    # (the fused kernel names the VMEM its resident dQ needs, PR 36)
     assert count("flash_fwd") == count("flash_bwd_dkv") == 6
-    assert count("flash_bwd_dq") == 6
+    assert count("flash_bwd_dq") == 0
     # five expert blocks: two grouped products forward, four backward, and
     # none computed again (the recomputed FFN half keeps their results)
     print("grouped products:", count("ragged-dot-none"))

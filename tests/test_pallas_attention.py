@@ -145,6 +145,18 @@ def _exact_cases():
             1024, 64, causal, dtype, fold, bq, bk, fused,
             id=f"s1024-d64-{'causal' if causal else 'full'}-{dtype}"
                f"-fold{fold}-b{bq}x{bk}-{'fused' if fused else 'twopass'}")
+    # the latent cell's head width, small S: the fused sweep through the
+    # very pallas_call that names its VMEM limit (the default limit's room
+    # patched to nothing, so that every dQ asks for the raised one)
+    for s, dtype, causal, fold, bq, bk in [
+            (512, "bfloat16", True, 1, None, None),
+            (512, "float32", True, 2, 256, 256),
+            (1024, "bfloat16", True, 1, 512, 512),
+            (384, "float32", False, 1, 128, 128)]:
+        yield pytest.param(
+            s, 256, causal, dtype, fold, bq, bk, "raised",
+            id=f"s{s}-d256-{'causal' if causal else 'full'}-{dtype}"
+               f"-fold{fold}-b{bq}x{bk}-raised")
 
 
 @pytest.mark.parametrize("S,D,causal,dtype,fold,bq,bk,fused",
@@ -153,9 +165,13 @@ def test_flash_exact_output_and_grads(rng, monkeypatch, S, D, causal, dtype,
                                       fold, bq, bk, fused):
     import jax
     import jax.numpy as jnp
+    from distributedarrays_tpu import telemetry as tm
     from distributedarrays_tpu.ops import pallas_attention as PA
-    if not fused:
+    if fused is not True:
+        # nothing fits the default limit; two passes: nor the raised one
         monkeypatch.setattr(PA, "_FUSED_DQ_BYTES", 0)
+        if not fused:
+            monkeypatch.setattr(PA, "_FUSED_VMEM_CAP", 0)
         PA._build_bwd.cache_clear()
     # a causal S that is no multiple of a block is padded as _attention
     # pads it (keys at positions >= S are hidden from every real row)
@@ -180,7 +196,15 @@ def test_flash_exact_output_and_grads(rng, monkeypatch, S, D, causal, dtype,
     loss = lambda f: (lambda q, k, v: jnp.sum(f32(f(q, k, v)) * f32(w)))
     got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
     want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
-    if not fused:
+    if fused is not True:
+        if fused == "raised":
+            # the program that ran is the one sweep, and names its limit
+            limit = tm.gauge_value(
+                "pallas.flash_attention.plan", kernel="flash_bwd_dkv",
+                s=Spad, d=D, causal=causal, what="vmem_limit")
+            assert 0 < limit <= PA._FUSED_VMEM_CAP
+            assert f"vmem_limit_bytes={int(limit)}" in str(jax.make_jaxpr(
+                jax.grad(loss(flash), (0, 1, 2)))(q, k, v))
         PA._build_bwd.cache_clear()
     for name, a, b in zip("qkv", got, want):
         gap = float(jnp.abs(f32(a) - f32(b)).max() / jnp.abs(f32(b)).max())
@@ -281,7 +305,8 @@ def test_flash_plan_gauge_at_the_benchmark_cell_shape():
         assert [read(kernel, w) for w in ("bq", "bk", "fold")] == [
             1024, 1024, 1]
     # one backward program: a head's dQ fits VMEM
-    assert PA._fused_backward(1024, 64, jnp.bfloat16, 1, False)
+    assert PA._fused_backward(1024, 64, jnp.bfloat16, 1, False) == (
+        True, None)
     # the blocks the caller used to force leave one dead step a head
     jax.eval_shape(lambda q: PA.flash_attention(
         q, q, q, causal=True, block_q=512, block_k=512), q)
@@ -290,18 +315,124 @@ def test_flash_plan_gauge_at_the_benchmark_cell_shape():
     PA._build.cache_clear()
 
 
+_MIB = 1024 * 1024
+
+
 @pytest.mark.parametrize("s,d,dtype,fold,traced,want", [
-    (1024, 64, "bfloat16", 1, False, True),      # the benchmark cell
-    (8192, 128, "bfloat16", 1, False, True),
-    (16384, 128, "bfloat16", 1, False, False),   # dQ of a head: 16 MiB
-    (8192, 64, "float32", 1, False, False),      # 64 lanes pad to 128
-    (8192, 128, "bfloat16", 2, False, False),    # two heads a step
-    (256, 64, "float32", 1, True, False),        # the ring hop: two passes
+    (1024, 64, "bfloat16", 1, False, "fused"),      # gpt2m_train
+    (8192, 128, "bfloat16", 1, False, "fused"),     # phi4mf: exactly 8 MiB
+    (2048, 256, "bfloat16", 2, False, "fused"),
+    (8192, 256, "bfloat16", 1, False, "raised"),    # glm47f_train_s8k
+    (16384, 128, "bfloat16", 1, False, "raised"),   # dQ of a head: 16 MiB
+    (8192, 64, "float32", 1, False, "raised"),      # 64 lanes pad to 128
+    (8192, 128, "bfloat16", 2, False, "raised"),    # two heads a step
+    (16384, 256, "bfloat16", 1, False, "raised"),   # twice the cell's dQ
+    (32768, 256, "bfloat16", 1, False, "two"),      # past the cap
+    (65536, 128, "float32", 1, False, "two"),
+    (256, 64, "float32", 1, True, "two"),           # the ring hop
+    (8192, 256, "bfloat16", 1, True, "two"),
 ])
 def test_flash_backward_form_follows_from_the_shapes(s, d, dtype, fold,
                                                      traced, want):
+    from distributedarrays_tpu.ops.pallas_attention import (
+        _FUSED_VMEM_CAP, _fused_backward, _fused_vmem_bytes)
+    fused, limit = _fused_backward(s, d, dtype, fold, traced)
+    assert fused is (want != "two")
+    if want != "raised":
+        assert limit is None       # no limit named: the parent's program
+    else:
+        need = _fused_vmem_bytes(s, d, d, 1024, 1024, dtype, dtype, dtype,
+                                 fold)
+        assert need < limit <= _FUSED_VMEM_CAP and limit % _MIB == 0
+
+
+def test_flash_backward_vmem_limit_at_the_latent_cell_shape():
+    # glm47f_train_s8k's backward, (8192, 20 heads, 256) bf16 with blocks
+    # of 1024: the resident dQ alone is 16 MiB (float32 scratch and the
+    # output block in two buffers), the blocks 8 more; the chip's compiler
+    # takes the kernel from 26 MiB up and refuses it at 24 (compiled for a
+    # described v5e:2x2, PR 36), so the reckoned limit has to clear that
+    from distributedarrays_tpu.ops.pallas_attention import (
+        _FUSED_VMEM_CAP, _fused_backward, _fused_vmem_bytes)
+    need = _fused_vmem_bytes(8192, 256, 256, 1024, 1024, "bfloat16",
+                             "bfloat16", "bfloat16", 1)
+    assert 26 * _MIB <= need <= 32 * _MIB
+    fused, limit = _fused_backward(8192, 256, "bfloat16", 1, False, 256,
+                                   1024, 1024, "bfloat16", "bfloat16")
+    assert fused and need < limit <= 40 * _MIB < _FUSED_VMEM_CAP
+    # float32 dK, dV (grouped heads) and smaller blocks move the need, and
+    # the limit with it
+    wide = _fused_backward(8192, 256, "bfloat16", 1, False, 256, 1024, 1024,
+                           "bfloat16", "float32")[1]
+    small = _fused_backward(8192, 256, "bfloat16", 1, False, 256, 512, 512,
+                            "bfloat16", "bfloat16")[1]
+    assert small < limit < wide
+
+
+def _backward_jaxpr(q, k, v, **kw):
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu.ops import pallas_attention as PA
+    PA._build_bwd.cache_clear()
+    return str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(PA.flash_attention(q, k, v, causal=True, **kw)
+                                .astype(jnp.float32)), (0, 1, 2)))(q, k, v))
+
+
+@pytest.mark.parametrize("cell", ["gpt2m_train", "phi4mf_full",
+                                  "phi4mf_window"])
+def test_flash_backward_programs_of_the_fused_cells_name_no_limit(cell):
+    # what was one sweep under the default limit keeps its pallas_call to
+    # the letter: no compiler_params, so neither cell's program changes
+    import jax
+    import jax.numpy as jnp
+    sds = lambda s, h, d: jax.ShapeDtypeStruct((s, h, d), jnp.bfloat16)
+    if cell == "gpt2m_train":
+        txt = _backward_jaxpr(*[sds(1024, 128, 64)] * 3)
+    else:
+        txt = _backward_jaxpr(sds(8192, 40, 64), sds(8192, 20, 64),
+                              sds(8192, 10, 128),
+                              window=512 if cell == "phi4mf_window" else None)
+    assert "flash_bwd_dkv" in txt and "flash_bwd_dq" not in txt
+    assert "vmem_limit_bytes" not in txt
+    assert txt.count("compiler_params=FrozenDict({})") == 2   # fwd, bwd
+
+
+def test_flash_backward_at_the_latent_cell_shape_is_one_sweep():
+    import jax
+    import jax.numpy as jnp
     from distributedarrays_tpu.ops.pallas_attention import _fused_backward
-    assert _fused_backward(s, d, dtype, fold, traced) is want
+    q = jax.ShapeDtypeStruct((8192, 20, 256), jnp.bfloat16)
+    txt = _backward_jaxpr(q, q, q)
+    limit = _fused_backward(8192, 256, "bfloat16", 1, False)[1]
+    assert "flash_bwd_dkv" in txt and "flash_bwd_dq" not in txt
+    assert f"vmem_limit_bytes={limit}" in txt
+    # lse and D reach the one kernel as rows: nothing replicated over lanes
+    assert "f32[20,8192,128]" not in txt
+
+
+def test_flash_plan_gauge_says_the_raised_limit():
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu import telemetry as tm
+    from distributedarrays_tpu.ops.pallas_attention import _fused_backward
+
+    def read(kernel, s, d, what):
+        return tm.gauge_value("pallas.flash_attention.plan", kernel=kernel,
+                              s=s, d=d, causal=True, what=what)
+
+    q = jax.ShapeDtypeStruct((8192, 20, 256), jnp.bfloat16)
+    _backward_jaxpr(q, q, q)
+    assert read("flash_bwd_dkv", 8192, 256, "vmem_limit") == _fused_backward(
+        8192, 256, "bfloat16", 1, False)[1]
+    assert read("flash_bwd_dkv", 8192, 256, "bq") == 1024
+    # one sweep: no second program was planned
+    assert read("flash_bwd_dq", 8192, 256, "bq") is None
+    # under the default limit the gauge's keys are what they were
+    q = jax.ShapeDtypeStruct((1024, 128, 64), jnp.bfloat16)
+    _backward_jaxpr(q, q, q)
+    assert read("flash_bwd_dkv", 1024, 64, "bq") == 1024
+    assert read("flash_bwd_dkv", 1024, 64, "vmem_limit") is None
 
 
 def test_flash_default_blocks_and_fold_follow_from_the_shapes():
