@@ -51,11 +51,17 @@ from ..ops.pallas_attention import flash_attention
 from .moe import held_experts_ffn, route_sigmoid_topk
 from .transformer import blocked_nll, optax_f32_step
 
-__all__ = ["Config", "KINDS", "published_layers", "rope", "init_params",
-           "forward", "loss_parts", "loss_fn", "routing_stats",
-           "make_optax_train_step"]
+__all__ = ["Config", "KINDS", "SCOPES", "published_layers", "rope",
+           "init_params", "forward", "loss_parts", "loss_fn",
+           "routing_stats", "make_optax_train_step"]
 
 KINDS = ("dense", "moe")
+# the phases the ``jax.named_scope``s here and in ``moe.held_experts_ffn``
+# declare, as they nest (for the compiled step's phase map,
+# ``telemetry/programs.py``); ``mtp`` holds a block and a ``head_loss``
+SCOPES = ("embed", "block/mla", "block/mlp", "block/moe/route",
+          "block/moe/experts", "block/moe/shared", "mtp", "head_loss",
+          "optimizer")
 # What the recomputed FFN half of a layer keeps of its forward: the
 # up-projection of the dense FFN and of the shared expert (0.6 GB in all at
 # the benchmark's size) and the results of the routed experts' two grouped
@@ -389,4 +395,4 @@ def make_optax_train_step(cfg: Config, tx):
         (_, parts), g = jax.value_and_grad(f, has_aux=True)(params)
         return parts, g
 
-    return optax_f32_step(tx, grad_fn)
+    return optax_f32_step(tx, grad_fn, SCOPES)

@@ -48,10 +48,14 @@ from ..ops.pallas_attention import flash_attention
 from ..ops.pallas_selective_scan import selective_scan
 from .transformer import blocked_nll, optax_f32_step
 
-__all__ = ["Config", "KINDS", "layer_kinds", "lambda_init", "init_params",
-           "forward", "loss_fn", "make_optax_train_step"]
+__all__ = ["Config", "KINDS", "SCOPES", "layer_kinds", "lambda_init",
+           "init_params", "forward", "loss_fn", "make_optax_train_step"]
 
 KINDS = ("mamba", "window", "full", "gmu", "cross")
+# the phases the ``jax.named_scope``s below declare, as they nest (for the
+# compiled step's phase map, ``telemetry/programs.py``)
+SCOPES = ("embed", *(f"block/{k}" for k in KINDS), "block/mlp", "head_loss",
+          "optimizer")
 # What a recomputed layer keeps of its forward: the MLP's up-projection and
 # the mixer's input projection.  On the chip at the benchmark's size (PERF.md,
 # PR 33) a step read 398.6 ms with nothing kept, 370.4 with the first, 362.2
@@ -378,4 +382,4 @@ def make_optax_train_step(cfg: Config, tx):
     def grad_fn(params, tokens):
         return jax.value_and_grad(loss_fn)(params, tokens, cfg)
 
-    return optax_f32_step(tx, grad_fn)
+    return optax_f32_step(tx, grad_fn, SCOPES)

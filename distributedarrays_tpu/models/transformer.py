@@ -29,11 +29,16 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.pallas_attention import flash_attention
+from ..telemetry import programs
 from .mlp import make_mesh
 
 __all__ = ["init_params", "forward", "loss_fn", "train_step",
            "make_optax_train_step", "optax_f32_step", "blocked_nll",
-           "generate", "shard_params", "make_mesh", "Config"]
+           "generate", "shard_params", "make_mesh", "Config", "SCOPES"]
+
+# the phases this module's ``jax.named_scope``s declare, as they nest:
+# what ``telemetry.programs`` places the compiled step's instructions by
+SCOPES = ("embed", "block/attn", "block/mlp", "head_loss", "optimizer")
 
 
 class Config:
@@ -306,13 +311,16 @@ def train_step(params, tokens, lr, cfg: Config):
     return new, loss
 
 
-def _optax_f32_step(tx, grad_fn):
+def _optax_f32_step(tx, grad_fn, scopes=("optimizer",)):
     """Shared optax step with fp32 master arithmetic: bf16 params/grads
     upcast before ``tx.update`` + ``apply_updates`` and downcast after —
     at bf16 resolution (~8 mantissa bits) Adam-scale updates against
     O(0.1) weights would otherwise round to zero and training silently
     stalls.  State must be initialized from fp32 params (use the
-    returned ``init``)."""
+    returned ``init``).  The step is the registered program
+    ``train.optax_step`` (``telemetry/programs.py``): the jitted function
+    run inside a span of that name, with ``scopes``, the phases the
+    model's ``jax.named_scope``s declare, for its phase map."""
     import optax
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -329,7 +337,7 @@ def _optax_f32_step(tx, grad_fn):
     def init(params):
         return _optax_f32_init(tx, params)
 
-    return step, init
+    return programs.register("train.optax_step", step, scopes), init
 
 
 # the float32-master step under its public name: what every model of the
@@ -359,4 +367,4 @@ def make_optax_train_step(cfg: Config, tx):
     def grad_fn(params, tokens):
         return jax.value_and_grad(loss_fn)(params, tokens, cfg)
 
-    return _optax_f32_step(tx, grad_fn)
+    return _optax_f32_step(tx, grad_fn, SCOPES)

@@ -53,6 +53,7 @@ from . import cluster
 from . import alerts
 from . import stream
 from . import agg
+from . import programs
 from .memory import leak_census
 from .flight import postmortem, record_crash
 from .cluster import merge_journals, reconstruct_incidents
@@ -71,6 +72,7 @@ __all__ = [
     "current_trace_ids", "bind_trace_ids", "record_external_span",
     "to_perfetto", "to_prometheus",
     "memory", "flight", "tracing", "cluster", "alerts", "stream", "agg",
+    "programs",
     "leak_census", "postmortem", "record_crash",
     "merge_journals", "reconstruct_incidents",
     "AlertRule", "AlertManager", "default_rules",

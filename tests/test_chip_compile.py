@@ -34,6 +34,7 @@ from distributedarrays_tpu.ops import pallas_attention as PA
 from distributedarrays_tpu.ops import pallas_collectives as PC
 from distributedarrays_tpu.ops import pallas_gemm as PG
 from distributedarrays_tpu.ops import pallas_stencil as PS
+from distributedarrays_tpu.telemetry import programs
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -65,6 +66,22 @@ def on_tpu(monkeypatch):
     """Steer every module's platform test to the chip's branch."""
     for mod in (PG, PA, PC, PS):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+
+
+def _placed(step, text):
+    """({kernel: (phase, pass)}, {fusion: (phase, pass)}) of a registered
+    step: every ``tpu_custom_call`` and every fusion instruction of its
+    compiled text must have an entry in its phase map."""
+    pmap = programs.phase_map(step)
+    kernels = re.findall(
+        r"^\s+(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+        text, re.M)
+    fusions = re.findall(r"^\s+(?:ROOT )?%(\S+) = .*? fusion\(", text, re.M)
+    assert kernels and len(fusions) > 100
+    missing = [n for n in kernels + fusions if n not in pmap]
+    assert not missing, missing[:5]
+    return ({n: pmap[n][1:] for n in kernels},
+            {n: pmap[n][1:] for n in fusions})
 
 
 def _compiled_text(fn, *shapes):
@@ -411,11 +428,22 @@ def test_transformer_train_step_full_width(one_chip, on_tpu):
     params = jax.tree_util.tree_map(on, shapes)
     tokens = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
     lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-    compiled = T.train_step.lower(params, tokens, lr, cfg).compile()
+    # compiled through the registry (telemetry/programs.py), which then
+    # says what the program needs and where each instruction belongs
+    step = programs.register("train.sgd_step", T.train_step, T.SCOPES)
+    step.note(params, tokens, lr, cfg)
+    compiled = programs.compiled(step)
     # 8 layers x (flash forward + the one-sweep backward)
     assert compiled.as_text().count("tpu_custom_call") >= 16
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 14 * 2**30
+    mem = programs.memory(step)
+    assert mem["temp"] + mem["argument"] < 14 * 2**30
+    kernels, fusions = _placed(step, compiled.as_text())
+    assert set(kernels.values()) == {("block/attn", "forward"),
+                                     ("block/attn", "backward")}
+    # most fusions lie under a scope of the model's; the SGD update has
+    # none of its own, and XLA gives some fusions no op_name at all
+    assert sum(v[0] is not None for v in fusions.values()) \
+        > 0.6 * len(fusions)
 
 
 def test_mla_moe_train_step_at_the_benchmark_size(one_chip, monkeypatch):
@@ -439,12 +467,32 @@ def test_mla_moe_train_step_at_the_benchmark_size(one_chip, monkeypatch):
     params = jax.tree_util.tree_map(on, shapes)
     state = jax.tree_util.tree_map(on, jax.eval_shape(init, shapes))
     tokens = jax.ShapeDtypeStruct((1, 8194), jnp.int32, sharding=one_chip)
-    compiled = step.lower(params, state, tokens).compile()
-    mem = compiled.memory_analysis()
+    step.note(params, state, tokens)
+    compiled = programs.compiled(step)
+    mem = programs.memory(step)
     print(f"mla_moe step for v5e:2x2: arguments "
-          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, scratch "
-          f"{mem.temp_size_in_bytes / 1e9:.2f} GB; {mem}")
+          f"{mem['argument'] / 1e9:.2f} GB, scratch "
+          f"{mem['temp'] / 1e9:.2f} GB, in all {mem['total'] / 1e9:.2f} GB; "
+          f"{mem}")
+    assert mem["total"] == (mem["argument"] + mem["output"] - mem["alias"]
+                            + mem["temp"] + mem["generated_code"])
     txt = compiled.as_text()
+    kernels, fusions = _placed(step, txt)
+    # the flash kernels lie under block/mla (the MTP block's among them);
+    # libtpu's grouped products carry its own op_name and no scope
+    flash = {k: v for k, v in kernels.items() if k.startswith("flash")}
+    assert len(flash) == 12
+    assert set(flash.values()) == {("block/mla", "forward"),
+                                   ("block/mla", "backward")}
+    assert {v for k, v in kernels.items() if not k.startswith("flash")} \
+        <= {(None, "forward")}
+    # a third of the fusions carry no op_name at all (multi-output ones
+    # whose root is a tuple among them: 1.7% of the step's device time)
+    assert sum(v[0] is not None for v in fusions.values()) \
+        > 0.6 * len(fusions)
+    # the FFN half is computed again in the backward, attention is not
+    again = {v[0] for v in fusions.values() if v[1] == "recompute"}
+    assert "block/moe/experts" in again and "block/mla" not in again
     count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", txt))
     # six attention layers: a forward and a backward of one sweep each
     # (the fused kernel names the VMEM its resident dQ needs, PR 36)
@@ -454,4 +502,4 @@ def test_mla_moe_train_step_at_the_benchmark_size(one_chip, monkeypatch):
     # none computed again (the recomputed FFN half keeps their results)
     print("grouped products:", count("ragged-dot-none"))
     assert count("ragged-dot-none") == 30
-    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 14 * 2**30
+    assert mem["temp"] + mem["argument"] < 14 * 2**30

@@ -131,6 +131,60 @@ def test_mla_moe_step_carries_its_scopes_and_kernel_names(scope):
 _STEP_TEXT = {}
 
 
+# ---------------------------------------------------------------------------
+# the registered step: its span, and each model's declared scopes
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("module", ["transformer", "sambay", "mla_moe"])
+def test_declared_scopes_are_held_to_the_docs(module):
+    import importlib
+    mod = importlib.import_module(f"distributedarrays_tpu.models.{module}")
+    doc = (REPO / "docs" / "telemetry.md").read_text()
+    section = doc.split(
+        "### Compiled programs and device time by phase", 1)[1].split(
+            "\n### ", 1)[0]
+    listed = section.split(f"`{module}`", 1)[1].split(";", 1)[0]
+    assert re.findall(r"`([a-z_/]+)`", listed) == list(mod.SCOPES)
+    # the scopes are the ones the module's own named_scopes nest to
+    source = Path(mod.__file__).read_text()
+    for leaf in {s.rsplit("/", 1)[-1] for s in mod.SCOPES} - {
+            "route", "experts", "optimizer", *getattr(mod, "KINDS", ())}:
+        assert f'named_scope("{leaf}")' in source, leaf
+
+
+def test_optax_step_opens_the_documented_span(monkeypatch):
+    import optax
+    from distributedarrays_tpu.models import transformer as T
+    made = []
+
+    class Spy:
+        is_enabled = staticmethod(lambda: True)
+
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    cfg = T.Config(vocab=64, dim=32, heads=2, layers=1, max_seq=16)
+    step, init = T.make_optax_train_step(cfg, optax.adamw(1e-3))
+    params = T.init_params(jax.random.key(0), cfg)
+    state, tokens = init(params), jnp.zeros((2, 17), jnp.int32)
+    params, state, _ = step(params, state, tokens)     # traced outside
+    monkeypatch.setattr(tracing, "_TraceAnnotation", Spy)
+    step(params, state, tokens)
+    assert made == [tracing.ANNOTATION_PREFIX + step.name]
+    doc = (REPO / "docs" / "telemetry.md").read_text()
+    table = doc.split("| Span in the trace | Opened by |", 1)[1].split(
+        "\n\n", 1)[0]
+    assert f"| `{made[0]}` |" in table
+    assert made[0] == "dat.train.optax_step"
+
+
 def _sambay_step_text(cfg):
     if cfg not in _STEP_TEXT:
         import optax
