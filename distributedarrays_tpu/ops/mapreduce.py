@@ -42,10 +42,103 @@ __all__ = [
 ]
 
 
+# a deviation's shift is the mean of a corner of the array: this many
+# leading elements of the last reduced axis, and of every other reduced one
+_SAMPLE_LAST, _SAMPLE_OTHER = 128, 8
+# the deviation's second pass runs where (s1/n)^2 > _RESHIFT * variance
+_RESHIFT = 1.0
+
+
+def _moments(a, axis=None, keepdims=False, ddof=0, *, mapper=None, root):
+    """Variance (``root``: its square root) of ``mapper(a)`` over ``axis``
+    in ONE read of ``a``: with ``d = mapper(a) - k``, ``s1 = sum(d)`` and
+    ``s2 = sum(d * d)`` come out of one fused pass and the variance is
+    ``max(s2 - s1^2/n, 0) / (n - ddof)``, finished on scalars (on one value
+    a kept slice with ``axis``).  The shift ``k`` is the mean of a corner
+    sample of each reduced slice (a slice, never a reshape: a sharded array
+    is not gathered), so the subtraction cancels nothing that matters
+    while the corner is like the rest.  Where it is not, which the sums
+    themselves say (``(s1/n)^2 > _RESHIFT * (s2/n - (s1/n)^2)``: the shift
+    lies further from the mean than one deviation), a ``lax.cond`` on that
+    scalar makes the same pass again about the exact mean ``k + s1/n``:
+    the two reads ``jnp.var`` always takes.  Accumulation is in the type
+    ``jnp.var`` computes in (float32 for float32, bfloat16 and float16;
+    float64 for float64) and the result has the type it returns.
+
+    Real floating input only: integer, boolean and complex input keep
+    ``jnp.var`` / ``jnp.std`` (numpy's promotion and ``|x - mean|^2``),
+    decided from the mapped dtype when the program is traced;
+    ``jit.builds{fn=reduction_moments, form=moments|numpy}`` counts which.
+    """
+    m = a if mapper is None else mapper(a)
+    if not jnp.issubdtype(m.dtype, jnp.floating):
+        _tm.count("jit.builds", fn="reduction_moments", form="numpy")
+        return (jnp.std if root else jnp.var)(m, axis=axis, keepdims=keepdims,
+                                              ddof=ddof)
+    _tm.count("jit.builds", fn="reduction_moments", form="moments")
+    acc = jnp.promote_types(m.dtype, jnp.float32)
+    axes = tuple(range(m.ndim)) if axis is None else axis
+    n = int(np.prod([m.shape[i] for i in axes]))
+
+    def sums(x, shift):
+        d = x.astype(acc) - shift
+        return (jnp.sum(d, axis=axes, keepdims=True),
+                jnp.sum(d * d, axis=axes, keepdims=True))
+
+    def cut(x):
+        # one axis at a time, each cut behind a barrier: the partitioner of
+        # a sharded array then moves a few rows, where one cut of two axes
+        # moved whole columns of a shard
+        for i in axes:
+            x = jax.lax.optimization_barrier(jax.lax.slice_in_dim(
+                x, 0, min(x.shape[i], _SAMPLE_LAST if i == axes[-1]
+                          else _SAMPLE_OTHER), axis=i))
+        return x
+
+    # the corner of `a`, mapped, where the mapper can take it: cut from the
+    # mapped array, XLA keeps a mapped copy of all of `a` for the cut's
+    # sake.  Any shift is a right one, so a mapper that is not elementwise
+    # only makes it a poorer one; one that cannot take the corner (it holds
+    # an array of the whole shape, or gives another shape or type) has its
+    # own result cut
+    corner = cut(m)
+    if mapper is not None and m.shape == a.shape:
+        try:
+            mapped = mapper(cut(a))
+            if (mapped.shape, mapped.dtype) == (corner.shape, m.dtype):
+                corner = mapped
+        except Exception:  # noqa: BLE001 — whatever it is, cut(m) stands
+            pass
+    corner = corner.astype(acc)
+    # about its own first element, so that a constant array's shift is that
+    # constant to the last bit and its deviation exactly 0
+    first = corner[tuple(slice(1) if i in axes else slice(None)
+                         for i in range(m.ndim))]
+    k = first + jnp.mean(corner - first, axis=axes, keepdims=True)
+    s1, s2 = sums(m, k)
+
+    def again():
+        # mapped anew inside the branch: the conditional then takes `a` by
+        # reference and no mapped copy of it is kept for the branch's sake
+        return sums(a if mapper is None else mapper(a), k + s1 / n)
+
+    unlike = jnp.any(s1 * s1 / n > _RESHIFT * (s2 - s1 * s1 / n))
+    s1, s2 = jax.lax.cond(unlike, again, lambda: (s1, s2))
+    var = jnp.maximum(s2 - s1 * s1 / n, 0) / (n - ddof)
+    var = jnp.where(n - ddof > 0, var, jnp.nan)
+    if not keepdims:
+        var = jnp.squeeze(var, axes)
+    return (jnp.sqrt(var) if root else var).astype(m.dtype)
+
+
+_var = functools.partial(_moments, root=False)
+_std = functools.partial(_moments, root=True)
+
+
 _REDUCERS = {
     "sum": jnp.sum, "prod": jnp.prod, "max": jnp.max, "min": jnp.min,
-    "all": jnp.all, "any": jnp.any, "mean": jnp.mean, "std": jnp.std,
-    "var": jnp.var,
+    "all": jnp.all, "any": jnp.any, "mean": jnp.mean, "std": _std,
+    "var": _var,
 }
 
 
@@ -82,6 +175,9 @@ def _reduction_jit(mapper, reducer, axes, kw_items):
     kw = dict(kw_items)
 
     def fn(a):
+        if reducer in (_std, _var):     # these map for themselves
+            return reducer(a, axis=axes, keepdims=axes is not None,
+                           mapper=mapper, **kw)
         m = mapper(a) if mapper is not None else a
         if axes is None:
             return reducer(m, **kw)
@@ -266,15 +362,26 @@ dany = _named("any")
 
 
 def dvar(d, dims=None, ddof=1):
-    """Corrected (ddof=1) variance, matching Julia's Statistics.var default."""
-    return _reduce_entry(d, None, jnp.var, dims=dims, ddof=ddof)
+    """Corrected (ddof=1) variance, matching Julia's Statistics.var default,
+    in one read of the array: shifted sums accumulated in float32 (float64
+    for float64) and finished on scalars, a second read only where the
+    corner the shift is taken from is unlike the rest (``_moments``).
+    Integer, boolean and complex input keep ``jnp.var``."""
+    return _reduce_entry(d, None, _var, dims=dims, ddof=ddof)
 
 
 def dstd(d, dims=None, ddof=1):
     """Sample std, matching Julia's Statistics.std default (corrected);
-    reference ext/StatisticsExt.jl:6 builds mean from sum — here it is one
-    fused reduction."""
-    return _reduce_entry(d, None, jnp.std, dims=dims, ddof=ddof)
+    reference ext/StatisticsExt.jl:6 builds mean from sum and reads the
+    array again for the deviations.  Here it is one read: with ``k`` the
+    mean of a corner of the array, ``sum(a - k)`` and ``sum((a - k)^2)``
+    come out of one fused pass in float32 (float64 for float64) and the
+    deviation is finished on scalars; only where that corner is unlike the
+    rest (the shift lies further from the mean than one deviation) does a
+    conditional on the device read the array a second time, about the
+    exact mean (``_moments``).  Integer, boolean and complex input keep
+    ``jnp.std``."""
+    return _reduce_entry(d, None, _std, dims=dims, ddof=ddof)
 
 
 def dcount(pred, d, dims=None):

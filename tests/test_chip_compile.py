@@ -245,6 +245,108 @@ def test_flash_kernels_at_the_latent_cell_shape(one_chip):
     assert "f32[20,8192,128]" not in txt
 
 
+def _computations(text):
+    """{name: [instruction lines]} of a compiled module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line.strip())
+    return comps
+
+
+def _readers_of_the_whole(text, shape):
+    """The instructions outside a conditional's branches that read a whole
+    ``shape`` operand: not the plumbing that hands it on by reference, and
+    not a ``slice`` (bare, or the only use a fusion makes of it)."""
+    comps = _computations(text)
+    called = {n: set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)",
+                                " ".join(ls))) for n, ls in comps.items()}
+    inside = set()
+    for names in re.findall(r"branch_computations=\{([^}]*)\}", text):
+        todo = [n.strip().lstrip("%") for n in names.split(",")]
+        while todo:
+            n = todo.pop()
+            if n not in inside:
+                inside.add(n)
+                todo.extend(called.get(n, ()))
+    fused = set().union(*called.values())
+    head = re.compile(r"^(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)")
+
+    def slices_only(comp, index):
+        lines = comps[comp]
+        param = next(head.match(l).group(1) for l in lines
+                     if f" parameter({index})" in l)
+        users = [head.match(l) for l in lines
+                 if re.search(rf"%{re.escape(param)}\b", l.split(" = ", 1)[1])]
+        return users and all(u.group(3) == "slice" for u in users)
+
+    readers = []
+    for name, lines in comps.items():
+        if name in inside or name in fused:
+            continue
+        whole = {head.match(l).group(1) for l in lines
+                 if head.match(l) and head.match(l).group(2).startswith(shape)}
+        for l in lines:
+            m = head.match(l)
+            if not m or m.group(3) in ("tuple", "get-tuple-element", "bitcast",
+                                       "conditional", "slice", "parameter"):
+                continue
+            ops = [o.strip().split(" ")[-1].lstrip("%")
+                   for o in m.group(4).split(",")]
+            for i, o in enumerate(ops):
+                if o in whole and not (
+                        m.group(3) == "fusion" and slices_only(
+                            re.search(r"calls=%([\w.\-]+)", l).group(1), i)):
+                    readers.append(l)
+    return readers
+
+
+@pytest.mark.parametrize("cell", ["stream_one_chip", "stream_mapped",
+                                  "reshard_2x2"])
+def test_deviation_reads_the_array_once_at_the_cell_shapes(topo, one_chip,
+                                                            cell):
+    """``dstd`` as the chip's compiler writes it: ONE instruction over the
+    whole array outside the second-pass branch (the fusion that yields both
+    shifted sums, a mapper inside it), the conditional taking the array
+    by reference (no ``copy`` of its shape, temporaries of kilobytes), and
+    on a (2,2) mesh no ``all-gather`` and only scalar ``all-reduce``s."""
+    from distributedarrays_tpu.ops import mapreduce as MR
+    if cell.startswith("stream"):
+        local, x = (20480, 20480), jax.ShapeDtypeStruct(
+            (20480, 20480), jnp.float32, sharding=one_chip)
+    else:
+        mesh = Mesh(np.asarray(topo.devices, dtype=object).reshape(2, 2),
+                    ("d0", "d1"))
+        local, x = (16384, 24576), jax.ShapeDtypeStruct(
+            (32768, 49152), jnp.float32,
+            sharding=NamedSharding(mesh, P("d0", "d1")))
+    mapper = jnp.square if cell == "stream_mapped" else None
+    compiled = MR._reduction_jit(mapper, MR._std, None,
+                                 (("ddof", 1),)).lower(x).compile()
+    text = compiled.as_text()
+    shape = f"f32[{local[0]},{local[1]}]"
+    readers = _readers_of_the_whole(text, shape)
+    assert len(readers) == 1, readers
+    assert re.match(r"%\S+ = \(f32\[\]\S*, f32\[\]\S*\) fusion\(",
+                    readers[0]), readers[0]
+    assert len(re.findall(r" conditional\(", text)) == 1
+    assert not re.findall(rf"= {re.escape(shape)}\S* copy(?:-start)?\(",
+                          text)
+    assert "all-gather" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    for shapes in re.findall(r" = (\(.*?\)|\S+) all-reduce(?:-start)?\(",
+                             text):
+        for dims in re.findall(r"\[([\d,]*)\]", shapes):
+            assert int(np.prod([int(n) for n in dims.split(",") if n])) == 1
+    print(f"{cell}: temp {compiled.memory_analysis().temp_size_in_bytes} "
+          f"bytes; the reader: {readers[0][:120]}")
+
+
 def test_stencil5_block_8192(one_chip):
     x = jax.ShapeDtypeStruct((8192, 8192), jnp.float32, sharding=one_chip)
     h = jax.ShapeDtypeStruct((1, 8192), jnp.float32, sharding=one_chip)
