@@ -29,6 +29,8 @@ MODULES = [
     "distributedarrays_tpu.ops.conv",
     "distributedarrays_tpu.ops.pallas_gemm",
     "distributedarrays_tpu.ops.pallas_attention",
+    "distributedarrays_tpu.ops.pallas_selective_scan",
+    "distributedarrays_tpu.ops.pallas_ssd",
     "distributedarrays_tpu.ops.pallas_stencil",
     "distributedarrays_tpu.ops.pallas_collectives",
     "distributedarrays_tpu.ops.ring_schedules",
@@ -47,6 +49,8 @@ MODULES = [
     "distributedarrays_tpu.models.mlp",
     "distributedarrays_tpu.models.transformer",
     "distributedarrays_tpu.models.sp_transformer",
+    "distributedarrays_tpu.models.sambay",
+    "distributedarrays_tpu.models.mamba2_hybrid",
     "distributedarrays_tpu.train.trainer",
     "distributedarrays_tpu.train.optim",
     "distributedarrays_tpu.train.tasks",

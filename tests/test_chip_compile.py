@@ -222,6 +222,27 @@ def test_selective_scan_kernels_at_the_hybrid_cell_shapes(one_chip):
         assert name in txt, name
 
 
+def test_ssd_kernels_at_the_granite_cell_shapes(one_chip):
+    # what granite4h_train_s8k calls: 64 heads of 64, one group of B and C
+    # with 128 states, 8192 positions in chunks of 256; both kernels name
+    # the VMEM limit their plan reckons
+    from distributedarrays_tpu.ops import pallas_ssd as SSD
+    sds = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(s, d,
+                                                        sharding=one_chip)
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(SSD.ssd(x, dt, a, b, c, interpret=False))
+
+    txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                         sds((8192, 64, 64), jnp.bfloat16), sds((8192, 64)),
+                         sds((64,)), sds((8192, 1, 128), jnp.bfloat16),
+                         sds((8192, 1, 128), jnp.bfloat16))
+    for name in ("ssd_fwd", "ssd_bwd"):
+        assert name in txt, name
+    limit = SSD.ssd_plan(8192, 64, 64, 1, 128)["vmem_bytes"]
+    assert txt.count(f'"size":"{limit}"') >= 2
+
+
 def test_flash_kernels_at_the_latent_cell_shape(one_chip):
     # what glm47f_train_s8k calls: 20 heads of 256 (192 + 64 rotary) on
     # values of 256, 8192 positions; a head's dQ (16 MiB resident) fits
@@ -605,3 +626,54 @@ def test_mla_moe_train_step_at_the_benchmark_size(one_chip, monkeypatch):
     print("grouped products:", count("ragged-dot-none"))
     assert count("ragged-dot-none") == 30
     assert mem["temp"] + mem["argument"] < 14 * 2**30
+
+
+def test_mamba2_hybrid_train_step_at_the_benchmark_size(one_chip, monkeypatch):
+    # the step granite4h_train_s8k times, at its size: published widths,
+    # layers 0..9 (nine Mamba-2, attention at 5), 12544 vocabulary rows,
+    # one row of 8193 ids, AdamW
+    import optax
+    from distributedarrays_tpu.models import mamba2_hybrid as M
+    from distributedarrays_tpu.ops import pallas_ssd as SSD
+    monkeypatch.setattr(PA, "_on_tpu", lambda: True)
+    monkeypatch.setattr(SSD, "_on_tpu", lambda: True)
+    layers = tuple((i, "attention" if i == 5 else "mamba") for i in range(10))
+    cfg = M.Config(vocab=12544, dim=2048, ffn=8192, heads=32, kv_heads=8,
+                   head_dim=64, ssm_heads=64, ssm_head_dim=64, d_state=128,
+                   n_groups=1, chunk=256, layers=layers,
+                   attention_mult=0.015625)
+    step, init = M.make_optax_train_step(
+        cfg, optax.adamw(1e-3, weight_decay=0.1))
+    on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    shapes = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(shapes)) \
+        == 772_160_448
+    params = jax.tree_util.tree_map(on, shapes)
+    state = jax.tree_util.tree_map(on, jax.eval_shape(init, shapes))
+    tokens = jax.ShapeDtypeStruct((1, 8193), jnp.int32, sharding=one_chip)
+    step.note(params, state, tokens)
+    compiled = programs.compiled(step)
+    mem = programs.memory(step)
+    print(f"mamba2_hybrid step for v5e:2x2: arguments "
+          f"{mem['argument'] / 1e9:.2f} GB, scratch "
+          f"{mem['temp'] / 1e9:.2f} GB, in all {mem['total'] / 1e9:.2f} GB; "
+          f"{mem}")
+    # under the 14.5 GB the cell may need of the chip's 16
+    assert mem["total"] < 14.5e9
+    txt = compiled.as_text()
+    kernels, fusions = _placed(step, txt)
+    count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", txt))
+    # nine Mamba-2 layers: the forward kernel twice (recomputed), the
+    # backward once; one attention layer likewise
+    assert count("ssd_fwd") == 18 and count("ssd_bwd") == 9
+    assert count("flash_fwd") == 2 and count("flash_bwd_dkv") == 1
+    placed = {k.split(".")[0]: set() for k in kernels}
+    for k, v in kernels.items():
+        placed[k.split(".")[0]].add(v)
+    assert placed["ssd_fwd"] == {("block/mamba", "forward"),
+                                 ("block/mamba", "recompute")}
+    assert placed["ssd_bwd"] == {("block/mamba", "backward")}
+    assert placed["flash_fwd"] == {("block/attn", "forward"),
+                                   ("block/attn", "recompute")}
+    assert sum(v[0] is not None for v in fusions.values()) \
+        > 0.6 * len(fusions)

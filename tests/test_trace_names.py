@@ -71,6 +71,8 @@ def test_spelling_the_benchmark_readers_rely_on():
         "ring_all_gather", "ring_all_to_all", "ring_reduce_scatter"}
     assert {n for n in names if n.startswith("selective_scan_")} == {
         "selective_scan_fwd", "selective_scan_bwd"}
+    assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd",
+                                                          "ssd_bwd"}
 
 
 @pytest.mark.parametrize("scope", [
@@ -136,7 +138,8 @@ _STEP_TEXT = {}
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("module", ["transformer", "sambay", "mla_moe"])
+@pytest.mark.parametrize("module", ["transformer", "sambay", "mla_moe",
+                                    "mamba2_hybrid"])
 def test_declared_scopes_are_held_to_the_docs(module):
     import importlib
     mod = importlib.import_module(f"distributedarrays_tpu.models.{module}")
