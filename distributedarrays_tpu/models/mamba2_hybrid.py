@@ -223,11 +223,11 @@ def _attention(u, p, cfg):
     H, KV, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
     q, k, v = jnp.split(checkpoint_name(u @ p["wqkv"], "mix_in"),
                         [H * hd, (H + KV) * hd], axis=-1)
-    o = flash_attention(_fold(q.reshape(B, S, H, hd)),
-                        _fold(k.reshape(B, S, KV, hd)),
-                        _fold(v.reshape(B, S, KV, hd)), causal=True,
+    # the kernels read (B, S, heads, hd) in place, two heads of 64 a block
+    o = flash_attention(q.reshape(B, S, H, hd), k.reshape(B, S, KV, hd),
+                        v.reshape(B, S, KV, hd), causal=True,
                         scale=cfg.attention_mult)
-    return _unfold(o, B).reshape(B, S, H * hd) @ p["wo"]
+    return o.reshape(B, S, H * hd) @ p["wo"]
 
 
 def _layer(x, p, *, kind, cfg):

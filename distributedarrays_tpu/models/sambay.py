@@ -267,14 +267,6 @@ def _gmu(u, p, m_star, cfg):
     return (m_star.astype(jnp.float32) * gate).astype(u.dtype) @ p["wo"]
 
 
-def _fold_heads(t):
-    """(B, S, H, W) -> (S, B * H, W): the batch folds into the head axis,
-    so one kernel call covers it (a group of heads never straddles two
-    rows of the batch)."""
-    B, S, H, W = t.shape
-    return jnp.transpose(t, (1, 0, 2, 3)).reshape(S, B * H, W)
-
-
 def _diff_attention(q, k, v, p, index, cfg, window):
     """Differential attention on q (B, S, heads, hd) in the published head
     order and k (B, S, kv_heads, hd), v (B, S, kv_heads / 2, 2 hd) as the
@@ -287,10 +279,8 @@ def _diff_attention(q, k, v, p, index, cfg, window):
     G = cfg.kv_heads // 2                        # K/V pairs
     r = H // 2 // G                              # query pairs a K/V pair
     qk = jnp.swapaxes(q.reshape(B, S, G, r, 2, hd), 3, 4).reshape(B, S, H, hd)
-    o = flash_attention(_fold_heads(qk), _fold_heads(k), _fold_heads(v),
-                        causal=True, window=window)          # (S, B*H, 2hd)
-    o = jnp.transpose(o.reshape(S, B, G, 2, r, 2 * hd), (1, 0, 2, 3, 4, 5))
-    o = o.astype(jnp.float32)
+    o = flash_attention(qk, k, v, causal=True, window=window)  # (B,S,H,2hd)
+    o = o.reshape(B, S, G, 2, r, 2 * hd).astype(jnp.float32)
     o1 = o[:, :, :, 0].reshape(B, S, H // 2, 2 * hd)
     o2 = o[:, :, :, 1].reshape(B, S, H // 2, 2 * hd)
     f32 = lambda name: p[name].astype(jnp.float32)

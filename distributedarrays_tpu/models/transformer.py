@@ -2,8 +2,9 @@
 
 Composes the pieces this framework provides into one trainable model:
 
-- attention = the Pallas flash kernel (ops/pallas_attention.py) with the
-  batch dim folded into the head axis — one kernel call, no vmap, no
+- attention = the Pallas flash kernel (ops/pallas_attention.py), which
+  reads q, k, v and writes its result as the (B, S, heads·D) products lie
+  (the batch a grid axis) — one kernel call, no vmap, no layout copy, no
   O(S²) score matrix;
 - FFN and QKV/projection weights laid out Megatron-style over the ``tp``
   mesh axis (column-parallel up, row-parallel down) so GSPMD inserts the
@@ -127,8 +128,7 @@ def _rmsnorm(x, scale):
 def _attention(x, blk, heads):
     B, S, E = x.shape
     D = E // heads
-    qkv = x @ blk["qkv"]                                  # (B, S, 3E)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    qkv = x @ blk["qkv"]                     # (B, S, 3E): q's, k's, v's
 
     # pad the sequence to a healthy block multiple (tiny or odd S would
     # force degenerate flash blocks); padded KEYS sit at positions >= S so
@@ -139,22 +139,17 @@ def _attention(x, blk, heads):
     bs = next(b for b in (512, 256, 128, 64, 32)
               if b == 32 or (-(-S // b) * b - S) * 8 <= S)
     Spad = -(-S // bs) * bs
+    qkv = jnp.pad(qkv, ((0, 0), (0, Spad - S), (0, 0)))
 
-    def fold(t):
-        # (B, S, E) -> (Spad, B*heads, D): batch folds into the head axis
-        # so ONE flash-kernel call covers the whole batch (causality is
-        # per-head, so folding is exact)
-        t = jnp.pad(t, ((0, 0), (0, Spad - S), (0, 0)))
-        return jnp.transpose(t.reshape(B, Spad, heads, D),
-                             (1, 0, 2, 3)).reshape(Spad, B * heads, D)
-
-    # blocks, fold and the form of the backward are flash_attention's own
-    # choice from (Spad, D): the padding above only keeps Spad a multiple
+    # (B, S, 3E) viewed as (B, S, 3, heads, D): the kernels read q, k and v
+    # from the one product as it lies and write its gradient as one array
+    # (no slice or layout copy on either side); blocks, the heads a grid
+    # step takes and the form of the backward are flash_attention's own
+    # choice from the shapes: the padding above only keeps Spad a multiple
     # of what it will pick
-    o = flash_attention(fold(q), fold(k), fold(v), causal=True)
-    o = jnp.transpose(o.reshape(Spad, B, heads, D),
-                      (1, 0, 2, 3)).reshape(B, Spad, E)[:, :S]
-    return o @ blk["proj"]
+    o = flash_attention(qkv.reshape(B, Spad, 3, heads, D), None, None,
+                        causal=True)
+    return o.reshape(B, Spad, E)[:, :S] @ blk["proj"]
 
 
 def forward(params, tokens, cfg: Config):

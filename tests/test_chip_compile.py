@@ -243,6 +243,103 @@ def test_ssd_kernels_at_the_granite_cell_shapes(one_chip):
     assert txt.count(f'"size":"{limit}"') >= 2
 
 
+_INSTR = re.compile(r"^(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)")
+# instructions that hand a buffer on unchanged (a view, a tuple's part, a
+# move between memory spaces): what lies between two computations
+_PLUMBING = ("bitcast", "get-tuple-element", "copy-start", "copy-done")
+
+
+def _entry(text):
+    """{name: (opcode, operand names, line)} of the entry computation."""
+    name = re.search(r"^ENTRY %(\S+) ", text, re.M).group(1)
+    out = {}
+    for line in _computations(text)[name]:
+        m = _INSTR.match(line)
+        if m:
+            ops = [o.strip().split(" ")[-1].lstrip("%")
+                   for o in m.group(4).split(",") if o.strip()]
+            out[m.group(1)] = (m.group(3), ops, line)
+    return out
+
+
+def _is_product(comps, entry, name):
+    """Whether ``name`` is, past plumbing, a fusion that holds a product."""
+    while entry[name][0] in _PLUMBING:
+        name = entry[name][1][0]
+    op, _, line = entry[name]
+    called = re.search(r"calls=%([\w.\-]+)", line)
+    return op == "fusion" and any(
+        _INSTR.match(l) and _INSTR.match(l).group(3) in ("convolution", "dot")
+        for l in comps[called.group(1)])
+
+
+def _readers(entry, name):
+    """The instructions that read ``name``'s buffer, past plumbing."""
+    out, todo = [], [name]
+    while todo:
+        n = todo.pop()
+        for u, (op, ops, _) in entry.items():
+            if n in ops:
+                (todo if op in _PLUMBING else out).append(u)
+    return out
+
+
+def test_gpt2m_attention_block_reads_and_writes_in_place(one_chip, on_tpu):
+    # gpt2m_train's attention block, forward and backward, at (8, 1024, 16
+    # heads of 64): the q, k, v products feed the flash kernels and the
+    # kernels' results feed the output projection and the weight
+    # gradients as they lie; no copy or transpose anywhere in the program
+    # (copy-start/-done move a buffer between memory spaces, not layouts):
+    # one (B, S, 3E) product in, one (B, S, 3E) gradient out
+    from distributedarrays_tpu import telemetry as tm
+    B, S, H, D = 8, 1024, 16, 64
+    E = H * D
+    sd = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+
+    def block(x, blk, g):
+        y, vjp = jax.vjp(lambda x, blk: T._attention(x, blk, H), x, blk)
+        return y, vjp(g)
+
+    PA._build.cache_clear()
+    PA._build_bwd.cache_clear()
+    txt = _compiled_text(block, sd(B, S, E),
+                         {"qkv": sd(E, 3 * E), "proj": sd(E, E)}, sd(B, S, E))
+    comps, entry = _computations(txt), _entry(txt)
+    moved = [l for lines in comps.values() for l in lines
+             if _INSTR.match(l) and _INSTR.match(l).group(3) in (
+                 "copy", "transpose")]
+    assert not moved, moved[:3]
+    kernels = {n: v for n, v in entry.items() if n.startswith("flash_")}
+    assert sorted(n.split(".")[0] for n in kernels) == [
+        "flash_bwd_dkv", "flash_fwd"]
+    fwd = next(v for n, v in kernels.items() if n.startswith("flash_fwd"))
+    # q, k and v: the one product's result, read three times
+    assert len(set(fwd[1])) == 1 and _is_product(comps, entry, fwd[1][0])
+    # the output, and the packed gradient of q, k and v: read by products
+    # (the projection; the weight gradient and dX), and O by the backward
+    # kernel
+    outputs = [part for kname in kernels for part, (op, ops, line)
+               in entry.items() if re.match(r"\S+ = bf16", line) and (
+                   part == kname or op == "get-tuple-element"
+                   and ops == [kname])]
+    assert len(outputs) == 2
+    for part in outputs:
+        users = _readers(entry, part)
+        assert users and all(_is_product(comps, entry, u) or u in kernels
+                             for u in users), (part, users)
+
+    def read(s, d, what):
+        return tm.gauge_value("pallas.flash_attention.plan", kernel="flash_fwd",
+                              s=s, d=d, causal=True, what=what)
+
+    assert read(S, D, "lane_heads") == 2 and read(S, D, "fold") == 1
+    # glm47f_train_s8k's width: a head of 256 goes head-major (its q and k
+    # are built a head at a time, so the (B, S, H x D) view would be a copy)
+    q = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16)
+    jax.eval_shape(lambda q: PA.flash_attention(q, q, q, causal=True), q)
+    assert read(8192, 256, "lane_heads") == 0
+
+
 def test_flash_kernels_at_the_latent_cell_shape(one_chip):
     # what glm47f_train_s8k calls: 20 heads of 256 (192 + 64 rotary) on
     # values of 256, 8192 positions; a head's dQ (16 MiB resident) fits

@@ -448,3 +448,164 @@ def test_flash_default_blocks_and_fold_follow_from_the_shapes():
                               block_q=512) == (512, 1024, 1)
     assert tuned_flash_config(1024, 128, 64, "bfloat16", True,
                               head_fold=4) == (1024, 1024, 4)
+
+
+# ---------------------------------------------------------------------------
+# the caller's (B, S, heads, D) layout, read in place (two heads of 64 a
+# 128-lane block) or through head-major copies, against the dense rule
+# and against the head-major path the kernels took before
+# ---------------------------------------------------------------------------
+
+# B, S, query heads, k heads, v heads, D, value width, window, blocks,
+# backward form, the gauge's lane_heads
+_LAYOUT_CASES = {
+    "b1_d64_even": (1, 256, 4, 4, 4, 64, 64, None, 128, "fused", 2),
+    "b3_d64_even": (3, 256, 2, 2, 2, 64, 64, None, 128, "fused", 2),
+    "b3_d64_odd": (3, 256, 3, 3, 3, 64, 64, None, 128, "fused", 0),
+    "b1_d64_odd": (1, 128, 1, 1, 1, 64, 64, None, None, "fused", 0),
+    "b3_d128": (3, 256, 2, 2, 2, 128, 128, None, 128, "fused", 0),
+    "b1_d256": (1, 256, 2, 2, 2, 256, 256, None, 128, "fused", 0),
+    "b3_g2": (3, 256, 4, 2, 2, 64, 64, None, 128, "fused", 2),
+    "b3_g4": (3, 256, 8, 2, 2, 64, 64, None, 128, "fused", 2),
+    "b3_g4_twopass": (3, 256, 8, 2, 2, 64, 64, None, 128, "two", 2),
+    "b3_g2_v128": (3, 256, 4, 2, 1, 64, 128, None, 128, "fused", 0),
+    "b1_g2_v128_window": (1, 512, 4, 2, 1, 64, 128, 100, 128, "fused", 0),
+    "b3_window": (3, 512, 2, 2, 2, 64, 64, 100, 128, "fused", 2),
+    "b1_g4_window_twopass": (1, 512, 8, 2, 2, 64, 64, 200, 128, "two", 2),
+    "b3_d64_full": (3, 256, 2, 2, 2, 64, 64, "full", 128, "fused", 2),
+}
+
+
+def _dense_4d(q, k, v, causal, window, scale):
+    """(B, S, H, D) attention by the dense rule: ``_dense_attention_shd``
+    a row of the batch, or a masked softmax where a window is kept; k and
+    v heads repeated over the query heads they serve."""
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu.ops.pallas_attention import (
+        _dense_attention_shd)
+    H = q.shape[2]
+    k = jnp.repeat(k, H // k.shape[2], axis=2)
+    v = jnp.repeat(v, H // v.shape[2], axis=2)
+    if window is None:
+        return jax.vmap(lambda q, k, v: _dense_attention_shd(
+            q, k, v, causal, scale))(q, k, v)
+    S = q.shape[1]
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    live = (j <= i) & (i - j < window)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
+    p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v).astype(q.dtype)
+
+
+@pytest.mark.parametrize("case", list(_LAYOUT_CASES))
+def test_flash_in_the_callers_layout_matches_dense_and_head_major(
+        case, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu import telemetry as tm
+    from distributedarrays_tpu.ops import pallas_attention as PA
+    B, S, H, Hk, Hv, D, Dv, window, blk, form, lanes = _LAYOUT_CASES[case]
+    causal = window != "full"
+    window = None if window == "full" else window
+    if form == "two":
+        # nothing fits either limit: the dQ pass runs as its own kernel
+        monkeypatch.setattr(PA, "_FUSED_DQ_BYTES", 0)
+        monkeypatch.setattr(PA, "_FUSED_VMEM_CAP", 0)
+    PA._build.cache_clear()
+    PA._build_bwd.cache_clear()
+    ks = jax.random.split(jax.random.key(B * S + H + D + Dv), 4)
+    q = jax.random.normal(ks[0], (B, S, H, D))
+    k = jax.random.normal(ks[1], (B, S, Hk, D))
+    v = jax.random.normal(ks[2], (B, S, Hv, Dv))
+    w = jax.random.normal(ks[3], (B, S, H, Dv))
+    scale = 1.0 / np.sqrt(D)
+
+    def flash(q, k, v):
+        return PA.flash_attention(q, k, v, causal=causal, window=window,
+                                  block_q=blk, block_k=blk)
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a) * w), (0, 1, 2))(q, k, v)
+
+    got, got_g = flash(q, k, v), grads(flash)
+    assert got.shape == (B, S, H, Dv)
+    assert tm.gauge_value("pallas.flash_attention.plan", kernel="flash_fwd",
+                          s=S, d=D, causal=causal, what="lane_heads",
+                          **({} if window is None else {"window": window})
+                          ) == lanes
+    want = _dense_4d(q, k, v, causal, window, scale)
+    want_g = grads(lambda *a: _dense_4d(*a, causal, window, scale))
+    # the head-major path: every call through (B x H, S, D) copies
+    monkeypatch.setattr(PA, "_lane_heads", lambda *a: 0)
+    major, major_g = flash(q, k, v), grads(flash)
+    PA._build_bwd.cache_clear()
+    for ref, ref_g, tol in ((want, want_g, 1e-4), (major, major_g, 1e-5)):
+        assert float(jnp.abs(got - ref).max()) < 2e-5
+        for name, a, b in zip("qkv", got_g, ref_g):
+            gap = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+            assert gap < tol, (name, gap)
+
+
+# B, S, heads, D, window, blocks, backward form, the gauge's lane_heads
+_PACKED_CASES = {
+    "b3_d64": (3, 256, 4, 64, None, 128, "fused", 2),
+    "b1_d64_window": (1, 512, 2, 64, 100, 128, "fused", 2),
+    "b2_d64_full": (2, 256, 2, 64, "full", 128, "fused", 2),
+    "b2_d64_twopass": (2, 256, 2, 64, None, 128, "two", 2),
+    "b2_d32_head_major": (2, 128, 2, 32, None, None, "fused", 0),
+    "row_d64": (None, 256, 2, 64, None, 128, "fused", 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_PACKED_CASES))
+def test_flash_reads_packed_qkv_and_writes_its_gradient_packed(
+        case, monkeypatch):
+    # q, k, v as one (B, S, 3, H, D) projection: the same attention and the
+    # same gradients as the three arrays apart, the gradient one array
+    import jax
+    import jax.numpy as jnp
+    from distributedarrays_tpu import telemetry as tm
+    from distributedarrays_tpu.ops import pallas_attention as PA
+    B, S, H, D, window, blk, form, lanes = _PACKED_CASES[case]
+    causal = window != "full"
+    window = None if window == "full" else window
+    if form == "two":
+        # no one sweep: the packed form gives way to q, k, v apart
+        monkeypatch.setattr(PA, "_FUSED_DQ_BYTES", 0)
+        monkeypatch.setattr(PA, "_FUSED_VMEM_CAP", 0)
+    PA._build.cache_clear()
+    PA._build_bwd.cache_clear()
+    ks = jax.random.split(jax.random.key(S + H + D), 2)
+    shape = (S, 3, H, D) if B is None else (B, S, 3, H, D)
+    qkv = jax.random.normal(ks[0], shape)
+    w = jax.random.normal(ks[1], shape[:-3] + (H, D))
+    kw = dict(causal=causal, window=window, block_q=blk, block_k=blk)
+
+    def packed(x):
+        return PA.flash_attention(x, None, None, **kw)
+
+    def apart(x):
+        return PA.flash_attention(*(x[..., n, :, :] for n in range(3)), **kw)
+
+    def dense(x):
+        x4 = x if B is not None else x[None]
+        o = _dense_4d(*(x4[:, :, n] for n in range(3)), causal, window,
+                      1.0 / np.sqrt(D))
+        return o if B is not None else o[0]
+
+    loss = lambda f: (lambda x: jnp.sum(f(x) * w))
+    got, got_g = packed(qkv), jax.grad(loss(packed))(qkv)
+    assert got.shape == w.shape and got_g.shape == qkv.shape
+    assert tm.gauge_value("pallas.flash_attention.plan", kernel="flash_fwd",
+                          s=S, d=D, causal=causal, what="lane_heads",
+                          **({} if window is None else {"window": window})
+                          ) == lanes
+    for ref, tol in ((dense, 1e-4), (apart, 1e-5)):
+        assert float(jnp.abs(got - ref(qkv)).max()) < 2e-5
+        want_g = jax.grad(loss(ref))(qkv)
+        for n in range(3):
+            a, b = got_g[..., n, :, :], want_g[..., n, :, :]
+            gap = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+            assert gap < tol, ("qkv"[n], gap)
+    PA._build_bwd.cache_clear()
