@@ -1644,15 +1644,18 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
     (seq, heads, head_dim) ones (one row of a batch), without
     materializing the S×S score matrix.
 
-    The kernels read q, k, v and write the result in the caller's
-    token-major layout: (B, S, H, D) is (B, S, H·D) with no copy, and a
-    grid step's block is a column block of it, one head of a width that
-    is a multiple of 128, or two heads of 64 side by side in 128 lanes.
-    Heads of another width, an odd count of 64-wide heads, values of
-    another width than 64-wide keys, or a ``head_fold`` the lanes do not
-    give go through head-major (B·H, S, D) copies instead; the gauge
-    ``pallas.flash_attention.plan{what=lane_heads}`` says which (2, 1 or
-    0).  Causality, the window and the softmax run per row of the batch.
+    With heads of 64 (an even count, values 64 wide too, groups of one
+    head or of whole pairs) the kernels read q, k, v and write the result
+    in the caller's token-major layout: (B, S, H, D) is (B, S, H·D) with
+    no copy, and a grid step's block is a column block of it, two heads
+    side by side in 128 lanes.  Every other shape goes through head-major
+    (B·H, S, D) copies, heads of 128 or more lanes among them (``_lane_heads``:
+    the callers that have them build q and k a head at a time, so the
+    token-major view would itself be a copy), as do an odd count of
+    64-wide heads, values of another width than 64-wide keys, and a
+    ``head_fold`` past 2; the gauge
+    ``pallas.flash_attention.plan{what=lane_heads}`` says which (2 or 0).
+    Causality, the window and the softmax run per row of the batch.
     ``q`` may also carry k and v, with ``k`` and ``v`` None: the
     (B, S, 3·H·D) result of one projection (q's columns, then k's, then
     v's) viewed as ([B,] S, 3, H, D).  With heads of 64 the kernels read

@@ -31,6 +31,7 @@ MODULES = [
     "distributedarrays_tpu.ops.pallas_attention",
     "distributedarrays_tpu.ops.pallas_selective_scan",
     "distributedarrays_tpu.ops.pallas_ssd",
+    "distributedarrays_tpu.ops.pallas_gated_delta",
     "distributedarrays_tpu.ops.pallas_stencil",
     "distributedarrays_tpu.ops.pallas_collectives",
     "distributedarrays_tpu.ops.ring_schedules",
@@ -51,6 +52,7 @@ MODULES = [
     "distributedarrays_tpu.models.sp_transformer",
     "distributedarrays_tpu.models.sambay",
     "distributedarrays_tpu.models.mamba2_hybrid",
+    "distributedarrays_tpu.models.olmo_hybrid",
     "distributedarrays_tpu.train.trainer",
     "distributedarrays_tpu.train.optim",
     "distributedarrays_tpu.train.tasks",

@@ -774,3 +774,59 @@ def test_mamba2_hybrid_train_step_at_the_benchmark_size(one_chip, monkeypatch):
                                    ("block/attn", "recompute")}
     assert sum(v[0] is not None for v in fusions.values()) \
         > 0.6 * len(fusions)
+
+
+def test_olmo_hybrid_train_step_at_the_benchmark_size(one_chip, monkeypatch):
+    # the step olmohyb_train_s8k times, at its size: published widths,
+    # layers 0..3 (three gated delta-rule layers, full attention at 3),
+    # 12544 vocabulary rows, one row of 8193 ids, AdamW
+    import optax
+    from distributedarrays_tpu.models import olmo_hybrid as M
+    from distributedarrays_tpu.ops import pallas_gated_delta as GD
+    from distributedarrays_tpu import telemetry as tm
+    monkeypatch.setattr(PA, "_on_tpu", lambda: True)
+    monkeypatch.setattr(GD, "_on_tpu", lambda: True)
+    layers = tuple((i, "full_attention" if i == 3 else "linear_attention")
+                   for i in range(4))
+    cfg = M.Config(vocab=12544, dim=3840, ffn=11008, heads=30, head_dim=128,
+                   lin_heads=30, key_dim=96, value_dim=192, layers=layers)
+    step, init = M.make_optax_train_step(
+        cfg, optax.adamw(1e-3, weight_decay=0.1))
+    on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    shapes = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(shapes)) \
+        == 928_862_196
+    params = jax.tree_util.tree_map(on, shapes)
+    state = jax.tree_util.tree_map(on, jax.eval_shape(init, shapes))
+    tokens = jax.ShapeDtypeStruct((1, 8193), jnp.int32, sharding=one_chip)
+    step.note(params, state, tokens)
+    compiled = programs.compiled(step)
+    mem = programs.memory(step)
+    print(f"olmo_hybrid step for v5e:2x2: arguments "
+          f"{mem['argument'] / 1e9:.2f} GB, scratch "
+          f"{mem['temp'] / 1e9:.2f} GB, in all {mem['total'] / 1e9:.2f} GB; "
+          f"{mem}")
+    # under the 14.5 GB the cell may need of the chip's 16
+    assert mem["total"] < 14.5e9
+    txt = compiled.as_text()
+    kernels, fusions = _placed(step, txt)
+    count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", txt))
+    # three linear-attention layers: the forward kernel twice (recomputed),
+    # the backward once; the full-attention layer likewise
+    assert count("gdn_fwd") == 6 and count("gdn_bwd") == 3
+    assert count("flash_fwd") == 2 and count("flash_bwd_dkv") == 1
+    placed = {k.split(".")[0]: set() for k in kernels}
+    for k, v in kernels.items():
+        placed[k.split(".")[0]].add(v)
+    assert placed["gdn_fwd"] == {("block/linear", "forward"),
+                                 ("block/linear", "recompute")}
+    assert placed["gdn_bwd"] == {("block/linear", "backward")}
+    assert placed["flash_fwd"] == {("block/attn", "forward"),
+                                   ("block/attn", "recompute")}
+    # heads of 128 go through head-major copies (PERF.md section 7)
+    assert tm.gauge_value("pallas.flash_attention.plan", kernel="flash_fwd",
+                          s=8192, d=128, causal=True, what="lane_heads") == 0
+    assert tm.gauge_value("pallas.gated_delta.plan", L=8192, H=30, dk=96,
+                          dv=192, what="checkpoint_bytes") == 283_115_520
+    assert sum(v[0] is not None for v in fusions.values()) \
+        > 0.6 * len(fusions)

@@ -23,6 +23,7 @@ for _p in (str(BENCH),):
         sys.path.insert(0, _p)
 
 import harness  # noqa: E402
+from _trace_lock import traced_rehearsal  # noqa: E402
 
 CELL = "granite4h_train_s8k"
 
@@ -58,10 +59,11 @@ def _fails(numbers, limits):
 
 def test_rehearsal_reaches_its_line_with_the_new_readers(capsys):
     capsys.readouterr()
-    rc = harness.main(["--workload", CELL, "--seed", "2147483999",
-                       "--seconds", "0.2", "--trace", "1", "--platform",
-                       "cpu", "--size", "tiny"], t0=time.perf_counter(),
-                      root=BENCH)
+    with traced_rehearsal():
+        rc = harness.main(["--workload", CELL, "--seed", "2147483999",
+                           "--seconds", "0.2", "--trace", "1", "--platform",
+                           "cpu", "--size", "tiny"], t0=time.perf_counter(),
+                          root=BENCH)
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc == harness.EXIT_REHEARSAL
     assert line["correct"] is True, line["compared"]

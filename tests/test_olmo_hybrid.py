@@ -1,0 +1,354 @@
+"""The Olmo hybrid (``models/olmo_hybrid.py``) and its gated delta-rule kernel
+pair (``ops/pallas_gated_delta.py``) against plain references at tiny sizes
+on the CPU (kernels in interpret mode): the kernels against the recurrence
+taken one position after the other, the model against its package
+reference and the benchmark's, the step's scopes against the phases the
+readers place."""
+
+import os
+import re
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+REPO = Path(__file__).resolve().parent.parent
+for _p in (str(REPO / "benchmark"),):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+from distributedarrays_tpu.models import olmo_hybrid as M       # noqa: E402
+from distributedarrays_tpu.models import (                       # noqa: E402
+    olmo_hybrid_reference as MR)
+from distributedarrays_tpu.ops.pallas_gated_delta import (      # noqa: E402
+    gated_delta, gated_delta_plan)
+
+LAYERS = ((2, "linear_attention"), (3, "full_attention"),
+          (4, "linear_attention"))
+DIMS = dict(dim=64, ffn=96, heads=4, head_dim=16, lin_heads=4, key_dim=16,
+            value_dim=32, d_conv=4)
+
+
+def _config(layers=LAYERS, dtype=jnp.float32):
+    return M.Config(vocab=96, dim=64, ffn=96, heads=4, head_dim=16,
+                    lin_heads=4, key_dim=16, value_dim=32, layers=layers,
+                    loss_rows=16, dtype=dtype)
+
+
+def _weights(layers=LAYERS, seed=3):
+    """Seeded weights with every leaf moved off its start (a scale of 1
+    would hide a gradient path)."""
+    import datagen_olmo_hybrid as G
+    params = G.olmo_hybrid_weights(jax.random.key(seed), DIMS,
+                                   [k for _, k in layers], 96, jnp.float32)
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
+    return jax.tree_util.tree_unflatten(tree, [
+        x + 0.05 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+
+
+def _tokens(seed=1, batch=1, seq=72):
+    import datagen_olmo_hybrid as G
+    return G.token_rows(jax.random.key(seed), 1, batch, seq + 1, 96)[0]
+
+
+# ---------------------------------------------------------------------------
+# the delta-rule kernels against the recurrence
+# ---------------------------------------------------------------------------
+
+
+GDN_CASES = {
+    # L, H, dk, dv, chunk, -g from .. to, beta from .. to
+    "padded_mild_gate": (44, 2, 16, 24, 16, 0.0, 0.1, 0.0, 1.0),
+    "one_chunk": (16, 2, 16, 16, 16, 0.0, 0.1, 0.0, 2.0),
+    "many_chunks_alpha_near_one": (96, 4, 8, 16, 8, 0.0, 1e-3, 0.0, 2.0),
+    "alpha_near_zero": (50, 4, 8, 16, 8, 3.0, 8.0, 0.0, 2.0),
+    "beta_near_two": (64, 2, 16, 32, 16, 0.0, 0.05, 1.9, 2.0),
+}
+
+
+def _gdn_case(L, H, dk, dv, glo, ghi, blo, bhi, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (L, H, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (L, H, dk)))
+    v = jax.random.normal(ks[2], (L, H, dv))
+    beta = jax.random.uniform(ks[3], (L, H), minval=blo, maxval=bhi)
+    g = -jax.random.uniform(ks[4], (L, H), minval=glo, maxval=ghi)
+    return (q, k, v, beta, g), jax.random.normal(ks[5], (L, H, dv))
+
+
+_GDN_RESULTS = {}
+
+
+def _gdn_results(case):
+    """(kernel, recurrence) results of a case: the forward, then the five
+    gradients of a weighted sum, computed once for the six tests of it."""
+    if case not in _GDN_RESULTS:
+        L, H, dk, dv, chunk, glo, ghi, blo, bhi = GDN_CASES[case]
+        args, w = _gdn_case(L, H, dk, dv, glo, ghi, blo, bhi)
+        kernel = lambda *a: gated_delta(*a, chunk=chunk)
+        with jax.default_matmul_precision("highest"):
+            _GDN_RESULTS[case] = [
+                (f(*args),) + jax.grad(lambda *a: jnp.sum(f(*a) * w),
+                                       argnums=(0, 1, 2, 3, 4))(*args)
+                for f in (kernel, MR.delta_rule)]
+    return _GDN_RESULTS[case]
+
+
+def _rel(got, want):
+    return float(jnp.max(jnp.abs(got - want))) / float(jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("arg", ["forward", "q", "k", "v", "beta", "g"])
+@pytest.mark.parametrize("case", list(GDN_CASES))
+def test_gdn_kernels_match_the_sequential_recurrence(case, arg):
+    n = ["forward", "q", "k", "v", "beta", "g"].index(arg)
+    got, want = (r[n] for r in _gdn_results(case))
+    assert got.shape == want.shape
+    # float32 throughout at this size; the kernels sum in another order (by
+    # chunks, the solve by doubling): read 7e-7 at most
+    assert _rel(got, want) < 2e-5
+
+
+def test_a_kernel_that_clamps_beta_to_one_fails_the_comparison():
+    # eigenvalues of the transition in (-1, 0) are the published
+    # configuration's (linear_allow_neg_eigval): a kernel held to beta <= 1
+    # is wrong there by far more than the comparison lets through
+    L, H, dk, dv, chunk, glo, ghi, blo, bhi = GDN_CASES["beta_near_two"]
+    (q, k, v, beta, g), _ = _gdn_case(L, H, dk, dv, glo, ghi, blo, bhi)
+    with jax.default_matmul_precision("highest"):
+        want = MR.delta_rule(q, k, v, beta, g)
+    clamped = gated_delta(q, k, v, jnp.minimum(beta, 1.0), g, chunk=chunk)
+    assert _rel(clamped, want) > 100 * 2e-5
+    assert _rel(gated_delta(q, k, v, beta, g, chunk=chunk), want) < 2e-5
+
+
+def test_gdn_in_bfloat16_stays_near_the_float32_recurrence():
+    # the training type: the products with a width take bf16 operands, the
+    # solve, the states and the sums stay float32
+    args, _ = _gdn_case(64, 2, 16, 32, 0.0, 0.1, 0.0, 2.0)
+    q, k, v, beta, g = args
+    bf = lambda t: t.astype(jnp.bfloat16)
+    got = gated_delta(bf(q), bf(k), bf(v), beta, g, chunk=16)
+    assert got.dtype == jnp.float32
+    with jax.default_matmul_precision("highest"):
+        want = MR.delta_rule(*args)
+    assert _rel(got, want) < 3e-2
+
+
+def test_gdn_plan_and_its_gauge():
+    from distributedarrays_tpu import telemetry as tm
+    plan = gated_delta_plan(8192, 30, 96, 192)
+    assert (plan["chunk"], plan["chunks"], plan["head_block"]) == (64, 128, 5)
+    assert plan["checkpoint_bytes"] == 128 * 30 * 192 * 96 * 4
+    assert plan["vmem_bytes"] % 2**20 == 0 and plan["vmem_bytes"] < 16 * 2**20
+    assert gated_delta_plan(44, 2, 16, 24, 16)["padded"] == 48
+    assert gated_delta_plan(64, 7, 8, 8, 8)["head_block"] == 1
+    with pytest.raises(ValueError):
+        gated_delta_plan(64, 2, 8, 8, 12)
+    args, _ = _gdn_case(32, 2, 16, 24, 0.0, 0.1, 0.0, 1.0)
+    gated_delta(*args, chunk=16)
+    read = lambda what: tm.gauge_value("pallas.gated_delta.plan", L=32, H=2,
+                                       dk=16, dv=24, what=what)
+    assert (read("chunk"), read("chunks"), read("head_block")) == (16, 2, 2)
+    assert read("checkpoint_bytes") == 2 * 2 * 24 * 16 * 4
+    assert read("vmem_bytes") > 0
+
+
+# ---------------------------------------------------------------------------
+# the model against the references
+# ---------------------------------------------------------------------------
+
+
+def _gaps(g, g0):
+    """{leaf path: |g - g0| / max(|g0|, a thousandth of the median
+    leaf's norm)}."""
+    flat = jax.tree_util.tree_leaves_with_path(g)
+    flat0 = jax.tree_util.tree_leaves(g0)
+    scale = float(np.median([float(jnp.linalg.norm(x)) for x in flat0]))
+    return {jax.tree_util.keystr(p): float(jnp.linalg.norm(a - b)) / max(
+        float(jnp.linalg.norm(b)), 1e-3 * scale)
+        for (p, a), b in zip(flat, flat0)}
+
+
+def test_loss_and_every_leaf_gradient_match_refs_olmo_hybrid():
+    import refs_olmo_hybrid as R
+    cfg, params, tok = _config(), _weights(), _tokens()
+    loss, g = jax.jit(jax.value_and_grad(M.loss_fn),
+                      static_argnums=2)(params, tok, cfg)
+    dims = dict(DIMS, eps=1e-6, kinds=tuple(k for _, k in LAYERS))
+    grads = {"layers": [None] * len(LAYERS)}
+
+    def keep(n, sub):
+        if n is None:
+            grads.update(sub)
+        else:
+            grads["layers"][n] = sub
+
+    with jax.default_matmul_precision("highest"):
+        nll = R._row_nll_and_grads(params, tok[0], dims, None, keep)
+    loss0 = nll / (tok.shape[1] - 1)
+    g0 = jax.tree_util.tree_map(lambda t: t / (tok.shape[1] - 1), grads)
+    assert abs(float(loss) - loss0) < 2e-5 * abs(loss0)
+    want, gap = R.leaf_norm_dict(g0), R.leaf_norm_dict(g, g0)
+    assert set(gap) == set(R.leaf_norm_dict(params))
+    floor = 1e-3 * float(np.median(list(want.values())))
+    # float32 on both sides at this size: read 1.3e-5 at most
+    worst = max((gap[k] / max(want[k], floor), k) for k in gap)
+    assert worst[0] < 5e-4, worst
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_package_reference_agrees_with_the_program(batch):
+    # with two rows the batch folds into the kernels' heads
+    cfg, params = _config(), _weights()
+    tok = _tokens(batch=batch)
+    vg = lambda f: jax.jit(jax.value_and_grad(f), static_argnums=2)
+    loss, g = vg(M.loss_fn)(params, tok, cfg)
+    loss0, g0 = vg(MR.loss_fn)(params, tok, cfg)
+    assert abs(float(loss) - float(loss0)) < 2e-5 * abs(float(loss0))
+    # float32 on both sides: read 1.2e-5 at most (A_log, dt_bias)
+    worst = max((v, k) for k, v in _gaps(g, g0).items())
+    assert worst[0] < 5e-4, worst
+    fwd = lambda f: jax.jit(f, static_argnums=2)(params, tok[:, :-1], cfg)
+    logits, logits0 = fwd(M.forward), fwd(MR.forward)
+    assert np.allclose(logits, logits0, atol=2e-5)
+
+
+@pytest.mark.parametrize("leaf", [
+    "post_mix_norm", "post_mlp_norm", "o_norm", "q_norm", "k_norm"])
+def test_each_norm_acts_and_its_gradient_matches(leaf):
+    # the post-norms, the gated head norm and the QK-norm each change the
+    # logits when their scale moves (channel by channel: the post-norm
+    # after the mixer takes a uniform scale of o_norm out again), and their
+    # gradients agree with the package reference's
+    cfg, params, tok = _config(), _weights(), _tokens()
+    n = next(i for i, p in enumerate(params["layers"]) if leaf in p)
+    moved = jax.tree_util.tree_map(lambda x: x, params)
+    moved["layers"][n] = dict(params["layers"][n])
+    scale = params["layers"][n][leaf]
+    moved["layers"][n][leaf] = scale * (
+        1.0 + 0.5 * jax.random.normal(jax.random.key(5), scale.shape))
+    base = M.forward(params, tok[:, :-1], cfg)
+    assert float(jnp.max(jnp.abs(M.forward(moved, tok[:, :-1], cfg) - base))) \
+        > 1e-3, leaf
+    grad = lambda f: jax.jit(jax.grad(f), static_argnums=2)(params, tok, cfg)
+    got = grad(M.loss_fn)["layers"][n][leaf]
+    want = grad(MR.loss_fn)["layers"][n][leaf]
+    assert float(jnp.linalg.norm(want)) > 0
+    assert float(jnp.linalg.norm(got - want)) < 5e-4 * float(
+        jnp.linalg.norm(want))
+
+
+def test_bf16_training_step_runs_and_moves_the_weights():
+    import optax
+    cfg = _config(dtype=jnp.bfloat16)
+    params = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16),
+                                    _weights())
+    before = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params)
+    step, init = M.make_optax_train_step(cfg, optax.adamw(1e-3))
+    params, state, loss = step(params, init(params), _tokens())
+    assert np.isfinite(float(loss))
+    moved = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))),
+        params, before)
+    assert moved["embed"] > 0 and moved["head"] > 0
+    assert moved["layers"][0]["w_in"] > 0 and moved["layers"][1]["w_qkv"] > 0
+    assert moved["layers"][0]["w_ab"] > 0 and moved["layers"][2]["conv_w"] > 0
+    assert moved["layers"][1]["w_o"] > 0 and moved["layers"][0]["w_o"] > 0
+    # (the norm scales lie near 1, A_log near log of 0..16 and dt_bias near
+    # log(dt), some -2 to -7, where one step of 1e-3 is under half a bf16
+    # ulp: they move on neither side)
+
+
+def test_olmo_hybrid_parameters_at_the_cell_widths():
+    import counts_olmo_hybrid as C
+    cut = tuple((i, "full_attention" if i == 3 else "linear_attention")
+                for i in range(4))
+    cfg = M.Config(vocab=12544, dim=3840, ffn=11008, heads=30, head_dim=128,
+                   lin_heads=30, key_dim=96, value_dim=192, layers=cut)
+    shapes = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+    n = sum(x.size for x in jax.tree_util.tree_leaves(shapes))
+    m = dict(DIMS, dim=3840, ffn=11008, heads=30, head_dim=128, lin_heads=30,
+             key_dim=96, value_dim=192)
+    assert n == C.olmo_hybrid_params(m, [k for _, k in cut], 12544) \
+        == 928_862_196
+    per = {k: C.layer_params(k, m) for k in M.KINDS}
+    assert per == {"linear_attention": 215_570_172,
+                   "full_attention": 185_809_920}
+    layer0 = shapes["layers"][0]
+    assert sum(x.size for k, x in layer0.items()
+               if k not in ("w1", "w2", "post_mix_norm", "post_mlp_norm")) \
+        == 88_750_332
+
+
+# ---------------------------------------------------------------------------
+# the step's scopes, and what importing the package loads
+# ---------------------------------------------------------------------------
+
+
+def test_declared_scopes_are_phases_the_readers_place():
+    # block/linear is in no group of phases.py: its own reader
+    # (phase_linear_ms) reads it from the split by phase, and
+    # phase_unscoped_ms is not reported for this model's cell
+    from layer_metrics.phases import GROUPS
+    from layer_metrics import phase_linear_ms
+    placed = {phase: g for g, phases in GROUPS.items() for phase in phases}
+    assert {s: placed.get(s) for s in M.SCOPES} == {
+        "embed": "head_loss", "block/linear": None, "block/attn": "attn",
+        "block/mlp": "mlp", "head_loss": "head_loss",
+        "optimizer": "optimizer"}
+    assert phase_linear_ms.PHASE in M.SCOPES
+
+
+@pytest.mark.parametrize("scope", ["embed", "block/linear", "block/attn",
+                                   "block/mlp", "head_loss", "optimizer",
+                                   "gdn_fwd", "gdn_bwd", "flash_fwd",
+                                   "flash_bwd_dkv"])
+def test_step_carries_its_scopes_and_kernel_names(scope, step_text):
+    names = set(re.findall(r'loc\("([^"]*)"', step_text))
+    if scope.startswith("block/"):
+        leaf = scope.split("/")[1]
+        hits = [n for n in names if f"block/{leaf}/" in n
+                or f"jvp(block)/{leaf}/" in n]
+        # a layer is computed forward, again in the backward, and backward
+        assert any("rematted_computation" in n for n in hits)
+        assert any(n.startswith("jit(step)/jvp(") for n in hits)
+    else:
+        hits = [n for n in names if scope in n]
+    assert hits, scope
+
+
+@pytest.fixture(scope="module")
+def step_text():
+    import optax
+    cfg = _config(dtype=jnp.bfloat16)
+    step, init = M.make_optax_train_step(cfg, optax.adamw(1e-3))
+    p = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+    return step.lower(p, jax.eval_shape(init, p),
+                      jax.ShapeDtypeStruct((1, 33), jnp.int32)
+                      ).as_text(debug_info=True)
+
+
+def test_importing_the_package_loads_neither_the_model_nor_the_kernels():
+    code = textwrap.dedent("""
+        import sys
+        import distributedarrays_tpu
+        import distributedarrays_tpu.ops
+        for name in ("distributedarrays_tpu.models.olmo_hybrid",
+                     "distributedarrays_tpu.models.olmo_hybrid_reference",
+                     "distributedarrays_tpu.ops.pallas_gated_delta"):
+            assert name not in sys.modules, name
+        print("ok")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0 and "ok" in out.stdout, out.stderr[-2000:]

@@ -75,6 +75,13 @@ def test_spelling_the_benchmark_readers_rely_on():
                                                           "ssd_bwd"}
 
 
+def test_spelling_of_the_gated_delta_kernels():
+    # gdn_scan_ms reads ``^%?gdn_(fwd|bwd)``
+    names = {name for _, name in _pallas_calls()}
+    assert {n for n in names if n.startswith("gdn_")} == {"gdn_fwd",
+                                                          "gdn_bwd"}
+
+
 @pytest.mark.parametrize("scope", [
     "embed", "mamba", "window", "full", "gmu", "cross", "mlp", "head_loss",
     "optimizer", "selective_scan_fwd", "selective_scan_bwd", "flash_fwd",
