@@ -34,21 +34,25 @@ Layout.  Heads lead: q, k, v and o are (H, L, width) and a grid step holds
 ``head_block`` heads of one chunk; ``beta`` and the running sums come as
 (H, chunks, C) and stay resident for a head block while its chunks go by.
 The grid is (head blocks, chunks) with the chunks innermost; a block's
-states persist in a scratch between chunks.  The forward saves the state
-each chunk starts from (H x chunks x dv x dk float32) and nothing else;
-the backward walks the chunks last to first, computes a chunk's ``T``,
-``W`` and ``V'`` again from its saved state, carries the state's cotangent
-in VMEM, and gives dq, dk, dv, dbeta and the cotangent of the running
-sums (the running sums themselves, and their cotangent's sum back into
-``g``, are XLA's, round the kernels).  The products with a width take
-their operands in the activations' type (bf16 in training) and accumulate
-in float32; the solve, the states and every sum are float32.
+states persist in a scratch between chunks.  The forward saves two things
+a chunk: the state it starts from (H x chunks x dv x dk float32) and its
+solve ``X = (I + A)^-1`` (C x C float32 a chunk and head, consecutive
+chunks side by side in 128-lane rows), which depends on k, beta and the
+gates alone.  The backward walks the chunks last to
+first, reads a chunk's ``X`` and takes ``T = X diag(beta)`` from it,
+computes what is cheap again (``K K^T``, ``G``, ``W``, ``V'`` from the
+saved state, ``Q K^T``), carries the state's cotangent in VMEM, and gives
+dq, dk, dv, dbeta and the cotangent of the running sums (the running sums
+themselves, and their cotangent's sum back into ``g``, are XLA's, round
+the kernels).  The products with a width take their operands in the
+activations' type (bf16 in training) and accumulate in float32; the
+solve, the states and every sum are float32.
 
 ``custom_vjp``: ``gated_delta`` is differentiable in all five arguments.
-The plan (chunk, chunks, heads a block, VMEM asked for, checkpoint bytes)
-is published as the gauge ``pallas.gated_delta.plan`` when a program is
-built (docs/telemetry.md).  Interpreter mode runs the same kernels
-off-TPU.
+The plan (chunk, chunks, heads a block, VMEM asked for, the bytes of the
+saved states and of the saved solves) is published as the gauge
+``pallas.gated_delta.plan`` when a program is built (docs/telemetry.md).
+Interpreter mode runs the same kernels off-TPU.
 """
 
 from __future__ import annotations
@@ -129,29 +133,67 @@ def _unit_lower_inverse(a, m: _Masks):
     return x
 
 
-def _forward_parts(q, k, v, brow, betarow, s, m: _Masks):
-    """What a chunk's forward computes, for its output, its next state and
-    the backward: a dict of the chunk's matrices."""
-    cdt = q.dtype
+def _prelude(k, brow, betarow, m: _Masks):
+    """A chunk's state-free (C, C) part, which the solve is made from and
+    the backward takes again: the running sums and beta as columns, ``G``,
+    ``K K^T`` and ``b_C`` as a row."""
     bcol, betacol = _col(brow, m), _col(betarow, m)
     gam = jnp.exp(jnp.where(m.lower, bcol - brow, -jnp.inf))      # G
     kk = _dot(k, k, _NT)
-    a = jnp.where(m.strict, betacol * kk * gam, 0.0)
-    x = _unit_lower_inverse(a, m)
+    bend = jnp.sum(jnp.where(m.jj == m.c - 1, brow, 0.0), axis=1,
+                   keepdims=True)                                # b_C a row
+    return dict(bcol=bcol, betacol=betacol, gam=gam, kk=kk, bend=bend)
+
+
+def _solve(pre, m: _Masks):
+    """The chunk's ``X = (I + A)^-1``: the forward's alone, which writes it
+    out for the backward."""
+    a = jnp.where(m.strict, pre["betacol"] * pre["kk"] * pre["gam"], 0.0)
+    return _unit_lower_inverse(a, m)
+
+
+def _forward_parts(q, k, v, x, betarow, s, pre, m: _Masks):
+    """What a chunk's forward computes from its prelude, its solve ``x``
+    and the state ``s`` it starts from, for its output, its next state and
+    the backward: ``pre`` with the chunk's further matrices."""
+    cdt = q.dtype
     t = x * betarow                                              # T
-    eb = jnp.exp(bcol)
+    eb = jnp.exp(pre["bcol"])
     kf = k.astype(_F32)
     kg = kf * eb
     tc = t.astype(cdt)
     w = _dot(tc, kg.astype(cdt))
     vn = _dot(tc, v) - _dot(w.astype(cdt), s.astype(cdt), _NT)     # V'
     qk = _dot(q, k, _NT)
-    p = qk * gam
-    bend = jnp.sum(jnp.where(m.jj == m.c - 1, brow, 0.0), axis=1,
-                   keepdims=True)                                # b_C a row
-    kt = kf * jnp.exp(bend - bcol)
-    return dict(bcol=bcol, betacol=betacol, gam=gam, kk=kk, x=x, t=t, eb=eb,
-                kg=kg, w=w, vn=vn, qk=qk, p=p, kt=kt, bend=bend)
+    p = qk * pre["gam"]
+    kt = kf * jnp.exp(pre["bend"] - pre["bcol"])
+    return dict(pre, t=t, eb=eb, kg=kg, w=w, vn=vn, qk=qk, p=p, kt=kt)
+
+
+def _pack(chunk: int) -> int:
+    """Chunks whose solves share a row of the saved ``X``: a (C, C) float32
+    block pads to 128 lanes in HBM and VMEM, so chunks of a width that
+    divides 128 lie side by side, consecutive chunks in one block."""
+    return 128 // chunk if 128 % chunk == 0 else 1
+
+
+def _put_solve(x_ref, i, c, x):
+    """Chunk ``c``'s solve into its lanes of head ``i``'s resident row."""
+    w = x.shape[1]
+    pack = x_ref.shape[3] // w
+    for r in range(pack):
+        @pl.when(c % pack == r)
+        def _put(r=r):
+            x_ref[i, 0, :, r * w:(r + 1) * w] = x
+
+
+def _get_solve(x_ref, i, c, w):
+    """Chunk ``c``'s solve, (w, w), from head ``i``'s resident row."""
+    pack = x_ref.shape[3] // w
+    x = x_ref[i, 0, :, 0:w]
+    for r in range(1, pack):
+        x = jnp.where(c % pack == r, x_ref[i, 0, :, r * w:(r + 1) * w], x)
+    return x
 
 
 def _end_scale(bcol, like, m: _Masks):
@@ -163,8 +205,8 @@ def _end_scale(bcol, like, m: _Masks):
                            axis=0, keepdims=True))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, o_ref, ck_ref, s_scr,
-                *, hb: int):
+def _fwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, o_ref, ck_ref, x_ref,
+                s_scr, *, hb: int):
     c = pl.program_id(1)
     m = _Masks(q_ref.shape[1])
 
@@ -177,8 +219,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, o_ref, ck_ref, s_scr,
         cdt = q.dtype
         s = s_scr[i]                                             # (dv, dk)
         ck_ref[i, 0] = s
-        f = _forward_parts(q, k, v, _chunk_row(b_ref[i], c),
-                           _chunk_row(beta_ref[i], c), s, m)
+        betarow = _chunk_row(beta_ref[i], c)
+        pre = _prelude(k, _chunk_row(b_ref[i], c), betarow, m)
+        x = _solve(pre, m)
+        _put_solve(x_ref, i, c, x)
+        f = _forward_parts(q, k, v, x, betarow, s, pre, m)
         qg = (q.astype(_F32) * f["eb"]).astype(cdt)
         o_ref[i] = (_dot(qg, s.astype(cdt), _NT)
                     + _dot(f["p"].astype(cdt), f["vn"].astype(cdt))
@@ -187,12 +232,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, o_ref, ck_ref, s_scr,
             + _dot(f["vn"].astype(cdt), f["kt"].astype(cdt), _TN)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, ck_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dbeta_ref, db_ref, ds_scr, *, hb: int,
+def _bwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, ck_ref, x_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dbeta_ref, db_ref, ds_scr, *, hb: int,
                 nc: int):
     """One chunk (the grid walks them last to first) of one head block.
     ``ds_scr`` holds each head's cotangent of the state the chunk ends
-    with; the rows of dbeta and db are written into resident blocks."""
+    with; the rows of dbeta and db are written into resident blocks.  The
+    chunk's solve ``X`` is the forward's, read from ``x_ref``."""
     c = pl.program_id(1)
     at = nc - 1 - c
     m = _Masks(q_ref.shape[1])
@@ -213,8 +259,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, ck_ref, do_ref, dq_ref,
         doc = do_ref[i].astype(cdt)
         brow = _chunk_row(b_ref[i], at)
         betarow = _chunk_row(beta_ref[i], at)
-        f = _forward_parts(q, k, v, brow, betarow, s0, m)
-        eb, gam, kk, x, t = f["eb"], f["gam"], f["kk"], f["x"], f["t"]
+        x = _get_solve(x_ref, i, at, m.c)
+        f = _forward_parts(q, k, v, x, betarow, s0,
+                           _prelude(k, brow, betarow, m), m)
+        eb, gam, kk, t = f["eb"], f["gam"], f["kk"], f["t"]
         vnc = f["vn"].astype(cdt)
         tc = t.astype(cdt)
         qg = q.astype(_F32) * eb
@@ -279,12 +327,14 @@ def _vmem_bytes(c: int, hb: int, nc: int, dk: int, dv: int,
                 itemsize: int) -> int:
     """What the backward (the larger kernel) holds in VMEM a grid step,
     from its specs: the q, k, v, dq, dk, dv blocks and dO (float32), the
-    checkpoint block, the resident rows of beta, b, dbeta and db, every block
-    in two buffers; the states' cotangents (scratch); and a head's (C, C)
-    and (C, width) float32 temporaries, some thirty of each."""
+    checkpoint block, the solve's block, the resident rows of beta, b, dbeta
+    and db, every block in two buffers; the states' cotangents (scratch);
+    and a head's (C, C) and (C, width) float32 temporaries, some thirty of
+    each."""
     kw, vw = _lanes(dk), _lanes(dv)
     blocks = hb * c * (4 * kw * itemsize + 2 * vw * itemsize + vw * 4)
-    blocks += hb * dv * kw * 4 + 4 * hb * nc * _lanes(c) * 4
+    blocks += hb * dv * kw * 4 + hb * c * _lanes(c) * 4
+    blocks += 4 * hb * nc * _lanes(c) * 4
     temps = 30 * (c * _lanes(c) + c * vw) * 4
     return 2 * blocks + hb * dv * kw * 4 + temps
 
@@ -293,16 +343,18 @@ def gated_delta_plan(L: int, H: int, dk: int, dv: int,
                      chunk: int | None = None, itemsize: int = 2) -> dict:
     """What a call on these shapes is built with: the chunk and the padded
     length, the heads a grid step, the VMEM limit the kernels name, and
-    the bytes of the states the forward saves for the backward."""
+    the bytes the forward saves for the backward: the states each chunk
+    starts from, and each chunk's solve."""
     chunk = int(chunk or _CHUNK)
     if chunk % 8:
         raise ValueError(f"chunk {chunk} is not a multiple of 8")
     hb = max(d for d in range(1, min(_HEAD_BLOCK, H) + 1) if H % d == 0)
-    chunks = -(-L // chunk)
+    chunks, pack = -(-L // chunk), _pack(chunk)
     need = _vmem_bytes(chunk, hb, chunks, dk, dv, itemsize)
     return dict(chunk=chunk, chunks=chunks, padded=chunks * chunk,
                 head_block=hb, vmem_bytes=-(-need * 5 // 4 // 2**20) * 2**20,
-                checkpoint_bytes=chunks * H * dv * dk * 4)
+                checkpoint_bytes=chunks * H * dv * dk * 4,
+                solve_bytes=H * -(-chunks // pack) * chunk * pack * chunk * 4)
 
 
 @functools.lru_cache(maxsize=32)
@@ -312,9 +364,9 @@ def _build(Lp: int, H: int, dk: int, dv: int, chunk: int, cdt: str,
     and v (H, Lp, dv) in ``cdt``; beta and the running sums (H, chunks,
     chunk) float32."""
     plan = gated_delta_plan(Lp, H, dk, dv, chunk, jnp.dtype(cdt).itemsize)
-    hb, nc = plan["head_block"], Lp // chunk
+    hb, nc, pack = plan["head_block"], Lp // chunk, _pack(chunk)
     for what in ("chunk", "chunks", "head_block", "vmem_bytes",
-                 "checkpoint_bytes"):
+                 "checkpoint_bytes", "solve_bytes"):
         _tm.set_gauge("pallas.gated_delta.plan", plan[what], L=Lp, H=H,
                       dk=dk, dv=dv, what=what)
     params = pltpu.CompilerParams(vmem_limit_bytes=plan["vmem_bytes"])
@@ -324,26 +376,30 @@ def _build(Lp: int, H: int, dk: int, dv: int, chunk: int, cdt: str,
         vals = pl.BlockSpec((hb, chunk, dv), lambda j, c: (j, cmap(c), 0))
         rows = pl.BlockSpec((hb, nc, chunk), lambda j, c: (j, 0, 0))
         ck = pl.BlockSpec((hb, 1, dv, dk), lambda j, c: (j, cmap(c), 0, 0))
-        return keys, vals, rows, ck
+        xs = pl.BlockSpec((hb, 1, chunk, pack * chunk),
+                          lambda j, c: (j, cmap(c) // pack, 0, 0))
+        return keys, vals, rows, ck, xs
 
-    keys, vals, rows, ck = specs(lambda c: c)
+    keys, vals, rows, ck, xs = specs(lambda c: c)
     fwd = pl.pallas_call(
         functools.partial(_fwd_kernel, hb=hb),
         grid=(H // hb, nc),
         in_specs=[keys, keys, vals, rows, rows],
-        out_specs=(vals, ck),
+        out_specs=(vals, ck, xs),
         out_shape=(jax.ShapeDtypeStruct((H, Lp, dv), _F32),
-                   jax.ShapeDtypeStruct((H, nc, dv, dk), _F32)),
+                   jax.ShapeDtypeStruct((H, nc, dv, dk), _F32),
+                   jax.ShapeDtypeStruct((H, -(-nc // pack), chunk,
+                                         pack * chunk), _F32)),
         scratch_shapes=[pltpu.VMEM((hb, dv, dk), _F32)],
         compiler_params=params,
         name="gdn_fwd",
         interpret=interpret,
     )
-    keys, vals, rows, ck = specs(lambda c: nc - 1 - c)
+    keys, vals, rows, ck, xs = specs(lambda c: nc - 1 - c)
     bwd = pl.pallas_call(
         functools.partial(_bwd_kernel, hb=hb, nc=nc),
         grid=(H // hb, nc),
-        in_specs=[keys, keys, vals, rows, rows, ck, vals],
+        in_specs=[keys, keys, vals, rows, rows, ck, xs, vals],
         out_specs=(keys, keys, vals, rows, rows),
         out_shape=(jax.ShapeDtypeStruct((H, Lp, dk), jnp.dtype(cdt)),
                    jax.ShapeDtypeStruct((H, Lp, dk), jnp.dtype(cdt)),
@@ -370,14 +426,14 @@ def _gdn_core(q, k, v, beta, b, interpret):
 
 def _gdn_fwd(q, k, v, beta, b, interpret):
     fwd, _ = _calls(q, v, b, interpret)
-    o, ck = fwd(q, k, v, beta, b)
-    return o, (q, k, v, beta, b, ck)
+    o, ck, x = fwd(q, k, v, beta, b)
+    return o, (q, k, v, beta, b, ck, x)
 
 
 def _gdn_bwd(interpret, res, do):
-    q, k, v, beta, b, ck = res
+    q, k, v, beta, b, ck, x = res
     _, bwd = _calls(q, v, b, interpret)
-    return bwd(q, k, v, beta, b, ck, do)
+    return bwd(q, k, v, beta, b, ck, x, do)
 
 
 _gdn_core.defvjp(_gdn_fwd, _gdn_bwd)

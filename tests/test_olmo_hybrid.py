@@ -70,6 +70,9 @@ GDN_CASES = {
     "many_chunks_alpha_near_one": (96, 4, 8, 16, 8, 0.0, 1e-3, 0.0, 2.0),
     "alpha_near_zero": (50, 4, 8, 16, 8, 3.0, 8.0, 0.0, 2.0),
     "beta_near_two": (64, 2, 16, 32, 16, 0.0, 0.05, 1.9, 2.0),
+    # two head blocks of 5, 13 chunks (the saved solves 8 to a row, the
+    # last row part-filled) and a padded tail of 8 positions
+    "head_blocks_padded_solve_rows": (200, 10, 8, 16, 16, 0.0, 0.1, 0.0, 2.0),
 }
 
 
@@ -159,7 +162,86 @@ def test_gdn_plan_and_its_gauge():
                                        dk=16, dv=24, what=what)
     assert (read("chunk"), read("chunks"), read("head_block")) == (16, 2, 2)
     assert read("checkpoint_bytes") == 2 * 2 * 24 * 16 * 4
+    # the two chunks' solves (16 x 16) share one 128-lane row a head
+    assert read("solve_bytes") == 2 * 1 * 16 * 128 * 4
     assert read("vmem_bytes") > 0
+    # at the cell's shapes two chunks of 64 share a row: no lane is padding
+    assert plan["solve_bytes"] == 128 * 30 * 64 * 64 * 4 == 62_914_560
+
+
+def _kernel_operands(args, chunk):
+    """q, k, v, beta and the running sums as ``gated_delta`` hands them to
+    the kernels (heads first; no padding needed at these lengths)."""
+    q, k, v, beta, g = args
+    L, H, _ = q.shape
+    heads = lambda t: jnp.transpose(t, (1, 0, 2))
+    rows = lambda t: t.T.reshape(H, L // chunk, chunk)
+    return (heads(q), heads(k), heads(v), rows(beta),
+            jnp.cumsum(rows(g), axis=-1))
+
+
+def test_the_saved_solve_is_the_inverse_of_each_chunk():
+    # the forward writes each chunk's X = (I + A)^-1 for the backward to
+    # read; against a float64 solve of every chunk of every head
+    from distributedarrays_tpu.ops import pallas_gated_delta as GD
+    chunk = 16
+    args, _ = _gdn_case(160, 10, 8, 16, 0.0, 0.5, 0.0, 2.0)
+    ops = _kernel_operands(args, chunk)
+    _, res = GD._gdn_fwd(*ops, True)
+    saved = np.asarray(res[-1], np.float64)
+    k = np.asarray(ops[1], np.float64)
+    beta, b = (np.asarray(t, np.float64) for t in ops[3:])
+    H, nc = beta.shape[:2]
+    pack = saved.shape[3] // chunk
+    worst = 0.0
+    for h in range(H):
+        for c in range(nc):
+            kc = k[h, c * chunk:(c + 1) * chunk]
+            decay = np.exp(np.tril(b[h, c][:, None] - b[h, c][None, :]))
+            a = np.tril(beta[h, c][:, None] * (kc @ kc.T) * decay, -1)
+            want = np.linalg.inv(np.eye(chunk) + a)
+            got = saved[h, c // pack, :,
+                        (c % pack) * chunk:(c % pack + 1) * chunk]
+            worst = max(worst, np.abs(got - want).max() / np.abs(want).max())
+    assert worst < 2e-5, worst
+
+
+def _highest_products(jaxpr):
+    """{kernel name: dot_generals at HIGHEST in its body} over every
+    ``pallas_call`` reached from ``jaxpr``."""
+    found = {}
+
+    def products(jx):
+        n = 0
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "dot_general" and eqn.params.get(
+                    "precision") == (jax.lax.Precision.HIGHEST,) * 2:
+                n += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += products(sub)
+        return n
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = products(
+                    eqn.params["jaxpr"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr)
+    return found
+
+
+def test_the_backward_reads_the_solve_and_does_not_solve_again():
+    # one head a grid step at the default chunk of 64: the forward's solve
+    # is six doubling levels of two float32 products; the backward holds
+    # only the two of dA = -X^T (dT beta) X^T
+    args, w = _gdn_case(64, 1, 8, 8, 0.0, 0.1, 0.0, 2.0)
+    grad = jax.grad(lambda *a: jnp.sum(gated_delta(*a) * w),
+                    argnums=(0, 1, 2, 3, 4))
+    assert _highest_products(jax.make_jaxpr(grad)(*args).jaxpr) == {
+        "gdn_fwd": 12, "gdn_bwd": 2}
 
 
 # ---------------------------------------------------------------------------
