@@ -50,9 +50,12 @@ KINDS = ("linear_attention", "full_attention")
 SCOPES = ("embed", "block/linear", "block/attn", "block/mlp", "head_loss",
           "optimizer")
 # What a recomputed layer keeps of its forward: the MLP's up-projection,
-# named in ``mamba2_hybrid._mlp``; the mixers' projections are computed
-# again (PERF.md, section 4: the step then needs 14.35 GB of the chip).
-_KEEP = jax.checkpoint_policies.save_only_these_names("mlp_up")
+# named in ``mamba2_hybrid._mlp``, and the delta rule's chunk solves,
+# named in ``pallas_gated_delta._gdn_fwd`` (62,914,560 bytes a layer at
+# the cell's widths, so the recomputed forward runs the recurrence alone);
+# the mixers' projections are computed again (PERF.md, section 4: the step
+# then needs 14.54 GB of the chip).
+_KEEP = jax.checkpoint_policies.save_only_these_names("mlp_up", "gdn_solve")
 _L2_EPS = 1e-6
 
 

@@ -31,14 +31,17 @@ MXU's full precision.  Every exponent is a difference ``b_i - b_j`` with
 ``i >= j`` or a running sum itself, never above 0, so nothing overflows.
 
 Layout.  Heads lead: q, k, v and o are (H, L, width) and a grid step holds
-``head_block`` heads of one chunk; ``beta`` and the running sums come as
-(H, chunks, C) and stay resident for a head block while its chunks go by.
-The grid is (head blocks, chunks) with the chunks innermost; a block's
-states persist in a scratch between chunks.  The forward saves two things
-a chunk: the state it starts from (H x chunks x dv x dk float32) and its
-solve ``X = (I + A)^-1`` (C x C float32 a chunk and head, consecutive
-chunks side by side in 128-lane rows), which depends on k, beta and the
-gates alone.  The backward walks the chunks last to
+``head_block`` heads; ``beta`` and the running sums come as (H, chunks, C)
+and stay resident for a head block while its chunks go by.  The forward
+is two kernels, both named ``gdn_fwd``.  The solve writes each chunk's
+``X = (I + A)^-1`` (C x C float32 a chunk and head, consecutive chunks side
+by side in 128-lane rows, a grid step a row), which depends on k, beta
+and the gates alone: it carries no state, and ``_gdn_fwd`` names its
+result ``"gdn_solve"`` so that a recomputation policy may keep it.  The
+recurrence reads ``X``, walks the chunks first to last (grid (head blocks,
+chunks), a block's states in a scratch between chunks), and writes ``o``
+and the state each chunk starts from (H x chunks x dv x dk float32).  The
+backward walks the chunks last to
 first, reads a chunk's ``X`` and takes ``T = X diag(beta)`` from it,
 computes what is cheap again (``K K^T``, ``G``, ``W``, ``V'`` from the
 saved state, ``Q K^T``), carries the state's cotangent in VMEM, and gives
@@ -49,8 +52,11 @@ activations' type (bf16 in training) and accumulate in float32; the
 solve, the states and every sum are float32.
 
 ``custom_vjp``: ``gated_delta`` is differentiable in all five arguments.
-The plan (chunk, chunks, heads a block, VMEM asked for, the bytes of the
-saved states and of the saved solves) is published as the gauge
+A caller without a recomputation runs the solve once and the recurrence
+once; under ``jax.checkpoint`` its policy decides whether the recomputed
+forward solves again.  The plan (chunk, chunks, heads a block, the VMEM
+the three kernels ask for, the bytes of the saved states and of the
+saved solves) is published as the gauge
 ``pallas.gated_delta.plan`` when a program is built (docs/telemetry.md).
 Interpreter mode runs the same kernels off-TPU.
 """
@@ -61,6 +67,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -133,22 +140,21 @@ def _unit_lower_inverse(a, m: _Masks):
     return x
 
 
-def _prelude(k, brow, betarow, m: _Masks):
-    """A chunk's state-free (C, C) part, which the solve is made from and
-    the backward takes again: the running sums and beta as columns, ``G``,
-    ``K K^T`` and ``b_C`` as a row."""
-    bcol, betacol = _col(brow, m), _col(betarow, m)
+def _prelude(brow, m: _Masks):
+    """A chunk's state-free (C, C) part that every kernel takes again: the
+    running sums as a column, ``G`` and ``b_C`` as a row."""
+    bcol = _col(brow, m)
     gam = jnp.exp(jnp.where(m.lower, bcol - brow, -jnp.inf))      # G
-    kk = _dot(k, k, _NT)
     bend = jnp.sum(jnp.where(m.jj == m.c - 1, brow, 0.0), axis=1,
                    keepdims=True)                                # b_C a row
-    return dict(bcol=bcol, betacol=betacol, gam=gam, kk=kk, bend=bend)
+    return dict(bcol=bcol, gam=gam, bend=bend)
 
 
-def _solve(pre, m: _Masks):
-    """The chunk's ``X = (I + A)^-1``: the forward's alone, which writes it
-    out for the backward."""
-    a = jnp.where(m.strict, pre["betacol"] * pre["kk"] * pre["gam"], 0.0)
+def _solve(k, betarow, pre, m: _Masks):
+    """The chunk's ``X = (I + A)^-1``: the solve kernel's alone, which
+    writes it out for the recurrence and the backward."""
+    a = jnp.where(m.strict, _col(betarow, m) * _dot(k, k, _NT) * pre["gam"],
+                  0.0)
     return _unit_lower_inverse(a, m)
 
 
@@ -177,16 +183,6 @@ def _pack(chunk: int) -> int:
     return 128 // chunk if 128 % chunk == 0 else 1
 
 
-def _put_solve(x_ref, i, c, x):
-    """Chunk ``c``'s solve into its lanes of head ``i``'s resident row."""
-    w = x.shape[1]
-    pack = x_ref.shape[3] // w
-    for r in range(pack):
-        @pl.when(c % pack == r)
-        def _put(r=r):
-            x_ref[i, 0, :, r * w:(r + 1) * w] = x
-
-
 def _get_solve(x_ref, i, c, w):
     """Chunk ``c``'s solve, (w, w), from head ``i``'s resident row."""
     pack = x_ref.shape[3] // w
@@ -205,8 +201,36 @@ def _end_scale(bcol, like, m: _Masks):
                            axis=0, keepdims=True))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, o_ref, ck_ref, x_ref,
+def _solve_kernel(k_ref, beta_ref, b_ref, x_ref):
+    """The solves of one row of the saved ``X`` for a head block: the row's
+    ``pack`` consecutive chunks side by side in its lanes.  No state is
+    carried, so every grid step stands alone.  The heads go by in a loop:
+    unrolled, their solves overlap by some 5%, but every equation of a
+    kernel's body is lowered again for each of its calls in a program, at
+    set-up, which the unrolled body lengthened by more (PERF.md, section
+    6)."""
+    w = beta_ref.shape[2]
+    pack = x_ref.shape[3] // w
+    first = pl.program_id(1) * pack
+    m = _Masks(w)
+
+    def head(i, carry):
+        for p in range(pack):
+            c = first + p
+            pre = _prelude(_chunk_row(b_ref[i], c), m)
+            x_ref[i, 0, :, p * w:(p + 1) * w] = _solve(
+                k_ref[i, p * w:(p + 1) * w], _chunk_row(beta_ref[i], c),
+                pre, m)
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0], head, 0)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, x_ref, o_ref, ck_ref,
                 s_scr, *, hb: int):
+    """The recurrence: one chunk (the grid walks them first to last) of one
+    head block, from the chunk's solve read from ``x_ref``; ``s_scr``
+    carries each head's state from chunk to chunk."""
     c = pl.program_id(1)
     m = _Masks(q_ref.shape[1])
 
@@ -219,11 +243,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, o_ref, ck_ref, x_ref,
         cdt = q.dtype
         s = s_scr[i]                                             # (dv, dk)
         ck_ref[i, 0] = s
-        betarow = _chunk_row(beta_ref[i], c)
-        pre = _prelude(k, _chunk_row(b_ref[i], c), betarow, m)
-        x = _solve(pre, m)
-        _put_solve(x_ref, i, c, x)
-        f = _forward_parts(q, k, v, x, betarow, s, pre, m)
+        f = _forward_parts(q, k, v, _get_solve(x_ref, i, c, m.c),
+                           _chunk_row(beta_ref[i], c), s,
+                           _prelude(_chunk_row(b_ref[i], c), m), m)
         qg = (q.astype(_F32) * f["eb"]).astype(cdt)
         o_ref[i] = (_dot(qg, s.astype(cdt), _NT)
                     + _dot(f["p"].astype(cdt), f["vn"].astype(cdt))
@@ -260,9 +282,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, ck_ref, x_ref, do_ref,
         brow = _chunk_row(b_ref[i], at)
         betarow = _chunk_row(beta_ref[i], at)
         x = _get_solve(x_ref, i, at, m.c)
-        f = _forward_parts(q, k, v, x, betarow, s0,
-                           _prelude(k, brow, betarow, m), m)
-        eb, gam, kk, t = f["eb"], f["gam"], f["kk"], f["t"]
+        f = _forward_parts(q, k, v, x, betarow, s0, _prelude(brow, m), m)
+        eb, gam, t = f["eb"], f["gam"], f["t"]
+        kk, betacol = _dot(k, k, _NT), _col(betarow, m)
         vnc = f["vn"].astype(cdt)
         tc = t.astype(cdt)
         qg = q.astype(_F32) * eb
@@ -302,8 +324,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, beta_ref, b_ref, ck_ref, x_ref, do_ref,
         da = jnp.where(m.strict, da, 0.0)
         # A = diag(beta) (K K^T * G), strictly lower
         dbeta_col = rsum(da * kk * gam)
-        dkk = (f["betacol"] * da * gam).astype(cdt)
-        dgam = dgam + f["betacol"] * da * kk
+        dkk = (betacol * da * gam).astype(cdt)
+        dgam = dgam + betacol * da * kk
         dk = dk + _dot(dkk, k) + _dot(dkk, k, _TN)
         # G = exp(b_i - b_j)
         mm = dgam * gam
@@ -325,12 +347,12 @@ def _lanes(n: int) -> int:
 
 def _vmem_bytes(c: int, hb: int, nc: int, dk: int, dv: int,
                 itemsize: int) -> int:
-    """What the backward (the larger kernel) holds in VMEM a grid step,
-    from its specs: the q, k, v, dq, dk, dv blocks and dO (float32), the
-    checkpoint block, the solve's block, the resident rows of beta, b, dbeta
-    and db, every block in two buffers; the states' cotangents (scratch);
-    and a head's (C, C) and (C, width) float32 temporaries, some thirty of
-    each."""
+    """What the backward (the largest of the three kernels) holds in VMEM
+    a grid step, from its specs: the q, k, v, dq, dk, dv blocks and dO
+    (float32), the checkpoint block, the solve's block, the resident rows
+    of beta, b, dbeta and db, every block in two buffers; the states'
+    cotangents (scratch); and a head's (C, C) and (C, width) float32
+    temporaries, some thirty of each."""
     kw, vw = _lanes(dk), _lanes(dv)
     blocks = hb * c * (4 * kw * itemsize + 2 * vw * itemsize + vw * 4)
     blocks += hb * dv * kw * 4 + hb * c * _lanes(c) * 4
@@ -360,9 +382,10 @@ def gated_delta_plan(L: int, H: int, dk: int, dv: int,
 @functools.lru_cache(maxsize=32)
 def _build(Lp: int, H: int, dk: int, dv: int, chunk: int, cdt: str,
            interpret: bool):
-    """(forward call, backward call) on padded operands: q, k (H, Lp, dk)
-    and v (H, Lp, dv) in ``cdt``; beta and the running sums (H, chunks,
-    chunk) float32."""
+    """(solve call, recurrence call, backward call) on padded operands: q,
+    k (H, Lp, dk) and v (H, Lp, dv) in ``cdt``; beta and the running sums
+    (H, chunks, chunk) float32.  The solve and the recurrence are both
+    named ``gdn_fwd``: together they are the forward."""
     plan = gated_delta_plan(Lp, H, dk, dv, chunk, jnp.dtype(cdt).itemsize)
     hb, nc, pack = plan["head_block"], Lp // chunk, _pack(chunk)
     for what in ("chunk", "chunks", "head_block", "vmem_bytes",
@@ -381,15 +404,26 @@ def _build(Lp: int, H: int, dk: int, dv: int, chunk: int, cdt: str,
         return keys, vals, rows, ck, xs
 
     keys, vals, rows, ck, xs = specs(lambda c: c)
+    nr = -(-nc // pack)
+    solve = pl.pallas_call(
+        _solve_kernel,
+        grid=(H // hb, nr),
+        in_specs=[pl.BlockSpec((hb, pack * chunk, dk),
+                               lambda j, r: (j, r, 0)), rows, rows],
+        out_specs=pl.BlockSpec((hb, 1, chunk, pack * chunk),
+                               lambda j, r: (j, r, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((H, nr, chunk, pack * chunk), _F32),
+        compiler_params=params,
+        name="gdn_fwd",
+        interpret=interpret,
+    )
     fwd = pl.pallas_call(
         functools.partial(_fwd_kernel, hb=hb),
         grid=(H // hb, nc),
-        in_specs=[keys, keys, vals, rows, rows],
-        out_specs=(vals, ck, xs),
+        in_specs=[keys, keys, vals, rows, rows, xs],
+        out_specs=(vals, ck),
         out_shape=(jax.ShapeDtypeStruct((H, Lp, dv), _F32),
-                   jax.ShapeDtypeStruct((H, nc, dv, dk), _F32),
-                   jax.ShapeDtypeStruct((H, -(-nc // pack), chunk,
-                                         pack * chunk), _F32)),
+                   jax.ShapeDtypeStruct((H, nc, dv, dk), _F32)),
         scratch_shapes=[pltpu.VMEM((hb, dv, dk), _F32)],
         compiler_params=params,
         name="gdn_fwd",
@@ -411,7 +445,7 @@ def _build(Lp: int, H: int, dk: int, dv: int, chunk: int, cdt: str,
         name="gdn_bwd",
         interpret=interpret,
     )
-    return fwd, bwd
+    return solve, fwd, bwd
 
 
 def _calls(q, v, b, interpret):
@@ -425,14 +459,18 @@ def _gdn_core(q, k, v, beta, b, interpret):
 
 
 def _gdn_fwd(q, k, v, beta, b, interpret):
-    fwd, _ = _calls(q, v, b, interpret)
-    o, ck, x = fwd(q, k, v, beta, b)
+    solve, fwd, _ = _calls(q, v, b, interpret)
+    # named, so that a caller's recomputation policy may keep the solves
+    # (``models.olmo_hybrid._KEEP``): a recomputed forward then runs the
+    # recurrence alone
+    x = checkpoint_name(solve(k, beta, b), "gdn_solve")
+    o, ck = fwd(q, k, v, beta, b, x)
     return o, (q, k, v, beta, b, ck, x)
 
 
 def _gdn_bwd(interpret, res, do):
     q, k, v, beta, b, ck, x = res
-    _, bwd = _calls(q, v, b, interpret)
+    bwd = _calls(q, v, b, interpret)[2]
     return bwd(q, k, v, beta, b, ck, x, do)
 
 

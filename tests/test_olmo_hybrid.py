@@ -181,14 +181,15 @@ def _kernel_operands(args, chunk):
 
 
 def test_the_saved_solve_is_the_inverse_of_each_chunk():
-    # the forward writes each chunk's X = (I + A)^-1 for the backward to
-    # read; against a float64 solve of every chunk of every head
+    # the solve kernel writes each chunk's X = (I + A)^-1 for the
+    # recurrence and the backward to read; against a float64 solve of
+    # every chunk of every head
     from distributedarrays_tpu.ops import pallas_gated_delta as GD
     chunk = 16
     args, _ = _gdn_case(160, 10, 8, 16, 0.0, 0.5, 0.0, 2.0)
     ops = _kernel_operands(args, chunk)
-    _, res = GD._gdn_fwd(*ops, True)
-    saved = np.asarray(res[-1], np.float64)
+    solve = GD._calls(ops[0], ops[2], ops[4], True)[0]
+    saved = np.asarray(solve(*ops[1:2], *ops[3:]), np.float64)
     k = np.asarray(ops[1], np.float64)
     beta, b = (np.asarray(t, np.float64) for t in ops[3:])
     H, nc = beta.shape[:2]
@@ -206,10 +207,11 @@ def test_the_saved_solve_is_the_inverse_of_each_chunk():
     assert worst < 2e-5, worst
 
 
-def _highest_products(jaxpr):
-    """{kernel name: dot_generals at HIGHEST in its body} over every
-    ``pallas_call`` reached from ``jaxpr``."""
-    found = {}
+def _kernels(jaxpr):
+    """[(kernel name, dot_generals at HIGHEST in its body)] of every
+    ``pallas_call`` reached from ``jaxpr``, sorted: the solve and the
+    recurrence share the name ``gdn_fwd`` and differ in their products."""
+    found = []
 
     def products(jx):
         n = 0
@@ -224,24 +226,50 @@ def _highest_products(jaxpr):
     def walk(jx):
         for eqn in jx.eqns:
             if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] = products(
-                    eqn.params["jaxpr"])
+                found.append((eqn.params["name"],
+                              products(eqn.params["jaxpr"])))
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
     walk(jaxpr)
-    return found
+    return sorted(found)
+
+
+# at the default chunk of 64 and one head a grid step: the solve kernel
+# takes the two chunks of a row of X, each six doubling levels of two
+# float32 products; the recurrence reads X and holds none; the backward
+# holds only the two of dA = -X^T (dT beta) X^T
+SOLVE, RECURRENCE, BACKWARD = ("gdn_fwd", 24), ("gdn_fwd", 0), ("gdn_bwd", 2)
 
 
 def test_the_backward_reads_the_solve_and_does_not_solve_again():
-    # one head a grid step at the default chunk of 64: the forward's solve
-    # is six doubling levels of two float32 products; the backward holds
-    # only the two of dA = -X^T (dT beta) X^T
     args, w = _gdn_case(64, 1, 8, 8, 0.0, 0.1, 0.0, 2.0)
     grad = jax.grad(lambda *a: jnp.sum(gated_delta(*a) * w),
                     argnums=(0, 1, 2, 3, 4))
-    assert _highest_products(jax.make_jaxpr(grad)(*args).jaxpr) == {
-        "gdn_fwd": 12, "gdn_bwd": 2}
+    assert _kernels(jax.make_jaxpr(grad)(*args).jaxpr) == sorted(
+        [SOLVE, RECURRENCE, BACKWARD])
+
+
+@pytest.mark.parametrize("policy", ["the_model_keeps_the_solve",
+                                    "a_policy_without_the_solve"])
+def test_a_recomputed_layer_solves_once(policy, monkeypatch):
+    # two linear-attention layers under jax.checkpoint: with _KEEP the
+    # solve runs once a layer (in the forward) and the recurrence twice
+    # (forward, and again in the backward); a policy that forgets
+    # "gdn_solve" solves twice a layer
+    if policy == "a_policy_without_the_solve":
+        monkeypatch.setattr(M, "_KEEP", jax.checkpoint_policies
+                            .save_only_these_names("mlp_up"))
+    cfg = M.Config(vocab=96, dim=64, ffn=96, heads=4, head_dim=16,
+                   lin_heads=1, key_dim=16, value_dim=32, loss_rows=16,
+                   layers=((0, "linear_attention"), (1, "linear_attention")))
+    params = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+    tokens = jax.ShapeDtypeStruct((1, 73), jnp.int32)
+    found = _kernels(jax.make_jaxpr(
+        lambda p, t: jax.grad(M.loss_fn)(p, t, cfg))(params, tokens).jaxpr)
+    solves = 2 if policy == "the_model_keeps_the_solve" else 4
+    assert found == sorted([SOLVE] * solves + [RECURRENCE] * 4
+                           + [BACKWARD] * 2)
 
 
 # ---------------------------------------------------------------------------

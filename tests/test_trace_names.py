@@ -57,8 +57,12 @@ def test_every_pallas_call_has_a_stable_documented_name():
     unnamed = [site for site, name in calls if not name]
     assert not unnamed, f"pallas_call without a constant name=: {unnamed}"
     names = [name for _, name in calls]
-    assert len(set(names)) == len(names), "two kernels share a name"
-    assert sorted(names) == sorted(_documented_kernels())
+    # one name a kernel, but for the delta rule's forward: its solve and
+    # its recurrence are both gdn_fwd, so that gdn_scan_ms reads both
+    shared = sorted(n for n in set(names) if names.count(n) > 1)
+    assert shared == ["gdn_fwd"] and names.count("gdn_fwd") == 2, \
+        f"kernels that share a name: {shared}"
+    assert sorted(set(names)) == sorted(_documented_kernels())
     # kind only, no shapes: the event carries those
     assert all(re.fullmatch(r"[a-z][a-z0-9_]*", n) for n in names)
 
