@@ -23,16 +23,13 @@ tied to the embedding.
 Training: every layer runs under ``jax.checkpoint`` (its input and the
 products ``_KEEP`` names are all that the backward keeps of it; the rest
 is computed again there), the loss is taken over row blocks of the sequence
-(``transformer.blocked_nll``), and ``make_optax_train_step`` goes through
-the float32-master step that ``models.transformer`` trains with.
+(``common.blocked_nll``), and ``make_optax_train_step`` goes through the
+float32-master step of ``models.common``.
 """
 
 from __future__ import annotations
 
 import functools
-import math
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +37,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pallas_attention import flash_attention
 from ..ops.pallas_ssd import ssd
-from .transformer import blocked_nll, optax_f32_step
+from . import common
+from .common import (blocked_nll, fold, gated_silu, init_layers, init_leaf,
+                     rms_norm, unfold)
 
 __all__ = ["Config", "KINDS", "SCOPES", "leaf_shapes", "init_params",
            "forward", "loss_fn", "make_optax_train_step"]
@@ -126,22 +125,9 @@ def leaf_shapes(cfg: Config, kind: str):
     return out
 
 
-def _init_leaf(key, shape, how, dtype):
-    if how == "ones":
-        return jnp.ones(shape, dtype)
-    if how == "zeros":
-        return jnp.zeros(shape, dtype)
-    if how == "A_log":
-        return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32)
-                       ).astype(dtype)
-    if how == "dt_bias":
-        # softplus(dt_bias) is log-uniform in [1e-3, 1e-1] (the Mamba-2
-        # family's default): dt_bias = dt + log(-expm1(-dt))
-        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
-                     * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    return (jax.random.normal(key, shape, jnp.float32)
-            * np.float32(1.0 / np.sqrt(how))).astype(dtype)
+# the Mamba-2 family's A: 1..heads
+_STARTS = {"A_log": lambda key, shape, dtype: jnp.log(
+    jnp.arange(1, shape[0] + 1, dtype=jnp.float32)).astype(dtype)}
 
 
 def init_params(key, cfg: Config):
@@ -150,46 +136,15 @@ def init_params(key, cfg: Config):
     the Mamba-2 family's defaults for ``A_log`` (log 1..heads), ``D_skip``
     (1) and ``dt_bias``."""
     dt = cfg.dtype
-    params = {"embed": _init_leaf(jax.random.fold_in(key, 0),
-                                  (cfg.vocab, cfg.dim), cfg.dim, dt),
-              "norm_f": jnp.ones((cfg.dim,), dt), "layers": []}
-    for n, (_, kind) in enumerate(cfg.layers):
-        lk = jax.random.fold_in(key, n + 1)
-        params["layers"].append({
-            name: _init_leaf(jax.random.fold_in(lk, j), shape, how, dt)
-            for j, (name, (shape, how)) in enumerate(
-                sorted(leaf_shapes(cfg, kind).items()))})
-    return params
+    return {"embed": init_leaf(jax.random.fold_in(key, 0),
+                               (cfg.vocab, cfg.dim), cfg.dim, dt),
+            "norm_f": jnp.ones((cfg.dim,), dt),
+            "layers": init_layers(key, cfg, leaf_shapes, 1, _STARTS)}
 
 
 # ---------------------------------------------------------------------------
 # the layers
 # ---------------------------------------------------------------------------
-
-
-def _rms(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    n = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (n * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _mlp(u, p):
-    # kept by a recomputed layer (``_KEEP``)
-    g, v = jnp.split(checkpoint_name(u @ p["w1"], "mlp_up"), 2, axis=-1)
-    return (jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32)
-            ).astype(u.dtype) @ p["w2"]
-
-
-def _fold(t):
-    """(B, S, H, W) -> (S, B * H, W): the batch folds into the head axis,
-    so one kernel call covers it (a group never straddles two rows)."""
-    B, S, H, W = t.shape
-    return jnp.transpose(t, (1, 0, 2, 3)).reshape(S, B * H, W)
-
-
-def _unfold(t, B):
-    S, BH, W = t.shape
-    return jnp.transpose(t.reshape(S, B, BH // B, W), (1, 0, 2, 3))
 
 
 def _mamba(u, p, cfg):
@@ -207,11 +162,11 @@ def _mamba(u, p, cfg):
                          + p["dt_bias"].astype(jnp.float32))      # (B, S, Hs)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
     xh = x.reshape(B, S, Hs, P)
-    y = ssd(_fold(xh), _fold(dt[..., None])[..., 0], jnp.tile(a, B),
-            _fold(bm.reshape(B, S, G, N)), _fold(cm.reshape(B, S, G, N)),
+    y = ssd(fold(xh), fold(dt[..., None])[..., 0], jnp.tile(a, B),
+            fold(bm.reshape(B, S, G, N)), fold(cm.reshape(B, S, G, N)),
             chunk=cfg.chunk)
-    y = _unfold(y, B) + (p["D_skip"].astype(jnp.float32)[:, None]
-                         * xh.astype(jnp.float32))
+    y = unfold(y, B) + (p["D_skip"].astype(jnp.float32)[:, None]
+                        * xh.astype(jnp.float32))
     g = y.reshape(B, S, E) * jax.nn.silu(z.astype(jnp.float32))
     g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.eps)
     g = g * p["norm_gated"].astype(jnp.float32)
@@ -235,9 +190,11 @@ def _layer(x, p, *, kind, cfg):
     mixer = (jax.named_scope("mamba"), _mamba) if kind == "mamba" else \
         (jax.named_scope("attn"), _attention)
     with jax.named_scope("block"), mixer[0]:
-        h = x + r * mixer[1](_rms(x, p["norm1"], cfg.eps), p, cfg)
+        h = x + r * mixer[1](rms_norm(x, p["norm1"], cfg.eps), p, cfg)
     with jax.named_scope("block"), jax.named_scope("mlp"):
-        return h + r * _mlp(_rms(h, p["norm2"], cfg.eps), p)
+        # the up-projection is kept by a recomputed layer (``_KEEP``)
+        return h + r * gated_silu(rms_norm(h, p["norm2"], cfg.eps),
+                                  p["w1"], p["w2"], "mlp_up")
 
 
 def _trunk(params, tok, cfg: Config, remat: bool):
@@ -249,7 +206,7 @@ def _trunk(params, tok, cfg: Config, remat: bool):
         if remat:
             fn = jax.checkpoint(fn, policy=_KEEP)
         x = fn(x, p)
-    return _rms(x, params["norm_f"], cfg.eps)
+    return rms_norm(x, params["norm_f"], cfg.eps)
 
 
 def forward(params, tokens, cfg: Config):
@@ -277,10 +234,7 @@ def loss_fn(params, tokens, cfg: Config):
 
 
 def make_optax_train_step(cfg: Config, tx):
-    """``(step, init)`` as ``models.transformer.make_optax_train_step``
-    gives them, for this model: one jit of ``value_and_grad(loss_fn)`` and
+    """``(step, init)`` as ``models.common.make_optax_train_step`` gives
+    them, for this model: one jit of ``value_and_grad(loss_fn)`` and
     ``tx.update`` in float32 master precision with donated state."""
-    def grad_fn(params, tokens):
-        return jax.value_and_grad(loss_fn)(params, tokens, cfg)
-
-    return optax_f32_step(tx, grad_fn, SCOPES)
+    return common.make_optax_train_step(loss_fn, cfg, tx, SCOPES)

@@ -32,24 +32,21 @@ both losses are over the slice).
 
 Training: the FFN half of every layer runs under ``jax.checkpoint`` (PERF.md
 says what was read for the choice), the losses are taken over row blocks of
-the sequence (``transformer.blocked_nll``), and ``make_optax_train_step``
-goes through the float32-master step that ``models.transformer`` trains
-with.
+the sequence (``common.blocked_nll``), and ``make_optax_train_step`` goes
+through the float32-master step of ``models.common``.
 """
 
 from __future__ import annotations
 
 import functools
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pallas_attention import flash_attention
+from .common import (blocked_nll, gated_silu, init_layer, init_layers,
+                     init_leaf, optax_f32_step, rms_norm)
 from .moe import held_experts_ffn, route_sigmoid_topk
-from .transformer import blocked_nll, optax_f32_step
 
 __all__ = ["Config", "KINDS", "SCOPES", "published_layers", "rope",
            "init_params", "forward", "loss_parts", "loss_fn",
@@ -161,40 +158,24 @@ def leaf_shapes(cfg: Config, kind: str):
     return out
 
 
-def _init_leaf(key, shape, how, dtype):
-    if how == "ones":
-        return jnp.ones(shape, dtype)
-    if how == "zeros":
-        return jnp.zeros(shape, dtype)
-    return (jax.random.normal(key, shape, jnp.float32)
-            * np.float32(1.0 / np.sqrt(how))).astype(dtype)
-
-
-def _init_layer(key, cfg, kind):
-    return {name: _init_leaf(jax.random.fold_in(key, j), shape, how,
-                             cfg.dtype)
-            for j, (name, (shape, how)) in enumerate(
-                sorted(leaf_shapes(cfg, kind).items()))}
-
-
 def init_params(key, cfg: Config):
     """{"embed", "head", "norm_f", "layers": [{...}], "mtp": {...}}:
     matrices normal with deviation 1/sqrt(fan_in), norm scales 1, the
     selection bias 0."""
     D, dt = cfg.dim, cfg.dtype
     params = {
-        "embed": _init_leaf(jax.random.fold_in(key, 0), (cfg.vocab, D), D, dt),
-        "head": _init_leaf(jax.random.fold_in(key, 1), (cfg.vocab, D), D, dt),
+        "embed": init_leaf(jax.random.fold_in(key, 0), (cfg.vocab, D), D, dt),
+        "head": init_leaf(jax.random.fold_in(key, 1), (cfg.vocab, D), D, dt),
         "norm_f": jnp.ones((D,), dt),
-        "layers": [_init_layer(jax.random.fold_in(key, n + 2), cfg, kind)
-                   for n, (_, kind) in enumerate(cfg.layers)]}
+        "layers": init_layers(key, cfg, leaf_shapes, 2)}
     if cfg.mtp is not None:
         mk = jax.random.fold_in(key, len(cfg.layers) + 2)
         params["mtp"] = {
             "enorm": jnp.ones((D,), dt), "hnorm": jnp.ones((D,), dt),
-            "eh_proj": _init_leaf(jax.random.fold_in(mk, 0), (2 * D, D),
-                                  2 * D, dt),
-            "block": _init_layer(jax.random.fold_in(mk, 1), cfg, "moe"),
+            "eh_proj": init_leaf(jax.random.fold_in(mk, 0), (2 * D, D),
+                                 2 * D, dt),
+            "block": init_layer(jax.random.fold_in(mk, 1),
+                                leaf_shapes(cfg, "moe"), dt),
             "norm": jnp.ones((D,), dt)}
     return params
 
@@ -202,12 +183,6 @@ def init_params(key, cfg: Config):
 # ---------------------------------------------------------------------------
 # the layers
 # ---------------------------------------------------------------------------
-
-
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    n = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (n * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def rope(x, theta: float):
@@ -226,10 +201,10 @@ def rope(x, theta: float):
 def _mla(u, p, cfg):
     B, S, _ = u.shape
     H, N, R, V = cfg.heads, cfg.nope, cfg.rope, cfg.v_dim
-    cq = _rmsnorm(u @ p["wqa"], p["q_norm"], cfg.eps)
+    cq = rms_norm(u @ p["wqa"], p["q_norm"], cfg.eps)
     q = (cq @ p["wqb"]).reshape(B, S, H, N + R)
     kva = u @ p["wkva"]
-    ckv = _rmsnorm(kva[..., :cfg.kv_rank], p["kv_norm"], cfg.eps)
+    ckv = rms_norm(kva[..., :cfg.kv_rank], p["kv_norm"], cfg.eps)
     kr = rope(kva[..., cfg.kv_rank:].reshape(B, S, 1, R), cfg.rope_theta)
     kv = (ckv @ p["wkvb"]).reshape(B, S, H, N + V)
     q = jnp.concatenate([q[..., :N], rope(q[..., N:], cfg.rope_theta)],
@@ -243,15 +218,14 @@ def _mla(u, p, cfg):
     return o.reshape(B, S, H * V) @ p["wo"]
 
 
-def _gated(u, w1, w2):
-    g, v = jnp.split(checkpoint_name(u @ w1, "ffn_up"), 2, axis=-1)
-    return (jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32)
-            ).astype(u.dtype) @ w2
+# the dense FFN and the shared expert; the up-projection is kept by the
+# recomputed FFN half (``_KEEP``)
+_gated = functools.partial(gated_silu, name="ffn_up")
 
 
 def _ffn_half(h, p, *, kind, cfg):
     """``h + FFN(RMS2(h))`` of a layer of ``kind``."""
-    u = _rmsnorm(h, p["ln2"], cfg.eps)
+    u = rms_norm(h, p["ln2"], cfg.eps)
     if kind == "dense":
         with jax.named_scope("block"), jax.named_scope("mlp"):
             return h + _gated(u, p["w1"], p["w2"])
@@ -269,7 +243,7 @@ def _layer(x, p, *, kind, cfg, remat, note=None):
     """One layer; ``note(h, p)`` sees an expert layer's input (before its
     norm) and parameters (``routing_stats``)."""
     with jax.named_scope("block"), jax.named_scope("mla"):
-        h = x + _mla(_rmsnorm(x, p["ln1"], cfg.eps), p, cfg)
+        h = x + _mla(rms_norm(x, p["ln1"], cfg.eps), p, cfg)
     if note is not None and kind == "moe":
         note(h, p)
     ffn = functools.partial(_ffn_half, kind=kind, cfg=cfg)
@@ -291,11 +265,11 @@ def _mtp_trunk(params, x, tok_next, cfg: Config, remat: bool, note=None):
     m = params["mtp"]
     with jax.named_scope("mtp"):
         e = params["embed"][tok_next].astype(cfg.dtype)
-        both = jnp.concatenate([_rmsnorm(e, m["enorm"], cfg.eps),
-                                _rmsnorm(x, m["hnorm"], cfg.eps)], axis=-1)
+        both = jnp.concatenate([rms_norm(e, m["enorm"], cfg.eps),
+                                rms_norm(x, m["hnorm"], cfg.eps)], axis=-1)
         h = _layer(both @ m["eh_proj"], m["block"], kind="moe", cfg=cfg,
                    remat=remat, note=note)
-        return _rmsnorm(h, m["norm"], cfg.eps)
+        return rms_norm(h, m["norm"], cfg.eps)
 
 
 def _logits(x, head):
@@ -310,7 +284,7 @@ def forward(params, tokens, cfg: Config, mtp: bool = False):
     predicts ``t_{i+2}``) come second."""
     tok = tokens[:, :-1] if mtp else tokens
     x = _trunk(params, tok, cfg, remat=False)
-    main = _logits(_rmsnorm(x, params["norm_f"], cfg.eps), params["head"])
+    main = _logits(rms_norm(x, params["norm_f"], cfg.eps), params["head"])
     if not mtp:
         return main
     return main, _logits(_mtp_trunk(params, x, tokens[:, 1:], cfg, False),
@@ -329,7 +303,7 @@ def loss_parts(params, tokens, cfg: Config):
     x = _trunk(params, tokens[:, :S], cfg, remat=True)
     rows = x.shape[0] * S
     with jax.named_scope("head_loss"):
-        main = blocked_nll(_rmsnorm(x, params["norm_f"], cfg.eps),
+        main = blocked_nll(rms_norm(x, params["norm_f"], cfg.eps),
                            params["head"], tokens[:, 1:S + 1],
                            cfg.loss_rows) / rows
     if cfg.mtp is None:
@@ -358,7 +332,7 @@ def routing_stats(params, tokens, cfg: Config):
     out = []
 
     def note(h, p):
-        u = _rmsnorm(h, p["ln2"], cfg.eps).reshape(-1, cfg.dim)
+        u = rms_norm(h, p["ln2"], cfg.eps).reshape(-1, cfg.dim)
         idx, _ = route_sigmoid_topk(u, p["router"], p["router_bias"],
                                     cfg.top_k, cfg.route_scale)
         counts = jnp.sum(idx.reshape(-1)[:, None] == jnp.arange(
@@ -375,8 +349,8 @@ def routing_stats(params, tokens, cfg: Config):
 
 
 def make_optax_train_step(cfg: Config, tx):
-    """``(step, init)`` as ``models.transformer.make_optax_train_step``
-    gives them, for this model: one jit of ``value_and_grad(loss_fn)`` and
+    """``(step, init)`` as ``models.common.make_optax_train_step`` gives
+    them, for this model: one jit of ``value_and_grad(loss_fn)`` and
     ``tx.update`` in float32 master precision with donated state.  The
     step's third result is the float32 vector ``[loss, L_main, L_mtp]``."""
     def grad_fn(params, tokens):

@@ -22,8 +22,8 @@ a learned scale; the embedding and the head are untied.
 Training: every layer runs under ``jax.checkpoint`` (its input and the
 products ``_KEEP`` names are all that the backward keeps of it; the rest
 is computed again there), the loss is taken over row blocks of the sequence
-(``transformer.blocked_nll``), and ``make_optax_train_step`` goes through
-the float32-master step that ``models.transformer`` trains with.
+(``common.blocked_nll``), and ``make_optax_train_step`` goes through the
+float32-master step of ``models.common``.
 """
 
 from __future__ import annotations
@@ -38,8 +38,9 @@ import jax.numpy as jnp
 
 from ..ops.pallas_attention import flash_attention
 from ..ops.pallas_gated_delta import gated_delta
-from .mamba2_hybrid import _fold, _init_leaf as _start, _mlp, _rms, _unfold
-from .transformer import blocked_nll, optax_f32_step
+from . import common
+from .common import (blocked_nll, fold, gated_silu, init_layers, init_leaf,
+                     rms_norm, unfold)
 
 __all__ = ["Config", "KINDS", "SCOPES", "leaf_shapes", "init_params",
            "forward", "loss_fn", "make_optax_train_step"]
@@ -50,7 +51,7 @@ KINDS = ("linear_attention", "full_attention")
 SCOPES = ("embed", "block/linear", "block/attn", "block/mlp", "head_loss",
           "optimizer")
 # What a recomputed layer keeps of its forward: the MLP's up-projection,
-# named in ``mamba2_hybrid._mlp``, and the delta rule's chunk solves,
+# named in ``_layer``, and the delta rule's chunk solves,
 # named in ``pallas_gated_delta._gdn_fwd`` (62,914,560 bytes a layer at
 # the cell's widths, so the recomputed forward runs the recurrence alone);
 # the mixers' projections are computed again (PERF.md, section 4: the step
@@ -118,12 +119,10 @@ def leaf_shapes(cfg: Config, kind: str):
     return out
 
 
-def _init_leaf(key, shape, how, dtype):
-    if how == "A_log":
-        # A uniform in (0, 16] (the Gated DeltaNet family's default)
-        a = 16.0 * (1.0 - jax.random.uniform(key, shape, jnp.float32))
-        return jnp.log(a).astype(dtype)
-    return _start(key, shape, how, dtype)
+def _a_log(key, shape, dtype):
+    # A uniform in (0, 16] (the Gated DeltaNet family's default)
+    a = 16.0 * (1.0 - jax.random.uniform(key, shape, jnp.float32))
+    return jnp.log(a).astype(dtype)
 
 
 def init_params(key, cfg: Config):
@@ -131,18 +130,13 @@ def init_params(key, cfg: Config):
     deviation 1/sqrt(fan_in), norm scales 1, and the Gated DeltaNet
     family's defaults for ``A_log`` and ``dt_bias``."""
     dt = cfg.dtype
-    params = {"embed": _init_leaf(jax.random.fold_in(key, 0),
-                                  (cfg.vocab, cfg.dim), cfg.dim, dt),
-              "head": _init_leaf(jax.random.fold_in(key, 1),
-                                 (cfg.vocab, cfg.dim), cfg.dim, dt),
-              "norm_f": jnp.ones((cfg.dim,), dt), "layers": []}
-    for n, (_, kind) in enumerate(cfg.layers):
-        lk = jax.random.fold_in(key, n + 2)
-        params["layers"].append({
-            name: _init_leaf(jax.random.fold_in(lk, j), shape, how, dt)
-            for j, (name, (shape, how)) in enumerate(
-                sorted(leaf_shapes(cfg, kind).items()))})
-    return params
+    return {"embed": init_leaf(jax.random.fold_in(key, 0),
+                               (cfg.vocab, cfg.dim), cfg.dim, dt),
+            "head": init_leaf(jax.random.fold_in(key, 1),
+                              (cfg.vocab, cfg.dim), cfg.dim, dt),
+            "norm_f": jnp.ones((cfg.dim,), dt),
+            "layers": init_layers(key, cfg, leaf_shapes, 2,
+                                  {"A_log": _a_log})}
 
 
 # ---------------------------------------------------------------------------
@@ -168,10 +162,10 @@ def _linear(u, p, cfg):
     beta = 2.0 * jax.nn.sigmoid(b)
     g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
         a + p["dt_bias"].astype(jnp.float32))
-    o = gated_delta(_fold(q.astype(u.dtype)), _fold(k.astype(u.dtype)),
-                    _fold(v.reshape(B, S, H, dv).astype(u.dtype)),
-                    _fold(beta[..., None])[..., 0], _fold(g[..., None])[..., 0])
-    o = _rms(_unfold(o, B), p["o_norm"], cfg.eps).reshape(B, S, H * dv)
+    o = gated_delta(fold(q.astype(u.dtype)), fold(k.astype(u.dtype)),
+                    fold(v.reshape(B, S, H, dv).astype(u.dtype)),
+                    fold(beta[..., None])[..., 0], fold(g[..., None])[..., 0])
+    o = rms_norm(unfold(o, B), p["o_norm"], cfg.eps).reshape(B, S, H * dv)
     o = o * jax.nn.silu(gate.astype(jnp.float32))
     return o.astype(u.dtype) @ p["w_o"]
 
@@ -180,7 +174,8 @@ def _attention(u, p, cfg):
     B, S, _ = u.shape
     H, hd = cfg.heads, cfg.head_dim
     q, k, v = jnp.split(u @ p["w_qkv"], 3, axis=-1)
-    q, k = _rms(q, p["q_norm"], cfg.eps), _rms(k, p["k_norm"], cfg.eps)
+    q = rms_norm(q, p["q_norm"], cfg.eps)
+    k = rms_norm(k, p["k_norm"], cfg.eps)
     o = flash_attention(q.reshape(B, S, H, hd), k.reshape(B, S, H, hd),
                         v.reshape(B, S, H, hd), causal=True,
                         scale=1.0 / math.sqrt(hd))
@@ -191,9 +186,11 @@ def _layer(x, p, *, kind, cfg):
     mixer = (jax.named_scope("linear"), _linear) \
         if kind == "linear_attention" else (jax.named_scope("attn"), _attention)
     with jax.named_scope("block"), mixer[0]:
-        h = x + _rms(mixer[1](x, p, cfg), p["post_mix_norm"], cfg.eps)
+        h = x + rms_norm(mixer[1](x, p, cfg), p["post_mix_norm"], cfg.eps)
     with jax.named_scope("block"), jax.named_scope("mlp"):
-        return h + _rms(_mlp(h, p), p["post_mlp_norm"], cfg.eps)
+        # the up-projection is kept by a recomputed layer (``_KEEP``)
+        return h + rms_norm(gated_silu(h, p["w1"], p["w2"], "mlp_up"),
+                            p["post_mlp_norm"], cfg.eps)
 
 
 def _trunk(params, tok, cfg: Config, remat: bool):
@@ -204,7 +201,7 @@ def _trunk(params, tok, cfg: Config, remat: bool):
         if remat:
             fn = jax.checkpoint(fn, policy=_KEEP)
         x = fn(x, p)
-    return _rms(x, params["norm_f"], cfg.eps)
+    return rms_norm(x, params["norm_f"], cfg.eps)
 
 
 def forward(params, tokens, cfg: Config):
@@ -228,10 +225,7 @@ def loss_fn(params, tokens, cfg: Config):
 
 
 def make_optax_train_step(cfg: Config, tx):
-    """``(step, init)`` as ``models.transformer.make_optax_train_step``
-    gives them, for this model: one jit of ``value_and_grad(loss_fn)`` and
+    """``(step, init)`` as ``models.common.make_optax_train_step`` gives
+    them, for this model: one jit of ``value_and_grad(loss_fn)`` and
     ``tx.update`` in float32 master precision with donated state."""
-    def grad_fn(params, tokens):
-        return jax.value_and_grad(loss_fn)(params, tokens, cfg)
-
-    return optax_f32_step(tx, grad_fn, SCOPES)
+    return common.make_optax_train_step(loss_fn, cfg, tx, SCOPES)

@@ -29,8 +29,8 @@ publishes and two products, the MLP's up-projection and the mixer's input
 projection, are all that the backward keeps of it; the rest of the layer
 is computed again there), the loss is taken over row blocks of the
 sequence so that the float32 logits are never held whole, and
-``make_optax_train_step`` goes through the float32-master step that
-``models.transformer`` trains with.
+``make_optax_train_step`` goes through the float32-master step of
+``models.common``.
 """
 
 from __future__ import annotations
@@ -38,15 +38,14 @@ from __future__ import annotations
 import functools
 import math
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pallas_attention import flash_attention
 from ..ops.pallas_selective_scan import selective_scan
-from .transformer import blocked_nll, optax_f32_step
+from . import common
+from .common import blocked_nll, gated_silu, init_layers, init_leaf
 
 __all__ = ["Config", "KINDS", "SCOPES", "layer_kinds", "lambda_init",
            "init_params", "forward", "loss_fn", "make_optax_train_step"]
@@ -176,25 +175,14 @@ def leaf_shapes(cfg: Config, kind: str):
     return out
 
 
-def _init_leaf(key, shape, how, dtype):
-    if how == "ones":
-        return jnp.ones(shape, dtype)
-    if how == "zeros":
-        return jnp.zeros(shape, dtype)
-    if how == "A_log":
-        return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1,
-                                                   dtype=jnp.float32)),
-                                shape).astype(dtype)
-    if how == "dt_bias":
-        # softplus(dt_b) is log-uniform in [1e-3, 1e-1] (the family's
-        # default): dt_b = dt + log(-expm1(-dt))
-        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
-                     * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    if how == "lambda":
-        return (0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
-    return (jax.random.normal(key, shape, jnp.float32)
-            * np.float32(1.0 / np.sqrt(how))).astype(dtype)
+# the Mamba family's A (log 1..d_state in every channel) and differential
+# attention's lambda vectors
+_STARTS = {
+    "A_log": lambda key, shape, dtype: jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[1] + 1, dtype=jnp.float32)),
+        shape).astype(dtype),
+    "lambda": lambda key, shape, dtype: (
+        0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)}
 
 
 def init_params(key, cfg: Config):
@@ -202,17 +190,11 @@ def init_params(key, cfg: Config):
     with deviation 1/sqrt(fan_in), norm scales 1, biases 0, and the Mamba
     family's defaults for ``A_log``, ``D_skip`` and ``dt_b``."""
     dt = cfg.dtype
-    params = {"embed": _init_leaf(jax.random.fold_in(key, 0),
-                                  (cfg.vocab, cfg.dim), cfg.dim, dt),
-              "ln_f_s": jnp.ones((cfg.dim,), dt),
-              "ln_f_b": jnp.zeros((cfg.dim,), dt), "layers": []}
-    for n, (_, kind) in enumerate(cfg.layers):
-        lk = jax.random.fold_in(key, n + 1)
-        params["layers"].append({
-            name: _init_leaf(jax.random.fold_in(lk, j), shape, how, dt)
-            for j, (name, (shape, how)) in enumerate(
-                sorted(leaf_shapes(cfg, kind).items()))})
-    return params
+    return {"embed": init_leaf(jax.random.fold_in(key, 0),
+                               (cfg.vocab, cfg.dim), cfg.dim, dt),
+            "ln_f_s": jnp.ones((cfg.dim,), dt),
+            "ln_f_b": jnp.zeros((cfg.dim,), dt),
+            "layers": init_layers(key, cfg, leaf_shapes, 1, _STARTS)}
 
 
 # ---------------------------------------------------------------------------
@@ -227,13 +209,6 @@ def _layernorm(x, scale, bias, eps):
     n = xc * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
     return (n * scale.astype(jnp.float32)
             + bias.astype(jnp.float32)).astype(x.dtype)
-
-
-def _mlp(u, p, cfg):
-    # kept by a recomputed layer (``_KEEP``)
-    g, v = jnp.split(checkpoint_name(u @ p["w1"], "mlp_up"), 2, axis=-1)
-    return (jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32)
-            ).astype(u.dtype) @ p["w2"]
 
 
 def _mamba(u, p, cfg):
@@ -326,7 +301,9 @@ def _layer(x, p, m_star, kv_star, *, index, kind, cfg):
             kv = kv if kind == "full" else None
         h = x + mix
     with jax.named_scope("block"), jax.named_scope("mlp"):
-        y = h + _mlp(_layernorm(h, p["ln2_s"], p["ln2_b"], cfg.eps), p, cfg)
+        # the up-projection is kept by a recomputed layer (``_KEEP``)
+        y = h + gated_silu(_layernorm(h, p["ln2_s"], p["ln2_b"], cfg.eps),
+                           p["w1"], p["w2"], "mlp_up")
     return y, m, kv
 
 
@@ -366,10 +343,7 @@ def loss_fn(params, tokens, cfg: Config):
 
 
 def make_optax_train_step(cfg: Config, tx):
-    """``(step, init)`` as ``models.transformer.make_optax_train_step``
-    gives them, for this model: one jit of ``value_and_grad(loss_fn)`` and
+    """``(step, init)`` as ``models.common.make_optax_train_step`` gives
+    them, for this model: one jit of ``value_and_grad(loss_fn)`` and
     ``tx.update`` in float32 master precision with donated state."""
-    def grad_fn(params, tokens):
-        return jax.value_and_grad(loss_fn)(params, tokens, cfg)
-
-    return optax_f32_step(tx, grad_fn, SCOPES)
+    return common.make_optax_train_step(loss_fn, cfg, tx, SCOPES)

@@ -43,7 +43,8 @@ from ..parallel import collectives as C
 from ..parallel.collectives import axis_size as _axis_size
 from .ring_attention import (ring_flash_attention_kernel,
                              zigzag_ring_flash_attention_kernel)
-from .transformer import Config, _rmsnorm
+from .common import fold, optax_f32_init, optax_f32_step, unfold
+from .transformer import Config, norm
 from .transformer import init_params as _transformer_init_params
 
 __all__ = ["SPConfig", "init_params", "param_specs", "forward_local",
@@ -130,35 +131,31 @@ def forward_local(params, tokens_loc, cfg: SPConfig, axis: str):
     x = params["embed"][tokens_loc] + pos[None]          # (b, s_loc, e)
 
     for blk in params["blocks"]:
-        h = _rmsnorm(x, blk["ln1"])
+        h = norm(x, blk["ln1"])
         qkv = h @ blk["qkv"]                             # (b, s_loc, 3e)
         q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def fold(t):
-            # (b, s_loc, e) -> (s_loc, b*h, d): batch folds into heads
-            return jnp.transpose(t.reshape(Bt, S_loc, H, D),
-                                 (1, 0, 2, 3)).reshape(S_loc, Bt * H, D)
-
+        # (b, s_loc, e) -> (s_loc, b*h, d): batch folds into heads
+        heads = lambda t: fold(t.reshape(Bt, S_loc, H, D))
         if cfg.zigzag:
             o = zigzag_ring_flash_attention_kernel(
-                fold(q), fold(k), fold(v), axis,
+                heads(q), heads(k), heads(v), axis,
                 block_q=cfg.block_q, block_k=cfg.block_k,
                 head_fold=cfg.head_fold, interpret=cfg.interpret)
         else:
             o = ring_flash_attention_kernel(
-                fold(q), fold(k), fold(v), axis, causal=True,
+                heads(q), heads(k), heads(v), axis, causal=True,
                 block_q=cfg.block_q, block_k=cfg.block_k,
                 head_fold=cfg.head_fold, interpret=cfg.interpret)
-        o = jnp.transpose(o.reshape(S_loc, Bt, H, D),
-                          (1, 0, 2, 3)).reshape(Bt, S_loc, E)
+        o = unfold(o, Bt).reshape(Bt, S_loc, E)
         x = x + o @ blk["proj"]
 
-        h2 = _rmsnorm(x, blk["ln2"])
+        h2 = norm(x, blk["ln2"])
         # batch folds into rows: the AG->RS ring returns each rank's rows
         f = tp_ffn(h2.reshape(Bt * S_loc, E), blk["w1"], blk["w2"], axis)
         x = x + f.reshape(Bt, S_loc, E)
 
-    return (_rmsnorm(x, params["ln_f"]) @ params["head"]).astype(jnp.float32)
+    return (norm(x, params["ln_f"]) @ params["head"]).astype(jnp.float32)
 
 
 def _loss_partial(params, tokens_loc, cfg: SPConfig, axis: str):
@@ -286,7 +283,7 @@ def make_optax_train_step(mesh, cfg: SPConfig, tx, axis: str = "p"):
     """Training with any optax optimizer: the (loss, grads) shard_map
     program composed with ``tx.update`` under ONE jit, in fp32 master
     precision (bf16 params/grads upcast for the optimizer arithmetic —
-    see ``transformer._optax_f32_step``) — GSPMD lays the optimizer
+    see ``common.optax_f32_step``) — GSPMD lays the optimizer
     state out to match each param (Adam moments for the tp-sharded FFN
     weights stay sharded, replicated params' moments replicated).  Hop
     knobs left ``None`` resolve per call against the autotune registry,
@@ -301,21 +298,18 @@ def make_optax_train_step(mesh, cfg: SPConfig, tx, axis: str = "p"):
         state = init(params)
         params, state, loss = step(params, state, tokens)
     """
-    from .transformer import _optax_f32_step
-
     built = {}
 
     def step(params, opt_state, tokens):
         rcfg = _resolve_cfg(cfg, mesh, axis, tokens.shape)
         if rcfg not in built:
-            built[rcfg] = _optax_f32_step(
+            built[rcfg] = optax_f32_step(
                 tx, lambda p, t: _grad_program(mesh, rcfg, axis)(p, t))[0]
         return built[rcfg](params, opt_state, tokens)
 
     def init(params):
-        # block-knob independent; fp32-master policy owned by transformer
-        from .transformer import _optax_f32_init
-        return _optax_f32_init(tx, params)
+        # block-knob independent; the fp32-master policy is common's
+        return optax_f32_init(tx, params)
 
     return step, init
 

@@ -30,12 +30,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.pallas_attention import flash_attention
-from ..telemetry import programs
+from . import common
 from .mlp import make_mesh
 
 __all__ = ["init_params", "forward", "loss_fn", "train_step",
-           "make_optax_train_step", "optax_f32_step", "blocked_nll",
-           "generate", "shard_params", "make_mesh", "Config", "SCOPES"]
+           "make_optax_train_step", "generate", "shard_params", "make_mesh",
+           "norm", "Config", "SCOPES"]
 
 # the phases this module's ``jax.named_scope``s declare, as they nest:
 # what ``telemetry.programs`` places the compiled step's instructions by
@@ -118,11 +118,9 @@ def shard_params(params, mesh: Mesh):
     return out
 
 
-def _rmsnorm(x, scale):
-    x32 = x.astype(jnp.float32)
-    n = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                            + 1e-6)
-    return (n * scale.astype(jnp.float32)).astype(x.dtype)
+def norm(x, scale):
+    """This model's RMS norm, eps 1e-6 (``sp_transformer`` shares it)."""
+    return common.rms_norm(x, scale, 1e-6)
 
 
 def _attention(x, blk, heads):
@@ -164,12 +162,12 @@ def forward(params, tokens, cfg: Config):
     for blk in params["blocks"]:
         with jax.named_scope("block"):
             with jax.named_scope("attn"):
-                x = x + _attention(_rmsnorm(x, blk["ln1"]), blk, cfg.heads)
+                x = x + _attention(norm(x, blk["ln1"]), blk, cfg.heads)
             with jax.named_scope("mlp"):
-                h = _rmsnorm(x, blk["ln2"])
+                h = norm(x, blk["ln2"])
                 x = x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
     with jax.named_scope("head_loss"):
-        return (_rmsnorm(x, params["ln_f"])
+        return (norm(x, params["ln_f"])
                 @ params["head"]).astype(jnp.float32)
 
 
@@ -181,34 +179,6 @@ def loss_fn(params, tokens, cfg: Config):
         logp = jax.nn.log_softmax(logits, axis=-1)
         ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
         return -jnp.mean(ll)
-
-
-def blocked_nll(x, table, targets, loss_rows: int):
-    """The summed cross-entropy of ``x`` (B, S, D) against ``targets``
-    (B, S) under the head ``table`` (vocab, D), float32: the logits exist
-    one block of at most ``loss_rows`` positions at a time, in both
-    directions (a block's are computed again in the backward), so a long
-    row's float32 logits are never held whole (``models/sambay.py``,
-    ``models/mla_moe.py``)."""
-    B, S, D = x.shape
-    rows = B * S
-    blk = min(loss_rows, rows)
-    while rows % blk:
-        blk -= 1
-    xb = x.reshape(rows // blk, blk, D)
-    tb = targets.reshape(rows // blk, blk)
-
-    @jax.checkpoint
-    def block_nll(tab, xr, tr):
-        logits = jnp.einsum("sd,vd->sv", xr, tab,
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
-        return jnp.sum(lse - picked)
-
-    return jax.lax.scan(
-        lambda acc, xt: (acc + block_nll(table, *xt), None),
-        jnp.zeros((), jnp.float32), (xb, tb))[0]
 
 
 def _decode_attn(h, blk, heads, kc, vc, i, t, max_seq):
@@ -268,12 +238,12 @@ def generate(params, prompt, n_new: int, cfg: Config,
         x = (params["embed"][tok][:, None]
              + params["pos"][t][None, None]).astype(cfg.dtype)
         for i, blk in enumerate(params["blocks"]):
-            a, kc, vc = _decode_attn(_rmsnorm(x, blk["ln1"]), blk, H,
+            a, kc, vc = _decode_attn(norm(x, blk["ln1"]), blk, H,
                                      kc, vc, i, t, cfg.max_seq)
             x = x + a
-            h2 = _rmsnorm(x, blk["ln2"])
+            h2 = norm(x, blk["ln2"])
             x = x + jax.nn.gelu(h2 @ blk["w1"]) @ blk["w2"]
-        logits = (_rmsnorm(x[:, 0], params["ln_f"])
+        logits = (norm(x[:, 0], params["ln_f"])
                   @ params["head"]).astype(jnp.float32)      # (B, V)
         if temperature > 0.0:
             nxt = jax.random.categorical(kt, logits / temperature, axis=-1)
@@ -306,52 +276,6 @@ def train_step(params, tokens, lr, cfg: Config):
     return new, loss
 
 
-def _optax_f32_step(tx, grad_fn, scopes=("optimizer",)):
-    """Shared optax step with fp32 master arithmetic: bf16 params/grads
-    upcast before ``tx.update`` + ``apply_updates`` and downcast after —
-    at bf16 resolution (~8 mantissa bits) Adam-scale updates against
-    O(0.1) weights would otherwise round to zero and training silently
-    stalls.  State must be initialized from fp32 params (use the
-    returned ``init``).  The step is the registered program
-    ``train.optax_step`` (``telemetry/programs.py``): the jitted function
-    run inside a span of that name, with ``scopes``, the phases the
-    model's ``jax.named_scope``s declare, for its phase map."""
-    import optax
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def step(params, opt_state, tokens):
-        loss, g = grad_fn(params, tokens)
-        with jax.named_scope("optimizer"):
-            p32 = _as_f32(params)
-            updates, opt_state = tx.update(_as_f32(g), opt_state, p32)
-            new32 = optax.apply_updates(p32, updates)
-            new = jax.tree_util.tree_map(
-                lambda n, p: n.astype(p.dtype), new32, params)
-        return new, opt_state, loss
-
-    def init(params):
-        return _optax_f32_init(tx, params)
-
-    return programs.register("train.optax_step", step, scopes), init
-
-
-# the float32-master step under its public name: what every model of the
-# package that trains through optax goes through (models/sambay.py)
-optax_f32_step = _optax_f32_step
-
-
-def _as_f32(t):
-    return jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), t)
-
-
-def _optax_f32_init(tx, params):
-    """Optimizer-state init from fp32 master params — the ONE owner of
-    the fp32-master policy's init half, shared by every step factory
-    (``_optax_f32_step`` here, ``sp_transformer.make_optax_train_step``)
-    so the upcast rule cannot silently diverge between them."""
-    return tx.init(_as_f32(params))
-
-
 def make_optax_train_step(cfg: Config, tx):
     """Training with any optax optimizer under the GSPMD model: one jit
     of value_and_grad + ``tx.update`` in fp32 master precision; XLA lays
@@ -359,7 +283,4 @@ def make_optax_train_step(cfg: Config, tx):
     (Megatron-sharded qkv/proj/w1/w2 moments stay tp-sharded).  Returns
     ``(step, init)``: ``state = init(params)``, then
     ``step(params, opt_state, tokens) -> (params, opt_state, loss)``."""
-    def grad_fn(params, tokens):
-        return jax.value_and_grad(loss_fn)(params, tokens, cfg)
-
-    return _optax_f32_step(tx, grad_fn, SCOPES)
+    return common.make_optax_train_step(loss_fn, cfg, tx, SCOPES)

@@ -48,6 +48,7 @@ MODULES = [
     "distributedarrays_tpu.models.kmeans",
     "distributedarrays_tpu.models.montecarlo",
     "distributedarrays_tpu.models.mlp",
+    "distributedarrays_tpu.models.common",
     "distributedarrays_tpu.models.transformer",
     "distributedarrays_tpu.models.sp_transformer",
     "distributedarrays_tpu.models.sambay",

@@ -13,6 +13,9 @@ new up-arrow, and fails when a debt is paid so its line is taken out here.
 The root modules (``layout``, ``core``, ``darray``) are the array type
 itself and every layer may import them; the root's own eager imports are
 ROADMAP Queue 3 item 6.
+
+Inside ``models/``, one case a module holds the seam between the training
+models: they share ``models/common.py`` and import nothing of each other.
 """
 
 import ast
@@ -129,3 +132,50 @@ def test_telemetry_is_a_leaf_but_for_the_live_plane():
     # the one arrow out of telemetry, and only from the two files named
     assert _graph("telemetry") == {
         "parallel": ["telemetry/agg.py", "telemetry/stream.py"]}
+
+
+# The training models and their float32 references.  No module of models/
+# imports a model's code but its own reference (a reference reads its
+# model's Config) and sp_transformer (GPT-2's Config, init_params and
+# norm): what two models share lives in models/common.py.  A model, a
+# reference and common import no private name of the package; the older
+# modules' private imports (kmeans, ring_attention, stencil, ulysses) are
+# ROADMAP Queue 3 debt.
+MODELS = {"transformer", "sp_transformer", "sambay", "mla_moe",
+          "mamba2_hybrid", "olmo_hybrid"}
+MAY_IMPORT = {"sp_transformer": {"transformer"}}
+
+
+def _from_the_package(path: Path):
+    """(module of models/ or None, name) of every name ``path`` imports
+    from the package (``from . import common`` names the module)."""
+    out = []
+    for node in ast.walk(ast.parse(path.read_text())):
+        if not isinstance(node, ast.ImportFrom):
+            continue
+        if node.level:
+            parts = ["models"] if node.level == 1 else []
+            parts += node.module.split(".") if node.module else []
+        elif (node.module or "").split(".")[0] == PKG.name:
+            parts = node.module.split(".")[1:]
+        else:
+            continue
+        for a in node.names:
+            full = parts + [a.name]
+            out.append((full[1] if full[0] == "models" else None, a.name))
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(
+    p.stem for p in (PKG / "models").glob("*.py") if p.stem != "__init__"))
+def test_a_model_imports_no_other_model_and_no_private_name(name):
+    imported = _from_the_package(PKG / "models" / f"{name}.py")
+    own = {name.removesuffix("_reference")} - {name}
+    models = {m for m, _ in imported
+              if m in MODELS or (m or "").endswith("_reference")}
+    assert models <= own | MAY_IMPORT.get(name, set()), (
+        f"models/{name}.py imports {sorted(models)}: a model imports no "
+        f"other model's code; move what they share into models/common.py")
+    if name.removesuffix("_reference") in MODELS | {"common"}:
+        private = [(m, n) for m, n in imported if n.startswith("_")]
+        assert not private, f"models/{name}.py imports private {private}"

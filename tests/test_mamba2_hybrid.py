@@ -9,7 +9,6 @@ import re
 import subprocess
 import sys
 import textwrap
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -17,46 +16,16 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-REPO = Path(__file__).resolve().parent.parent
-for _p in (str(REPO / "benchmark"),):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+import _models
+from distributedarrays_tpu.models import mamba2_hybrid as M
+from distributedarrays_tpu.models import mamba2_hybrid_reference as MR
+from distributedarrays_tpu.ops.pallas_ssd import ssd, ssd_plan
 
-from distributedarrays_tpu.models import mamba2_hybrid as M   # noqa: E402
-from distributedarrays_tpu.models import (                    # noqa: E402
-    mamba2_hybrid_reference as MR)
-from distributedarrays_tpu.ops.pallas_ssd import ssd, ssd_plan  # noqa: E402
-
-LAYERS = ((4, "mamba"), (5, "attention"), (6, "mamba"))
-DIMS = dict(dim=64, ffn=96, heads=4, kv_heads=2, head_dim=16, ssm_heads=4,
-            ssm_head_dim=16, d_inner=64, d_state=16, n_groups=2, d_conv=4,
-            chunk=16)
-MULT = dict(embedding_mult=12.0, residual_mult=0.22, attention_mult=1 / 16,
-            logits_scaling=8.0)
-
-
-def _config(layers=LAYERS, dtype=jnp.float32):
-    return M.Config(vocab=96, dim=64, ffn=96, heads=4, kv_heads=2,
-                    head_dim=16, ssm_heads=4, ssm_head_dim=16, d_state=16,
-                    n_groups=2, chunk=16, layers=layers, loss_rows=16,
-                    dtype=dtype, **MULT)
-
-
-def _weights(layers=LAYERS, seed=3):
-    """Seeded weights with every leaf moved off its start (a scale of 1
-    or a bias of 0 would hide a gradient path)."""
-    import datagen_granite as G
-    params = G.granite_weights(jax.random.key(seed), DIMS,
-                               [k for _, k in layers], 96, jnp.float32)
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + 0.05 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
-
-
-def _tokens(seed=1, batch=1, seq=40):
-    import datagen_granite as G
-    return G.token_rows(jax.random.key(seed), 1, batch, seq + 1, 96)[0]
+REPO = _models.REPO
+LAYERS, DIMS = _models.GRANITE_LAYERS, _models.GRANITE_DIMS
+MULT = _models.GRANITE_MULT
+TINY = _models.mamba2_hybrid
+_config, _weights, _tokens = TINY.config, TINY.weights, TINY.tokens
 
 
 # ---------------------------------------------------------------------------
@@ -84,22 +53,14 @@ def _ssd_case(L, H, P, G, N, lo, hi, seed=0):
         jax.random.normal(ks[5], (L, H, P))
 
 
-_SSD_RESULTS = {}
-
-
 def _ssd_results(case):
     """(kernel, recurrence) results of a case: the forward, then the five
     gradients of a weighted sum, computed once for the six tests of it."""
-    if case not in _SSD_RESULTS:
-        L, H, P, G, N, chunk, lo, hi = SSD_CASES[case]
-        args, w = _ssd_case(L, H, P, G, N, lo, hi)
-        kernel = lambda *a: ssd(*a, chunk=chunk)
-        with jax.default_matmul_precision("highest"):
-            _SSD_RESULTS[case] = [
-                (f(*args),) + jax.grad(lambda *a: jnp.sum(f(*a) * w),
-                                       argnums=(0, 1, 2, 3, 4))(*args)
-                for f in (kernel, MR.ssd_scan)]
-    return _SSD_RESULTS[case]
+    L, H, P, G, N, chunk, lo, hi = SSD_CASES[case]
+    args, w = _ssd_case(L, H, P, G, N, lo, hi)
+    kernel = lambda *a: ssd(*a, chunk=chunk)
+    with jax.default_matmul_precision("highest"):
+        return _models.against(("ssd", case), (kernel, MR.ssd_scan), args, w)
 
 
 @pytest.mark.parametrize("arg", ["forward", "x", "dt", "A", "B", "C"])
@@ -156,47 +117,26 @@ def test_ssd_plan_and_its_gauge():
 
 def test_loss_and_every_leaf_gradient_match_refs_granite():
     import refs_granite as R
-    cfg, params, tok = _config(), _weights(), _tokens()
-    loss, g = jax.jit(jax.value_and_grad(M.loss_fn),
-                      static_argnums=2)(params, tok, cfg)
+    params, tok = _weights(), _tokens()
+    loss, g = TINY.result("value_and_grad")
     dims = dict(DIMS, eps=1e-5, kinds=tuple(k for _, k in LAYERS), **MULT)
-    grads = {"layers": [None] * len(LAYERS)}
-
-    def keep(n, sub):
-        if n is None:
-            grads.update(sub)
-        else:
-            grads["layers"][n] = sub
-
-    with jax.default_matmul_precision("highest"):
-        nll = R._row_nll_and_grads(params, tok[0], dims, None, keep)
-    loss0 = nll / (tok.shape[1] - 1)
-    g0 = jax.tree_util.tree_map(lambda t: t / (tok.shape[1] - 1), grads)
+    loss0, g0 = _models.row_reference(R, params, tok, dims)
     assert abs(float(loss) - loss0) < 2e-5 * abs(loss0)
-    want, gap = R.leaf_norm_dict(g0), R.leaf_norm_dict(g, g0)
-    assert set(gap) == set(R.leaf_norm_dict(params))
-    floor = 1e-3 * float(np.median(list(want.values())))
     # float32 on both sides at this size: read 1.4e-6 at most
-    worst = max((gap[k] / max(want[k], floor), k) for k in gap)
+    worst = _models.worst_leaf(R, g, g0, params)
     assert worst[0] < 5e-4, worst
 
 
 @pytest.mark.parametrize("batch", [1, 2])
 def test_package_reference_agrees_with_the_program(batch):
     # with two rows the batch folds into the kernels' heads
-    cfg, params = _config(), _weights()
-    tok = _tokens(batch=batch)
-    vg = lambda f: jax.jit(jax.value_and_grad(f), static_argnums=2)
-    loss, g = vg(M.loss_fn)(params, tok, cfg)
-    loss0, g0 = vg(MR.loss_fn)(params, tok, cfg)
+    loss, g = TINY.result("value_and_grad", batch=batch)
+    loss0, g0 = TINY.result("value_and_grad", "reference", batch=batch)
     assert abs(float(loss) - float(loss0)) < 2e-5 * abs(float(loss0))
-    flat, flat0 = (jax.tree_util.tree_leaves(t) for t in (g, g0))
-    scale = float(np.median([float(jnp.linalg.norm(x)) for x in flat0]))
-    for a, b in zip(flat, flat0):
-        assert float(jnp.linalg.norm(a - b)) < 5e-4 * max(
-            float(jnp.linalg.norm(b)), 1e-3 * scale)
-    fwd = lambda f: jax.jit(f, static_argnums=2)(params, tok[:, :-1], cfg)
-    logits, logits0 = fwd(M.forward), fwd(MR.forward)
+    worst = max((v, k) for k, v in _models.gaps(g, g0).items())
+    assert worst[0] < 5e-4, worst
+    logits = TINY.result("forward", batch=batch)
+    logits0 = TINY.result("forward", "reference", batch=batch)
     assert np.allclose(logits, logits0, atol=2e-5)
 
 
@@ -223,15 +163,12 @@ def test_the_multipliers_act_where_the_configuration_says():
     # each multiplier changes the logits; the published ones are not the
     # identity, so none may be dropped silently
     params, tok = _weights(), _tokens()[:, :-1]
-    base = M.forward(params, tok, _config())
+    base = TINY.result("forward")
+    forward = _models.jitted(M.forward)
     for key, value in dict(embedding_mult=1.0, residual_mult=1.0,
                            attention_mult=0.25, logits_scaling=1.0).items():
-        cfg = M.Config(**{**dict(vocab=96, dim=64, ffn=96, heads=4,
-                                 kv_heads=2, head_dim=16, ssm_heads=4,
-                                 ssm_head_dim=16, d_state=16, n_groups=2,
-                                 chunk=16, layers=LAYERS,
-                                 dtype=jnp.float32), **MULT, key: value})
-        assert float(jnp.max(jnp.abs(M.forward(params, tok, cfg) - base))) \
+        cfg = _config(**{key: value})
+        assert float(jnp.max(jnp.abs(forward(params, tok, cfg) - base))) \
             > 1e-3, key
 
 
@@ -288,13 +225,7 @@ def test_step_carries_its_scopes_and_kernel_names(scope, step_text):
 
 @pytest.fixture(scope="module")
 def step_text():
-    import optax
-    cfg = _config(dtype=jnp.bfloat16)
-    step, init = M.make_optax_train_step(cfg, optax.adamw(1e-3))
-    p = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
-    return step.lower(p, jax.eval_shape(init, p),
-                      jax.ShapeDtypeStruct((1, 33), jnp.int32)
-                      ).as_text(debug_info=True)
+    return _models.step_text("mamba2_hybrid")
 
 
 def test_importing_the_package_loads_neither_the_model_nor_the_kernels():

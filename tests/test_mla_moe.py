@@ -6,7 +6,6 @@ CPU (kernels in interpret mode)."""
 import subprocess
 import sys
 import textwrap
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -14,23 +13,17 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-REPO = Path(__file__).resolve().parent.parent
+import _models
+from distributedarrays_tpu import telemetry as tm
+from distributedarrays_tpu.models import mla_moe as M
+from distributedarrays_tpu.models import mla_moe_reference as MR
+from distributedarrays_tpu.models import moe as E
+from distributedarrays_tpu.ops.pallas_attention import flash_attention
 
-from distributedarrays_tpu import telemetry as tm                 # noqa: E402
-from distributedarrays_tpu.models import mla_moe as M             # noqa: E402
-from distributedarrays_tpu.models import mla_moe_reference as MR  # noqa: E402
-from distributedarrays_tpu.models import moe as E                 # noqa: E402
-from distributedarrays_tpu.ops.pallas_attention import flash_attention  # noqa: E402
-
-DIMS = dict(vocab=96, dim=64, heads=4, q_rank=24, kv_rank=16, nope=24,
-            rope=8, v_dim=32, ffn=128, moe_ffn=32, n_experts=16, top_k=4,
-            loss_rows=32)
-LAYERS = ((0, "dense"), (1, "moe"), (2, "moe"))
-
-
-def _cfg(held=(4, 4), mtp=47, dtype=jnp.float32, **kw):
-    return M.Config(**{**DIMS, **kw}, layers=LAYERS, held=held, mtp=mtp,
-                    dtype=dtype)
+REPO = _models.REPO
+DIMS, LAYERS = _models.MLA_DIMS, _models.MLA_LAYERS
+TINY = _models.mla_moe
+_cfg = TINY.config
 
 
 def _rel(a, b):
@@ -41,10 +34,7 @@ def _rel(a, b):
 
 @pytest.fixture(scope="module")
 def tiny():
-    cfg = _cfg()
-    params = M.init_params(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (2, 50), 0, cfg.vocab)
-    return cfg, params, tokens
+    return _cfg(), TINY.weights(), TINY.tokens()
 
 
 # ---------------------------------------------------------------------------
@@ -54,24 +44,27 @@ def tiny():
 
 def test_logits_of_trunk_and_mtp_match_the_reference(tiny):
     cfg, params, tokens = tiny
-    main, mtp = M.forward(params, tokens[:, :-1], cfg, mtp=True)
+    forward = jax.jit(M.forward, static_argnums=(2, 3))
+    main, mtp = forward(params, tokens[:, :-1], cfg, True)
     for b in range(tokens.shape[0]):
         a, c = MR.forward(params, tokens[b, :-1], cfg, mtp=True)
         # float32 both sides; the order of the sums differs
         assert float(jnp.abs(main[b] - a).max()) < 5e-5
         assert float(jnp.abs(mtp[b] - c).max()) < 5e-5
-    alone = M.forward(params, tokens[:, :-2], cfg)
+    alone = forward(params, tokens[:, :-2], cfg, False)
     assert float(jnp.abs(alone - main).max()) < 1e-6
 
 
 def test_both_losses_and_every_leaf_gradient_match_the_reference(tiny):
     cfg, params, tokens = tiny
-    got, want = M.loss_parts(params, tokens, cfg), MR.loss_parts(
-        params, tokens, cfg)
+    got = _models.jitted(M.loss_parts)(params, tokens, cfg)
+    want = _models.jitted(MR.loss_parts)(params, tokens, cfg)
     for a, b in zip(got, want):
         assert abs(float(a) - float(b)) < 2e-6 * float(b)
-    loss, grads = jax.value_and_grad(M.loss_fn)(params, tokens, cfg)
-    ref_loss, ref_grads = MR.loss_and_grads(params, tokens, cfg)
+    loss, grads = _models.jitted(M.loss_fn, jax.value_and_grad)(
+        params, tokens, cfg)
+    ref_loss, ref_grads = _models.jitted(MR.loss_and_grads)(
+        params, tokens, cfg)
     assert abs(float(loss) - float(ref_loss)) < 2e-6 * float(ref_loss)
     assert abs(float(loss) - float(got[0] + cfg.mtp_lambda * got[1])) < 1e-6
     flat = jax.tree_util.tree_leaves_with_path(grads)
@@ -88,10 +81,10 @@ def test_both_losses_and_every_leaf_gradient_match_the_reference(tiny):
 def test_without_the_mtp_module_rows_are_one_id_shorter(tiny):
     _, _, tokens = tiny
     cfg = _cfg(mtp=None)
-    params = M.init_params(jax.random.key(0), cfg)
+    params = TINY.weights(mtp=None)
     assert "mtp" not in params
-    main, mtp = M.loss_parts(params, tokens[:, :-1], cfg)
-    want = MR.loss_parts(params, tokens[:, :-1], cfg)
+    main, mtp = _models.jitted(M.loss_parts)(params, tokens[:, :-1], cfg)
+    want = _models.jitted(MR.loss_parts)(params, tokens[:, :-1], cfg)
     assert abs(float(main) - float(want[0])) < 2e-6 * float(want[0])
     assert float(mtp) == 0.0 == float(want[1])
 
@@ -250,8 +243,8 @@ def test_expert_layer_gradients_match_the_dense_form():
     def dense(u, q):
         return jnp.sum(MR.expert_layer(u, q, cfg, shared=False) * ct)
 
-    got = jax.grad(prog, argnums=(0, 1))(jnp.asarray(u), share)
-    want = jax.grad(dense, argnums=(0, 1))(jnp.asarray(u), share)
+    got = jax.jit(jax.grad(prog, argnums=(0, 1)))(jnp.asarray(u), share)
+    want = jax.jit(jax.grad(dense, argnums=(0, 1)))(jnp.asarray(u), share)
     assert _rel(got[0], want[0]) < 1e-5 and float(
         jnp.linalg.norm(want[0])) > 0
     for k in ("router", "ew1", "ew2"):
@@ -402,16 +395,14 @@ def test_flash_at_width_256_matches_dense_attention(direction):
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.standard_normal((256, 2, 256)), jnp.float32)
                for _ in range(3))
-    if direction == "forward":
-        got = flash_attention(q, k, v, causal=True)
-        assert float(jnp.abs(got - _dense_attention(q, k, v)).max()) < 2e-5
-        return
     ct = jnp.asarray(rng.standard_normal((256, 2, 256)), jnp.float32)
-    got = jax.grad(lambda *a: jnp.sum(flash_attention(*a, causal=True) * ct),
-                   argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(_dense_attention(*a) * ct),
-                    argnums=(0, 1, 2))(q, k, v)
-    for g, r in zip(got, want):
+    got, want = _models.against(
+        "flash_256", (lambda *a: flash_attention(*a, causal=True),
+                      _dense_attention), (q, k, v), ct)
+    if direction == "forward":
+        assert float(jnp.abs(got[0] - want[0]).max()) < 2e-5
+        return
+    for g, r in zip(got[1:], want[1:]):
         assert _rel(g, r) < 2e-5
 
 

@@ -10,7 +10,6 @@ import re
 import subprocess
 import sys
 import textwrap
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -18,44 +17,16 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-REPO = Path(__file__).resolve().parent.parent
-for _p in (str(REPO / "benchmark"),):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
-
-from distributedarrays_tpu.models import olmo_hybrid as M       # noqa: E402
-from distributedarrays_tpu.models import (                       # noqa: E402
-    olmo_hybrid_reference as MR)
-from distributedarrays_tpu.ops.pallas_gated_delta import (      # noqa: E402
+import _models
+from distributedarrays_tpu.models import olmo_hybrid as M
+from distributedarrays_tpu.models import olmo_hybrid_reference as MR
+from distributedarrays_tpu.ops.pallas_gated_delta import (
     gated_delta, gated_delta_plan)
 
-LAYERS = ((2, "linear_attention"), (3, "full_attention"),
-          (4, "linear_attention"))
-DIMS = dict(dim=64, ffn=96, heads=4, head_dim=16, lin_heads=4, key_dim=16,
-            value_dim=32, d_conv=4)
-
-
-def _config(layers=LAYERS, dtype=jnp.float32):
-    return M.Config(vocab=96, dim=64, ffn=96, heads=4, head_dim=16,
-                    lin_heads=4, key_dim=16, value_dim=32, layers=layers,
-                    loss_rows=16, dtype=dtype)
-
-
-def _weights(layers=LAYERS, seed=3):
-    """Seeded weights with every leaf moved off its start (a scale of 1
-    would hide a gradient path)."""
-    import datagen_olmo_hybrid as G
-    params = G.olmo_hybrid_weights(jax.random.key(seed), DIMS,
-                                   [k for _, k in layers], 96, jnp.float32)
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + 0.05 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
-
-
-def _tokens(seed=1, batch=1, seq=72):
-    import datagen_olmo_hybrid as G
-    return G.token_rows(jax.random.key(seed), 1, batch, seq + 1, 96)[0]
+REPO = _models.REPO
+LAYERS, DIMS = _models.OLMO_LAYERS, _models.OLMO_DIMS
+TINY = _models.olmo_hybrid
+_config, _weights, _tokens = TINY.config, TINY.weights, TINY.tokens
 
 
 # ---------------------------------------------------------------------------
@@ -87,22 +58,15 @@ def _gdn_case(L, H, dk, dv, glo, ghi, blo, bhi, seed=0):
     return (q, k, v, beta, g), jax.random.normal(ks[5], (L, H, dv))
 
 
-_GDN_RESULTS = {}
-
-
 def _gdn_results(case):
     """(kernel, recurrence) results of a case: the forward, then the five
     gradients of a weighted sum, computed once for the six tests of it."""
-    if case not in _GDN_RESULTS:
-        L, H, dk, dv, chunk, glo, ghi, blo, bhi = GDN_CASES[case]
-        args, w = _gdn_case(L, H, dk, dv, glo, ghi, blo, bhi)
-        kernel = lambda *a: gated_delta(*a, chunk=chunk)
-        with jax.default_matmul_precision("highest"):
-            _GDN_RESULTS[case] = [
-                (f(*args),) + jax.grad(lambda *a: jnp.sum(f(*a) * w),
-                                       argnums=(0, 1, 2, 3, 4))(*args)
-                for f in (kernel, MR.delta_rule)]
-    return _GDN_RESULTS[case]
+    L, H, dk, dv, chunk, glo, ghi, blo, bhi = GDN_CASES[case]
+    args, w = _gdn_case(L, H, dk, dv, glo, ghi, blo, bhi)
+    kernel = lambda *a: gated_delta(*a, chunk=chunk)
+    with jax.default_matmul_precision("highest"):
+        return _models.against(("gdn", case), (kernel, MR.delta_rule),
+                               args, w)
 
 
 def _rel(got, want):
@@ -277,58 +241,29 @@ def test_a_recomputed_layer_solves_once(policy, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _gaps(g, g0):
-    """{leaf path: |g - g0| / max(|g0|, a thousandth of the median
-    leaf's norm)}."""
-    flat = jax.tree_util.tree_leaves_with_path(g)
-    flat0 = jax.tree_util.tree_leaves(g0)
-    scale = float(np.median([float(jnp.linalg.norm(x)) for x in flat0]))
-    return {jax.tree_util.keystr(p): float(jnp.linalg.norm(a - b)) / max(
-        float(jnp.linalg.norm(b)), 1e-3 * scale)
-        for (p, a), b in zip(flat, flat0)}
-
-
 def test_loss_and_every_leaf_gradient_match_refs_olmo_hybrid():
     import refs_olmo_hybrid as R
-    cfg, params, tok = _config(), _weights(), _tokens()
-    loss, g = jax.jit(jax.value_and_grad(M.loss_fn),
-                      static_argnums=2)(params, tok, cfg)
+    params, tok = _weights(), _tokens()
+    loss, g = TINY.result("value_and_grad")
     dims = dict(DIMS, eps=1e-6, kinds=tuple(k for _, k in LAYERS))
-    grads = {"layers": [None] * len(LAYERS)}
-
-    def keep(n, sub):
-        if n is None:
-            grads.update(sub)
-        else:
-            grads["layers"][n] = sub
-
-    with jax.default_matmul_precision("highest"):
-        nll = R._row_nll_and_grads(params, tok[0], dims, None, keep)
-    loss0 = nll / (tok.shape[1] - 1)
-    g0 = jax.tree_util.tree_map(lambda t: t / (tok.shape[1] - 1), grads)
+    loss0, g0 = _models.row_reference(R, params, tok, dims)
     assert abs(float(loss) - loss0) < 2e-5 * abs(loss0)
-    want, gap = R.leaf_norm_dict(g0), R.leaf_norm_dict(g, g0)
-    assert set(gap) == set(R.leaf_norm_dict(params))
-    floor = 1e-3 * float(np.median(list(want.values())))
     # float32 on both sides at this size: read 1.3e-5 at most
-    worst = max((gap[k] / max(want[k], floor), k) for k in gap)
+    worst = _models.worst_leaf(R, g, g0, params)
     assert worst[0] < 5e-4, worst
 
 
 @pytest.mark.parametrize("batch", [1, 2])
 def test_package_reference_agrees_with_the_program(batch):
     # with two rows the batch folds into the kernels' heads
-    cfg, params = _config(), _weights()
-    tok = _tokens(batch=batch)
-    vg = lambda f: jax.jit(jax.value_and_grad(f), static_argnums=2)
-    loss, g = vg(M.loss_fn)(params, tok, cfg)
-    loss0, g0 = vg(MR.loss_fn)(params, tok, cfg)
+    loss, g = TINY.result("value_and_grad", batch=batch)
+    loss0, g0 = TINY.result("value_and_grad", "reference", batch=batch)
     assert abs(float(loss) - float(loss0)) < 2e-5 * abs(float(loss0))
     # float32 on both sides: read 1.2e-5 at most (A_log, dt_bias)
-    worst = max((v, k) for k, v in _gaps(g, g0).items())
+    worst = max((v, k) for k, v in _models.gaps(g, g0).items())
     assert worst[0] < 5e-4, worst
-    fwd = lambda f: jax.jit(f, static_argnums=2)(params, tok[:, :-1], cfg)
-    logits, logits0 = fwd(M.forward), fwd(MR.forward)
+    logits = TINY.result("forward", batch=batch)
+    logits0 = TINY.result("forward", "reference", batch=batch)
     assert np.allclose(logits, logits0, atol=2e-5)
 
 
@@ -346,12 +281,12 @@ def test_each_norm_acts_and_its_gradient_matches(leaf):
     scale = params["layers"][n][leaf]
     moved["layers"][n][leaf] = scale * (
         1.0 + 0.5 * jax.random.normal(jax.random.key(5), scale.shape))
-    base = M.forward(params, tok[:, :-1], cfg)
-    assert float(jnp.max(jnp.abs(M.forward(moved, tok[:, :-1], cfg) - base))) \
+    base = TINY.result("forward")
+    forward = _models.jitted(M.forward)
+    assert float(jnp.max(jnp.abs(forward(moved, tok[:, :-1], cfg) - base))) \
         > 1e-3, leaf
-    grad = lambda f: jax.jit(jax.grad(f), static_argnums=2)(params, tok, cfg)
-    got = grad(M.loss_fn)["layers"][n][leaf]
-    want = grad(MR.loss_fn)["layers"][n][leaf]
+    got = TINY.result("value_and_grad")[1]["layers"][n][leaf]
+    want = TINY.result("value_and_grad", "reference")[1]["layers"][n][leaf]
     assert float(jnp.linalg.norm(want)) > 0
     assert float(jnp.linalg.norm(got - want)) < 5e-4 * float(
         jnp.linalg.norm(want))
@@ -438,13 +373,7 @@ def test_step_carries_its_scopes_and_kernel_names(scope, step_text):
 
 @pytest.fixture(scope="module")
 def step_text():
-    import optax
-    cfg = _config(dtype=jnp.bfloat16)
-    step, init = M.make_optax_train_step(cfg, optax.adamw(1e-3))
-    p = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
-    return step.lower(p, jax.eval_shape(init, p),
-                      jax.ShapeDtypeStruct((1, 33), jnp.int32)
-                      ).as_text(debug_info=True)
+    return _models.step_text("olmo_hybrid")
 
 
 def test_importing_the_package_loads_neither_the_model_nor_the_kernels():

@@ -1,6 +1,6 @@
 """``telemetry/programs.py``: the registry of compiled programs, the
 placement rule, and the wrapper ``optax_f32_step`` returns.  Each model's
-tiny step is compiled once a module."""
+tiny step is compiled once a process (``tests/_models.py``)."""
 
 import re
 
@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+import _models
 from distributedarrays_tpu import telemetry as tm
 from distributedarrays_tpu.telemetry import programs
 from distributedarrays_tpu.models import mla_moe as M
@@ -82,49 +83,16 @@ def test_parse_hlo_reads_heads_tuples_and_root():
 # each model's map
 # ---------------------------------------------------------------------------
 
-def _transformer():
-    cfg = T.Config(vocab=256, dim=128, heads=4, layers=2, max_seq=128,
-                   dtype=jnp.bfloat16)
-    return (T.make_optax_train_step(cfg, optax.adamw(1e-3)), T.SCOPES,
-            lambda: T.init_params(jax.random.key(0), cfg), (2, 65))
-
-
-def _sambay():
-    cut = tuple((i, k) for i, k in S.layer_kinds(32, 2) if 14 <= i <= 19)
-    cfg = S.Config(vocab=96, dim=128, ffn=256, heads=8, kv_heads=4,
-                   head_dim=16, window=24, layers=cut, loss_rows=32)
-    return (S.make_optax_train_step(cfg, optax.adamw(1e-3)), S.SCOPES,
-            lambda: S.init_params(jax.random.key(0), cfg), (1, 33))
-
-
-def _mla_moe():
-    cfg = M.Config(vocab=96, dim=64, heads=4, q_rank=24, kv_rank=16, nope=24,
-                   rope=8, v_dim=32, ffn=128, moe_ffn=32, n_experts=16,
-                   held=(4, 4), layers=((0, "dense"), (1, "moe")), mtp=47,
-                   loss_rows=32)
-    return (M.make_optax_train_step(cfg, optax.adamw(1e-3)), M.SCOPES,
-            lambda: M.init_params(jax.random.key(0), cfg), (1, 34))
-
-
-_MODELS = {"transformer": _transformer, "sambay": _sambay,
-           "mla_moe": _mla_moe}
-_BUILT = {}
+_MODELS = ["transformer", "sambay", "mla_moe"]
 
 
 @pytest.fixture
 def model(request):
     """(program, scopes, its map, its compiled text), compiled once."""
-    if request.param not in _BUILT:
-        (step, init), scopes, params, tokens = _MODELS[request.param]()
-        p = jax.eval_shape(params)
-        step.note(p, jax.eval_shape(init, p),
-                  jax.ShapeDtypeStruct(tokens, jnp.int32))
-        _BUILT[request.param] = (step, scopes, programs.phase_map(step),
-                                 programs.compiled(step).as_text())
-    return _BUILT[request.param]
+    return _models.step_program(request.param)
 
 
-_EACH = pytest.mark.parametrize("model", list(_MODELS), indirect=True)
+_EACH = pytest.mark.parametrize("model", _MODELS, indirect=True)
 
 
 @_EACH

@@ -5,7 +5,6 @@ tiny sizes on the CPU (kernels in interpret mode)."""
 import subprocess
 import sys
 import textwrap
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -13,45 +12,19 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-REPO = Path(__file__).resolve().parent.parent
-for _p in (str(REPO / "benchmark"),):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
-
-from distributedarrays_tpu.models import sambay as M          # noqa: E402
-from distributedarrays_tpu.models import sambay_reference as MR  # noqa: E402
-from distributedarrays_tpu.ops.pallas_attention import (      # noqa: E402
+import _models
+from distributedarrays_tpu.models import sambay as M
+from distributedarrays_tpu.ops.pallas_attention import (
     _count_steps, flash_attention)
-from distributedarrays_tpu.ops.pallas_selective_scan import (  # noqa: E402
+from distributedarrays_tpu.ops.pallas_selective_scan import (
     selective_scan, selective_scan_plan)
 
-CUT = tuple((i, k) for i, k in M.layer_kinds(32, 2) if 14 <= i <= 19)
+REPO = _models.REPO
+CUT = _models.sambay_cut()
 TWELVE = CUT + tuple((i + 10, k) for i, k in CUT)    # every kind twice
-DIMS = dict(dim=128, ffn=256, heads=8, kv_heads=4, head_dim=16, window=24,
-            d_inner=256, d_state=16, d_conv=4, dt_rank=8)
-
-
-def _config(layers, dtype=jnp.float32):
-    return M.Config(vocab=96, dim=128, ffn=256, heads=8, kv_heads=4,
-                    head_dim=16, window=24, layers=layers, dtype=dtype,
-                    loss_rows=32)
-
-
-def _weights(layers, seed=3):
-    """Seeded weights with every leaf moved off its start (a bias of 0 or
-    a scale of 1 would hide a gradient path)."""
-    import datagen_sambay as G
-    params = G.sambay_weights(jax.random.key(seed), DIMS, layers, 96,
-                              jnp.float32)
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + 0.05 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
-
-
-def _tokens(seed=1, batch=1, seq=32):
-    import datagen_sambay as G
-    return G.token_rows(jax.random.key(seed), 1, batch, seq + 1, 96)[0]
+DIMS = _models.SAMBAY_DIMS
+TINY = _models.sambay
+_config, _weights, _tokens = TINY.config, TINY.weights, TINY.tokens
 
 
 # ---------------------------------------------------------------------------
@@ -64,34 +37,27 @@ def test_loss_and_every_leaf_gradient_match_refs_sambay(layers):
     import refs_sambay as R
     cfg, params = _config(layers), _weights(layers)
     tok = _tokens(batch=2 if layers is CUT else 1, seq=40)
-    loss, g = jax.jit(jax.value_and_grad(M.loss_fn),
-                      static_argnums=2)(params, tok, cfg)
+    loss, g = _models.jitted(M.loss_fn, jax.value_and_grad)(params, tok, cfg)
     with jax.default_matmul_precision("highest"):
         loss0, g0 = R.ref_loss_and_grads(
             params, tok, dict(DIMS, eps=1e-5, layers=layers))
     assert abs(float(loss) - loss0) < 2e-5 * abs(loss0)
-    want, gap = R.leaf_norm_dict(g0), R.leaf_norm_dict(g, g0)
-    assert set(gap) == set(R.leaf_norm_dict(params))
-    floor = 1e-3 * float(np.median(list(want.values())))
-    # a key bias has no gradient (a shift of a row's scores): both read ~0
-    worst = max((gap[k] / max(want[k], floor), k) for k in gap)
+    worst = _models.worst_leaf(R, g, g0, params)
     assert worst[0] < 5e-4, worst
+    # a key bias has no gradient (a shift of a row's scores): both read ~0
+    want = R.leaf_norm_dict(g0)
+    floor = 1e-3 * float(np.median(list(want.values())))
     assert all(want[k] < floor for k in want if k.endswith(".bk"))
 
 
 def test_package_reference_agrees_with_the_program():
-    cfg, params, tok = _config(CUT), _weights(CUT), _tokens()
-    vg = lambda f: jax.jit(jax.value_and_grad(f), static_argnums=2)
-    loss, g = vg(M.loss_fn)(params, tok, cfg)
-    loss0, g0 = vg(MR.loss_fn)(params, tok, cfg)
+    loss, g = TINY.result("value_and_grad")
+    loss0, g0 = TINY.result("value_and_grad", "reference")
     assert abs(float(loss) - float(loss0)) < 2e-5 * abs(float(loss0))
-    flat, flat0 = (jax.tree_util.tree_leaves(t) for t in (g, g0))
-    scale = float(np.median([float(jnp.linalg.norm(x)) for x in flat0]))
-    for a, b in zip(flat, flat0):
-        assert float(jnp.linalg.norm(a - b)) < 5e-4 * max(
-            float(jnp.linalg.norm(b)), 1e-3 * scale)
-    fwd = lambda f: jax.jit(f, static_argnums=2)(params, tok[:, :-1], cfg)
-    assert np.allclose(fwd(M.forward), fwd(MR.forward), atol=2e-4)
+    worst = max((v, k) for k, v in _models.gaps(g, g0).items())
+    assert worst[0] < 5e-4, worst
+    assert np.allclose(TINY.result("forward"),
+                       TINY.result("forward", "reference"), atol=2e-4)
 
 
 def test_bf16_training_step_runs_and_moves_the_weights():
@@ -143,7 +109,7 @@ def test_lambda_init_follows_the_published_index():
     cfg_a = _config(((1, "full"),))
     cfg_b = _config(((17, "full"),))
     params, tok = _weights(((1, "full"),)), _tokens()
-    a, b = (float(jax.jit(M.loss_fn, static_argnums=2)(params, tok, c))
+    a, b = (float(_models.jitted(M.loss_fn)(params, tok, c))
             for c in (cfg_a, cfg_b))
     assert abs(a - b) > 1e-4
 
@@ -233,12 +199,9 @@ def test_scan_kernel_matches_the_sequential_scan(arg):
     # 44 positions in chunks of 16: three chunks, the last one padded
     args, w = _scan_case()
     kernel = lambda *a: selective_scan(*a, chunk=16, block_e=128)
-    if arg == "forward":
-        got, want = kernel(*args), _scan_ref(*args)
-    else:
-        n = ["x", "dt", "A", "B", "C"].index(arg)
-        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=n)(*args)
-                     for f in (kernel, _scan_ref))
+    n = ["forward", "x", "dt", "A", "B", "C"].index(arg)
+    got, want = (r[n] for r in _models.against(
+        "selective_scan", (kernel, _scan_ref), args, w))
     assert got.shape == want.shape
     assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(
         jnp.max(jnp.abs(want)))
@@ -304,12 +267,9 @@ def test_flash_variants_match_masked_dense_attention(case, direction):
     flash = lambda q, k, v: flash_attention(
         q, k, v, causal=True, window=window, block_q=blk, block_k=blk)
     dense = lambda q, k, v: _dense(q, k, v, window)
-    if direction == "forward":
-        pairs = [(flash(q, k, v), dense(q, k, v))]
-    else:
-        pairs = zip(*(jax.grad(lambda *a: jnp.sum(f(*a) * w), (0, 1, 2))(
-            q, k, v) for f in (flash, dense)))
-    for got, want in pairs:
+    ours, ref = _models.against(("flash", case), (flash, dense), (q, k, v), w)
+    n = slice(0, 1) if direction == "forward" else slice(1, 4)
+    for got, want in zip(ours[n], ref[n]):
         assert got.shape == want.shape
         assert float(jnp.max(jnp.abs(got - want))) < 2e-5
 
@@ -329,9 +289,9 @@ def test_cross_attention_reads_another_layers_keys_and_values():
         return jnp.sum(jnp.sin(attn(q, k, v)))
 
     flash = lambda q, k, v: flash_attention(q, k, v, causal=True)
-    got = jax.grad(lambda x, y: f(flash, x, y), (0, 1))(x, y)
-    want = jax.grad(lambda x, y: f(lambda *a: _dense(*a, None), x, y),
-                    (0, 1))(x, y)
+    got = jax.jit(jax.grad(lambda x, y: f(flash, x, y), (0, 1)))(x, y)
+    want = jax.jit(jax.grad(lambda x, y: f(lambda *a: _dense(*a, None), x, y),
+                            (0, 1)))(x, y)
     for a, b in zip(got, want):
         assert float(jnp.max(jnp.abs(a - b))) < 2e-5
 

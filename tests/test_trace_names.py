@@ -17,6 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import _models
 import distributedarrays_tpu as dat
 from distributedarrays_tpu import telemetry as tm
 from distributedarrays_tpu.telemetry import tracing
@@ -94,11 +95,7 @@ def test_sambay_step_carries_its_scopes_and_kernel_names(scope):
     # the scopes docs/telemetry.md lists for models/sambay.py reach the
     # step program's op names: forward, recomputed and backward
     from distributedarrays_tpu.models import sambay as S
-    cut = tuple((i, k) for i, k in S.layer_kinds(32, 2) if 14 <= i <= 19)
-    cfg = S.Config(vocab=96, dim=128, ffn=256, heads=8, kv_heads=4,
-                   head_dim=16, window=24, layers=cut, loss_rows=32)
-    text = _sambay_step_text(cfg)
-    names = set(re.findall(r'loc\("([^"]*)"', text))
+    names = set(re.findall(r'loc\("([^"]*)"', _models.step_text("sambay")))
     if scope in S.KINDS or scope == "mlp":
         hits = [n for n in names if f"block/{scope}/" in n
                 or f"jvp(block)/{scope}/" in n]
@@ -117,19 +114,7 @@ def test_sambay_step_carries_its_scopes_and_kernel_names(scope):
 def test_mla_moe_step_carries_its_scopes_and_kernel_names(scope):
     # the scopes docs/telemetry.md lists for models/mla_moe.py reach the
     # step program's op names; the FFN half forward, recomputed and backward
-    from distributedarrays_tpu.models import mla_moe as M
-    cfg = M.Config(vocab=96, dim=64, heads=4, q_rank=24, kv_rank=16, nope=24,
-                   rope=8, v_dim=32, ffn=128, moe_ffn=32, n_experts=16,
-                   held=(4, 4), layers=((0, "dense"), (1, "moe")), mtp=47,
-                   loss_rows=32)
-    if cfg not in _STEP_TEXT:
-        import optax
-        step, init = M.make_optax_train_step(cfg, optax.adamw(1e-3))
-        p = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
-        _STEP_TEXT[cfg] = step.lower(
-            p, jax.eval_shape(init, p),
-            jax.ShapeDtypeStruct((1, 34), jnp.int32)).as_text(debug_info=True)
-    names = set(re.findall(r'loc\("([^"]*)"', _STEP_TEXT[cfg]))
+    names = set(re.findall(r'loc\("([^"]*)"', _models.step_text("mla_moe")))
     hits = [n for n in names if scope.replace("block/", "block)/") in n
             or scope in n]
     assert hits, scope
@@ -140,8 +125,6 @@ def test_mla_moe_step_carries_its_scopes_and_kernel_names(scope):
         assert any("mtp/block" in n or "mtp)/block" in n for n in hits)
         assert any("head_loss" in n for n in hits)
 
-
-_STEP_TEXT = {}
 
 
 # ---------------------------------------------------------------------------
@@ -197,18 +180,6 @@ def test_optax_step_opens_the_documented_span(monkeypatch):
         "\n\n", 1)[0]
     assert f"| `{made[0]}` |" in table
     assert made[0] == "dat.train.optax_step"
-
-
-def _sambay_step_text(cfg):
-    if cfg not in _STEP_TEXT:
-        import optax
-        from distributedarrays_tpu.models import sambay as S
-        step, init = S.make_optax_train_step(cfg, optax.adamw(1e-3))
-        p = jax.eval_shape(lambda: S.init_params(jax.random.key(0), cfg))
-        _STEP_TEXT[cfg] = step.lower(
-            p, jax.eval_shape(init, p),
-            jax.ShapeDtypeStruct((1, 33), jnp.int32)).as_text(debug_info=True)
-    return _STEP_TEXT[cfg]
 
 
 # ---------------------------------------------------------------------------

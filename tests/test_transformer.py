@@ -121,7 +121,7 @@ def _sp_dense_forward(cfg, params, tokens):
     D = E // H
     x = params["embed"][tokens] + params["pos"][:S][None]
     for blk in params["blocks"]:
-        h = T._rmsnorm(x, blk["ln1"])
+        h = T.norm(x, blk["ln1"])
         q, k, v = jnp.split(h @ blk["qkv"], 3, axis=-1)
 
         def heads_(t):
@@ -132,9 +132,9 @@ def _sp_dense_forward(cfg, params, tokens):
                       s, -jnp.inf)
         o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), heads_(v))
         x = x + jnp.transpose(o, (0, 2, 1, 3)).reshape(B, S, E) @ blk["proj"]
-        h2 = T._rmsnorm(x, blk["ln2"])
+        h2 = T.norm(x, blk["ln2"])
         x = x + jax.nn.gelu(h2 @ blk["w1"]) @ blk["w2"]
-    return (T._rmsnorm(x, params["ln_f"]) @ params["head"]).astype(
+    return (T.norm(x, params["ln_f"]) @ params["head"]).astype(
         jnp.float32)
 
 
